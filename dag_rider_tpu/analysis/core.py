@@ -54,27 +54,29 @@ class Allow:
 
 
 def discover(repo_root: str) -> List[SourceFile]:
-    """Every .py file of the package plus the repo-root bench.py, in a
-    deterministic order."""
-    files: List[SourceFile] = []
+    """Every .py file of the package plus the repo-root bench.py and
+    chip_smoke.py, in a deterministic order."""
+    paths: List[str] = []
     pkg = os.path.join(repo_root, "dag_rider_tpu")
     for dirpath, dirnames, filenames in os.walk(pkg):
         dirnames.sort()
         if "__pycache__" in dirpath:
             continue
-        for fn in sorted(filenames):
-            if not fn.endswith(".py"):
-                continue
-            full = os.path.join(dirpath, fn)
-            rel = os.path.relpath(full, repo_root).replace(os.sep, "/")
-            with open(full, "r", encoding="utf-8") as fh:
-                src = fh.read()
-            files.append((rel, ast.parse(src, filename=rel), src))
-    bench = os.path.join(repo_root, "bench.py")
-    if os.path.exists(bench):
-        with open(bench, "r", encoding="utf-8") as fh:
+        paths.extend(
+            os.path.join(dirpath, fn)
+            for fn in sorted(filenames)
+            if fn.endswith(".py")
+        )
+    for root_script in ("bench.py", "chip_smoke.py"):
+        full = os.path.join(repo_root, root_script)
+        if os.path.exists(full):
+            paths.append(full)
+    files: List[SourceFile] = []
+    for full in paths:
+        rel = os.path.relpath(full, repo_root).replace(os.sep, "/")
+        with open(full, "r", encoding="utf-8") as fh:
             src = fh.read()
-        files.append(("bench.py", ast.parse(src, filename="bench.py"), src))
+        files.append((rel, ast.parse(src, filename=rel), src))
     return files
 
 

@@ -216,9 +216,12 @@ def build_cluster(
             "peers": {str(j): addrs[j] for j in range(n) if j != i},
             "keys": keys_path,
             "rbc": rbc,
-            # cpu: real Ed25519 on every vertex without the device
-            # verifier's AOT-compile boot cost — cluster rungs measure
-            # process/socket behavior, not kernel throughput
+            # cpu: real Ed25519 on every vertex, on the host — what the
+            # CPU test lanes run. The runners never own a chip (the
+            # supervisor pins them to JAX_PLATFORMS=cpu); a deployment
+            # with one overrides this with "verifier": "remote" and the
+            # address of the sidecar that holds it (chip_smoke.py
+            # phase B).
             "verifier": "cpu",
             "coin": coin,
             "cert": cert,

@@ -82,9 +82,12 @@ class ClusterSupervisor:
         self.restart_counts: Dict[int, int] = {}
         self._outs: List = []
         base_env = dict(os.environ)
-        # consensus workloads here are tiny; keep JAX off accelerators
-        # and the runners' import time deterministic
-        base_env.setdefault("JAX_PLATFORMS", "cpu")
+        # A chip belongs to one process, and no runner is that process:
+        # a deployment gives it to a sidecar the runners reach with
+        # "verifier": "remote". Set, not setdefault — on a machine whose
+        # environment names the TPU, a runner configured with a device
+        # cert/coin lane would otherwise contend for the chip.
+        base_env["JAX_PLATFORMS"] = "cpu"
         if trace:
             base_env["DAGRIDER_TRACE"] = "1"
         if env:

@@ -192,7 +192,7 @@ def build_topology(
     )
 
 
-def _coin_factory(kind: str, n: int, f: int):
+def coin_factory(kind: str, n: int, f: int):
     """round_robin -> None (the Config default); threshold_bls -> real
     (f+1)-of-n BLS coins sharing one set of share/sigma books (the bench
     idiom): share SIGNING stays per-process and real, but each wave's
@@ -265,7 +265,7 @@ def run_scenario(sc: Scenario) -> dict:
     sim = Simulation(
         cfg,
         transport=tp,
-        coin_factory=_coin_factory(sc.coin_kind(), cfg.n, cfg.f),
+        coin_factory=coin_factory(sc.coin_kind(), cfg.n, cfg.f),
         rbc=sc.resolved_rbc(),
         process_factory=process_factory,
     )

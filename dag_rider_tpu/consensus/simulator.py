@@ -215,8 +215,10 @@ class Simulation:
         matching signer factory when the caller didn't bring one — so a
         CPU-oracle run and a sharded run of the same Config verify the
         exact same signatures and their commit orders are comparable
-        byte for byte. "sharded" takes its mesh from DAGRIDER_MESH (or
-        the virtual-device fallback — parallel/mesh.mesh_from_env)."""
+        byte for byte. "sharded" takes its mesh from DAGRIDER_MESH
+        (parallel/mesh.mesh_from_env). "device" and "sharded" bring the
+        persistent compile cache and refuse a CPU backend that
+        JAX_PLATFORMS did not ask for, exactly as a node's do."""
         from dag_rider_tpu.verifier.base import (
             CertSigner,
             KeyRegistry,

@@ -326,21 +326,21 @@ class Node:
             )
 
         if kind in ("device", "sharded"):
-            # Production entry-path parity with bench/tests: repo-local
-            # XLA compile cache, then wrap the device verifier in a
-            # depth-K dispatch window whose construction AOT-compiles
-            # the fixed-bucket program — the first consensus round must
-            # not eat a cold ~35 s XLA compile. "sharded" shares every
-            # knob (verify_bucket/verify_depth/verify_warmup) and lays
-            # the batch over a device mesh sized by DAGRIDER_MESH
-            # (virtual-device fallback on CPU — parallel/mesh.py); its
-            # bucket rounds up to a mesh multiple internally, masks stay
-            # byte-identical to the single-chip program.
-            from dag_rider_tpu.utils.jaxcache import enable_persistent_cache
+            # Wrap the device verifier (which brings the persistent
+            # compile cache and refuses a CPU backend nobody asked for)
+            # in a depth-K dispatch window whose construction compiles
+            # the program the committee will dispatch — the first
+            # consensus round must not eat a cold XLA compile, and a
+            # program the chip refuses must fail the start-up. "sharded"
+            # shares every knob (verify_bucket/verify_depth/
+            # verify_warmup) and lays the batch over a device mesh sized
+            # by DAGRIDER_MESH (virtual devices under JAX_PLATFORMS=cpu
+            # — parallel/mesh.py); its bucket rounds up to a mesh
+            # multiple internally, masks stay byte-identical to the
+            # single-chip program.
             from dag_rider_tpu.verifier.pipeline import VerifierPipeline
             from dag_rider_tpu.verifier.tpu import TPUVerifier
 
-            enable_persistent_cache()
             if kind == "sharded":
                 from dag_rider_tpu.parallel.mesh import mesh_from_env
                 from dag_rider_tpu.parallel.sharded_verifier import (
@@ -585,6 +585,15 @@ class Node:
             return self.mempool.submit(block.transactions, client=client)
 
     def start(self) -> None:
+        # which verifier, on which platform, running which program: a
+        # validator that came up on the wrong one says so in its log
+        stats = getattr(self.process.verifier, "stats", None)
+        self.log.event(
+            "started",
+            round=self.process.round,
+            verifier=type(self.process.verifier).__name__,
+            **(stats() if callable(stats) else {}),
+        )
         self.process.defer_steps = True
         self.process.start()
         self._thread = threading.Thread(target=self._pump_loop, daemon=True)

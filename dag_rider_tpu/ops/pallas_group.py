@@ -226,7 +226,7 @@ def _pow22523_rows(z: List) -> List:
     """z^(2^252 - 3) on row lists — the RFC 8032 sqrt exponent chain,
     entirely in VMEM. fori_loop keeps the Mosaic program small for the
     long square runs; tuple carries, not stacked arrays (jnp.stack of 22
-    rows forced a VMEM relayout every iteration — the 250-deep chain
+    rows forced a VMEM re-layout every iteration — the 250-deep chain
     spent ~5x its multiply time shuffling, measured on-chip)."""
 
     def nsq(x: List, n: int) -> List:
@@ -339,7 +339,7 @@ def _block(n: int) -> int:
     for b in (512, 256, 128):
         if n % b == 0:
             return b
-    return n  # tiny test sizes (interpret mode)
+    return n  # below one lane tile: the whole array is the block
 
 
 _VREG = 8 * 128  # one (8, 128) int32 vector register's worth of lanes
@@ -351,8 +351,14 @@ def _call_rowwise(kernel, out_rows: int, interpret: bool, *args: jax.Array):
     Row counts may differ per operand (each arg's shape[0] is used); the
     lane count N must match. When N divides into (8, 128) vregs the
     operands are viewed as [rows, G, 8, 128] and each block is one
-    vreg-shaped row set; otherwise (tiny test sizes) a flat [rows, blk]
-    2D block is used.
+    vreg-shaped row set; otherwise a flat [rows, blk] 2D block is used.
+    The 2D branch is on the served path, not only in tests: a dispatch
+    of B signatures runs tree levels of 64B, 32B, ..., 2B lanes and the
+    finish kernel at B lanes, so at one n=256 round per dispatch
+    (B = 256) the last tree level (512 lanes) and the whole finish
+    kernel — sqrt chain included — are 2D, and at B = 512 the finish
+    kernel still is. Both branches compile under jax 0.9.0 / libtpu
+    0.0.34 and match the jnp tree on a v5e (chip_smoke.py phases A, C).
     """
     n = args[0].shape[1]
     if n % _VREG == 0:

@@ -19,34 +19,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from dag_rider_tpu import config
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    """``jax.shard_map`` across jax versions.
-
-    Newer jax exposes ``jax.shard_map(..., check_vma=)``; 0.4.x only has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)`` — the same
-    static varying-axis check under its old name. Every shard_map in this
-    package (sharded comb verify, sharded MSM) goes through here so the
-    mesh paths run on both the chip host's jax and the 0.4.x CI/test
-    containers."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f,
-            mesh=mesh,
-            in_specs=in_specs,
-            out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        check_rep=check_vma,
-    )
+from dag_rider_tpu.utils.jaxcache import cpu_requested
 
 
 def make_mesh(
@@ -75,22 +48,18 @@ def mesh_from_env(default_devices: int = 8) -> Mesh:
     """The 1-D batch mesh for ``verifier: "sharded"`` deployments.
 
     ``DAGRIDER_MESH`` gives the batch-axis device count; unset means
-    every visible device. On a CPU backend that has not been initialized
-    yet (laptops, CI), the XLA host-device-count flag is injected first
-    so the request still yields a real multi-device mesh — the virtual
-    8-device fallback the tests run on. If jax already initialized with
-    fewer devices than requested, the mesh clamps with a warning rather
-    than failing the node."""
+    every visible device. Only when ``JAX_PLATFORMS`` names ``cpu``
+    (tests, CI) and the backend has not been initialized yet, the XLA
+    host-device-count flag is injected first so the request still
+    yields a real multi-device mesh — the virtual 8-device mesh the
+    tests run on. Asking for more devices than jax has is an error on
+    an accelerator (a four-chip deployment that came up with one chip
+    must not serve at a quarter of its capacity); on the CPU platform
+    the mesh clamps with a warning, because the flag above is ignored
+    once jax has initialized."""
     want = config.env_opt_int("DAGRIDER_MESH")
-    platform = os.environ.get("JAX_PLATFORMS", "").split(",")[0].strip()
     flags = os.environ.get("XLA_FLAGS", "")
-    if (
-        platform.lower() == "cpu"
-        and "xla_force_host_platform_device_count" not in flags
-    ):
-        # Before the first jax.devices() call this flag still takes
-        # effect; after backend init it is ignored and the clamp below
-        # applies. Only the CPU platform honors it at all.
+    if cpu_requested() and "xla_force_host_platform_device_count" not in flags:
         virtual = want if want is not None else default_devices
         if virtual > 1:
             os.environ["XLA_FLAGS"] = (
@@ -100,6 +69,12 @@ def mesh_from_env(default_devices: int = 8) -> Mesh:
     if want is None:
         want = have
     if want > have:
+        platform = jax.devices()[0].platform
+        if platform != "cpu":
+            raise RuntimeError(
+                f"DAGRIDER_MESH={want} but jax sees {have} {platform} "
+                f"device(s); refusing to serve on a smaller mesh"
+            )
         warnings.warn(
             f"DAGRIDER_MESH={want} but only {have} device(s) visible; "
             f"clamping the mesh to {have}",

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from dag_rider_tpu.ops import bls_msm
-from dag_rider_tpu.parallel.mesh import make_mesh, shard_map
+from dag_rider_tpu.parallel.mesh import make_mesh
 
 
 def make_sharded_msm_kernel(mesh: Mesh, impl: str = "jnp"):
@@ -37,7 +37,7 @@ def make_sharded_msm_kernel(mesh: Mesh, impl: str = "jnp"):
     shard_map is exactly what lets the Mosaic kernels run per shard."""
 
     @functools.partial(
-        shard_map,
+        jax.shard_map,
         mesh=mesh,
         in_specs=(P("batch"), P("batch"), P("batch"), P("batch")),
         out_specs=(P(), P(), P()),

@@ -10,7 +10,7 @@ data-parallel) — XLA inserts the result all-gather; ICI carries it.
 
 First-class on the async seam (round 7): this class overrides ONLY the
 placement hooks of :class:`~dag_rider_tpu.verifier.tpu.TPUVerifier`
-(``_round_bucket``/``_put``/``_comb_fn``/``_aot_lower``/...), so
+(``_round_bucket``/``_put``/``_aot_lower``/...), so
 ``dispatch_batch``/``resolve_batch``/``warmup``/the chunk-streaming
 ``verify_rounds`` — and therefore every caller: ``VerifierPipeline``,
 ``Simulation.run``'s coalesced window, node.py — ride the mesh without a
@@ -59,7 +59,6 @@ from dag_rider_tpu.parallel.mesh import (
     batch_sharding,
     make_mesh,
     replicated,
-    shard_map,
 )
 from dag_rider_tpu.verifier.base import KeyRegistry
 from dag_rider_tpu.verifier.tpu import TPUVerifier, _bucket, _comb_impl
@@ -136,7 +135,7 @@ class ShardedTPUVerifier(TPUVerifier):
             from jax.sharding import PartitionSpec as P
 
             @functools.partial(
-                shard_map,
+                jax.shard_map,
                 mesh=self.mesh,
                 in_specs=(P("batch"), P("batch"), P(), P()),
                 out_specs=P("batch"),
@@ -205,17 +204,11 @@ class ShardedTPUVerifier(TPUVerifier):
             )
         return self._repl_tables
 
-    def _comb_fn(self, impl: str):
-        return self._sharded_comb_kernel(impl)
-
     def _windowed_dispatch(self, args) -> jax.Array:
         return self._sharded_verify(*(jnp.asarray(a) for a in args))
 
     def _aot_lower(self, size: int, impl: str, tables, b_tab):
-        # No donation on the mesh path: the per-shard input sub-buffers
-        # are small and the sharded executable is also the lazy kernel —
-        # one program, AOT'd at the fixed bucket with sharding-carrying
-        # avals so dispatch skips the jit cache entirely.
+        # lowered with sharding-carrying avals at the exact dispatch shape
         shd = self._batch_sharding
         return (
             self._sharded_comb_kernel(impl)

@@ -477,7 +477,7 @@ class GrpcTransport(Transport):
             # Authenticated frame: <u32 relayer> || codec message || MAC,
             # MAC'd with the (relayer, me) pair key. The relayer is the
             # transport-level sender; it differs from msg.sender only for
-            # relayed VALs (FETCH retransmissions and catch-up sync serve
+            # forwarded VALs (FETCH retransmissions and catch-up sync serve
             # other processes' original signed vertices — those are
             # self-certifying via the vertex signature + RBC digest
             # votes). For every control kind, msg.sender must BE the

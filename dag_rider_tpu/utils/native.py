@@ -4,15 +4,19 @@ The build's native surface (SURVEY §2a: host-side native code in C++):
 batched Ed25519 challenge-scalar computation for the verify host path.
 The library is compiled on demand with ``g++ -O2 -shared -fPIC`` into the
 package's ``native/`` directory and loaded with ctypes — no pybind11 /
-build-system dependency. Everything degrades to the pure-Python hashlib
-path when the toolchain or the compiled object is unavailable, and the
-hashlib path remains the differential-testing oracle
-(tests/test_native.py asserts byte-identical outputs).
+build-system dependency. The object's name carries a hash of the source
+it was built from, so an object left over from an older challenge.cpp
+(or copied around with a fresh mtime) is never loaded. A build or load
+failure raises: running without the library is a choice
+(``DAGRIDER_NATIVE=0``, the hashlib path — which stays the
+differential-testing oracle, tests/test_native.py), not a fallback.
 """
 
 from __future__ import annotations
 
 import ctypes
+import glob
+import hashlib
 import os
 import subprocess
 import threading
@@ -22,64 +26,56 @@ import numpy as np
 
 _DIR = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native")
 _SRC = os.path.join(_DIR, "challenge.cpp")
-_SO = os.path.join(_DIR, "libdagrider_native.so")
 
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
-_tried = False
 
 
-def _build() -> bool:
+def _so_path() -> str:
+    with open(_SRC, "rb") as fh:
+        tag = hashlib.sha256(fh.read()).hexdigest()[:16]
+    return os.path.join(_DIR, f"libdagrider_native.{tag}.so")
+
+
+def _build(so: str) -> None:
     """Compile to a temp file, then atomically rename into place — two
-    processes racing a cold/stale cache must never load a half-written
-    object."""
-    tmp = f"{_SO}.{os.getpid()}.tmp"
+    processes racing a cold cache must never load a half-written object.
+    Objects of older sources are removed."""
+    tmp = f"{so}.{os.getpid()}.tmp"
     try:
         proc = subprocess.run(
             ["g++", "-O2", "-shared", "-fPIC", "-o", tmp, _SRC],
             capture_output=True,
             timeout=120,
         )
-        if proc.returncode != 0 or not os.path.exists(tmp):
-            return False
-        os.replace(tmp, _SO)
-        return True
-    except (OSError, subprocess.TimeoutExpired):
-        return False
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"g++ failed on {_SRC}: "
+                f"{proc.stderr.decode(errors='replace')[-2000:]}"
+            )
+        os.replace(tmp, so)
     finally:
         if os.path.exists(tmp):
+            os.remove(tmp)
+    for old in glob.glob(os.path.join(_DIR, "libdagrider_native*.so")):
+        if old != so:
             try:
-                os.remove(tmp)
+                os.remove(old)
             except OSError:
-                pass
+                pass  # another process's; not ours to insist on
 
 
-def _stale() -> bool:
-    try:
-        if not os.path.exists(_SO):
-            return True
-        # No source in the deployment (prebuilt-only): use the .so as-is.
-        if not os.path.exists(_SRC):
-            return False
-        return os.path.getmtime(_SO) < os.path.getmtime(_SRC)
-    except OSError:
-        return True
-
-
-def load() -> Optional[ctypes.CDLL]:
-    """The native library, building it on first use; None if unavailable.
-    Never raises — every failure degrades to the pure-Python path."""
-    global _lib, _tried
+def load() -> ctypes.CDLL:
+    """The native library, built from the tracked source on first use.
+    Raises when it cannot be built or loaded (no g++, a compile error)."""
+    global _lib
     with _lock:
-        if _lib is not None or _tried:
+        if _lib is not None:
             return _lib
-        _tried = True
-        try:
-            if _stale() and not _build():
-                return None
-            lib = ctypes.CDLL(_SO)
-        except OSError:
-            return None
+        so = _so_path()
+        if not os.path.exists(so):
+            _build(so)
+        lib = ctypes.CDLL(so)
         u8p = ctypes.POINTER(ctypes.c_uint8)
         u64p = ctypes.POINTER(ctypes.c_uint64)
         lib.dagrider_challenge_batch.argtypes = [
@@ -96,11 +92,11 @@ def _u8(a: np.ndarray):
 
 def challenge_batch(
     rs: np.ndarray, pks: np.ndarray, msgs: Sequence[bytes]
-) -> Optional[np.ndarray]:
+) -> np.ndarray:
     """k_i = SHA-512(R_i || A_i || M_i) mod L for the whole batch.
 
     rs/pks: uint8[n, 32]; msgs: n byte strings. Returns uint8[n, 32]
-    little-endian scalars, or None when the native library is absent.
+    little-endian scalars.
 
     Thread-safe and re-entrant: every buffer the C call reads or writes
     is allocated per call (the copies above this line are part of the
@@ -111,8 +107,6 @@ def challenge_batch(
     blocks hash in genuinely parallel native code.
     """
     lib = load()
-    if lib is None:
-        return None
     n = len(msgs)
     if rs.shape != (n, 32) or pks.shape != (n, 32):
         raise ValueError("rs/pks must be uint8[n, 32]")

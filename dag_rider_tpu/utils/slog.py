@@ -69,6 +69,7 @@ KNOWN_EVENTS = frozenset(
         "pump_error",
         "restore_drop_invalid",
         "restored",
+        "started",
         "state_transfer",
         "state_transfer_attempt_failed",
         "state_transfer_failed",

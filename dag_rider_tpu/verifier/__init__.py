@@ -1,6 +1,7 @@
 from dag_rider_tpu.verifier.base import (
     KeyRegistry,
     Verifier,
+    VerifierCompileError,
     VerifierUnavailableError,
     VertexSigner,
 )
@@ -20,6 +21,7 @@ __all__ = [
     "NullVerifier",
     "VerifierPipeline",
     "ResilientVerifier",
+    "VerifierCompileError",
     "VerifierUnavailableError",
     "VerifierFaultInjector",
     "VerifierFaultPlan",

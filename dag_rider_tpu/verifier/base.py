@@ -171,6 +171,14 @@ class VerifierUnavailableError(RuntimeError):
     condition fail-closes to an all-False mask."""
 
 
+class VerifierCompileError(RuntimeError):
+    """A device program failed to lower or compile (a Mosaic refusal, a
+    VMEM overflow, an API the installed JAX no longer has). Unlike a
+    runtime fault this never clears on retry and says the deployment is
+    broken, so no containment, quarantine or ladder tier may absorb it:
+    every ``except Exception`` on the verify path re-raises it first."""
+
+
 class Verifier(abc.ABC):
     """Batched vertex-signature verification."""
 

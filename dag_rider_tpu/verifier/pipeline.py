@@ -1,7 +1,8 @@
 """Depth-K asynchronous verifier pipeline.
 
-BENCH_r05 measured the device seam at 228.5 sigs/s with 179 ms per
-dispatch over 72 dispatches: the FIXED per-dispatch cost (H2D transfer,
+Round 5 measured the device seam at 228.5 sigs/s with 179 ms per
+dispatch over 72 dispatches (a CPU-backend run; record removed in
+PR 21): the FIXED per-dispatch cost (H2D transfer,
 cache lookup, blocking resolve immediately after dispatch) dominates, not
 the math. The async halves already exist (``TPUVerifier.dispatch_batch``
 / ``resolve_batch``) but every caller used them at depth 1 — dispatch,
@@ -23,8 +24,11 @@ fully synchronous chunk loop.
   byte-identical to ``verify_batch`` / ``CPUVerifier``
   (tests/test_pipeline.py);
 - **AOT warmup** — construction calls the verifier's :meth:`warmup`,
-  which ``jit(...).lower(...).compile()``-s the fixed-bucket program so
-  the first consensus round never eats a ~35 s XLA compile.
+  which ``jit(...).lower(...).compile()``-s the program the committee
+  will dispatch, so the first consensus round never eats the XLA compile
+  and a program the chip refuses fails construction. A compile failure
+  (:class:`VerifierCompileError`) is never contained: it propagates out
+  of every window below.
 
 The mask is still a pure function of (vertex bytes, registry); the
 pipeline only changes WHEN the host blocks, never WHAT it computes.
@@ -39,7 +43,7 @@ from typing import Callable, Deque, List, Optional, Sequence
 from dag_rider_tpu import config
 from dag_rider_tpu.core.types import Vertex
 from dag_rider_tpu.utils.slog import NOOP, EventLog
-from dag_rider_tpu.verifier.base import Verifier
+from dag_rider_tpu.verifier.base import Verifier, VerifierCompileError
 
 
 def default_depth() -> int:
@@ -144,6 +148,8 @@ class VerifierPipeline(Verifier):
     def _dispatch(self, chunk: Sequence[Vertex]) -> None:
         try:
             handle = self.verifier.dispatch_batch(chunk)
+        except VerifierCompileError:
+            raise
         except Exception:  # noqa: BLE001 — prep/dispatch fault contained
             self._contain(chunk, failed_first=False)
             return
@@ -156,6 +162,8 @@ class VerifierPipeline(Verifier):
         _dispatch, prep already paid."""
         try:
             handle = self.verifier.dispatch_prepped(prepped)
+        except VerifierCompileError:
+            raise
         except Exception:  # noqa: BLE001 — dispatch fault contained
             self._contain(chunk, failed_first=False)
             return
@@ -211,6 +219,8 @@ class VerifierPipeline(Verifier):
             if self.quarantine_verifier is not None:
                 return self.quarantine_verifier.verify_batch(vs)
             return self.verifier.verify_batch(vs)
+        except VerifierCompileError:
+            raise
         except Exception:  # noqa: BLE001 — second failure fail-closes
             self.quarantine_rejected += 1
             return [False] * len(vs)
@@ -420,13 +430,27 @@ class VerifierPipeline(Verifier):
             out["shard_imbalance"] = round(
                 getattr(self.verifier, "last_shard_imbalance", 0.0), 3
             )
-        # fault-containment gauges (round 9), only once something was
-        # actually contained — the clean path's stats dict is unchanged
+        # where the work ran (TPUVerifier.stats) and the containment
+        # gauges — always present, so a consumer can assert on zero
+        vs = getattr(self.verifier, "stats", None)
+        if callable(vs):
+            v = vs()
+            out.update(
+                (k, v[k])
+                for k in ("platform", "device_kind", "impl", "bucket")
+                if k in v
+            )
         rs = self.resilience_stats()
-        if rs["poisoned_windows"] or rs["quarantined"]:
-            out["poisoned_windows"] = rs["poisoned_windows"]
-            out["quarantined"] = rs["quarantined"]
-            out["quarantine_rejected"] = rs["quarantine_rejected"]
+        out.update(
+            (k, rs[k])
+            for k in (
+                "retries",
+                "fallbacks",
+                "poisoned_windows",
+                "quarantined",
+                "quarantine_rejected",
+            )
+        )
         return out
 
     def resilience_stats(self) -> dict:

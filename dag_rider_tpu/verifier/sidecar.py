@@ -28,7 +28,11 @@ import grpc
 
 from dag_rider_tpu.core import codec
 from dag_rider_tpu.core.types import Vertex
-from dag_rider_tpu.verifier.base import Verifier, VerifierUnavailableError
+from dag_rider_tpu.verifier.base import (
+    Verifier,
+    VerifierCompileError,
+    VerifierUnavailableError,
+)
 
 _METHOD = "/dagrider.Verifier/VerifyBatch"
 _identity = lambda b: b  # noqa: E731
@@ -65,7 +69,12 @@ class _VerifyHandler(grpc.GenericRpcHandler):
                 context.abort(
                     grpc.StatusCode.INVALID_ARGUMENT, "malformed batch"
                 )
-            mask = self._backend.verify_batch(batch)
+            try:
+                mask = self._backend.verify_batch(batch)
+            except VerifierCompileError as e:
+                # not a transport fault: the client must not retry it or
+                # take it to a CPU tier (RemoteVerifier re-raises it)
+                context.abort(grpc.StatusCode.FAILED_PRECONDITION, str(e))
             return bytes(1 if ok else 0 for ok in mask)
 
         return grpc.unary_unary_rpc_method_handler(
@@ -93,18 +102,14 @@ class VerifierSidecarServer:
         # before warmup so the first prep builds the right pool.
         if prep_workers is not None and hasattr(backend, "prep_workers"):
             backend.prep_workers = int(prep_workers)
-        # Device-backed sidecars get entry-path parity with bench/tests:
-        # the repo-local XLA compile cache plus an AOT warmup of the
-        # fixed-bucket program BEFORE the port opens, so the first
-        # VerifyBatch RPC never eats a cold ~35 s XLA compile. Host-only
-        # backends (CPUVerifier oracle) skip both — no jax import.
+        # Device-backed sidecars compile the program their committee
+        # will dispatch BEFORE the port opens, so the first VerifyBatch
+        # RPC never eats a cold XLA compile and a program the chip
+        # refuses fails the start-up. Host-only backends (CPUVerifier
+        # oracle) have no warmup — no jax import.
         self.warmup_compile_s = 0.0
-        if hasattr(backend, "warmup"):
-            from dag_rider_tpu.utils.jaxcache import enable_persistent_cache
-
-            enable_persistent_cache()
-            if warmup:
-                self.warmup_compile_s = backend.warmup()
+        if warmup and hasattr(backend, "warmup"):
+            self.warmup_compile_s = backend.warmup()
         # one worker: device dispatches serialize anyway, and a single
         # thread keeps per-backend batching deterministic.
         self._server = grpc.server(futures.ThreadPoolExecutor(max_workers=1))
@@ -220,7 +225,11 @@ class RemoteVerifier(Verifier):
         for attempt in range(self._retries + 1):
             try:
                 mask = self._invoke(payload)
-            except (grpc.RpcError, VerifierUnavailableError):
+            except grpc.RpcError as e:
+                if e.code() == grpc.StatusCode.FAILED_PRECONDITION:
+                    raise VerifierCompileError(e.details()) from e
+                self.rpc_failures += 1
+            except VerifierUnavailableError:
                 self.rpc_failures += 1
             else:
                 if len(mask) == len(vertices):

@@ -31,7 +31,8 @@ import sys
 import tempfile
 import threading
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# host consensus only: this process and its children stay off the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 

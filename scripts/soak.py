@@ -19,7 +19,8 @@ import sys
 import tempfile
 import time
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# host consensus only: this process and its children stay off the chip
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 from dag_rider_tpu import node as node_mod
 from dag_rider_tpu.core.types import Block

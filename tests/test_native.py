@@ -9,14 +9,10 @@ the verifier must produce identical masks with it on or off.
 import hashlib
 
 import numpy as np
-import pytest
 
 from dag_rider_tpu.crypto import ed25519
 from dag_rider_tpu.utils import native
 
-pytestmark = pytest.mark.skipif(
-    native.load() is None, reason="native toolchain unavailable"
-)
 
 
 def test_challenge_batch_matches_hashlib():
@@ -86,3 +82,25 @@ def test_verifier_masks_identical_native_on_off(monkeypatch):
     monkeypatch.setenv("DAGRIDER_NATIVE", "0")
     without = ver.verify_batch(vs)
     assert with_native == without == [True, True, True, True, False]
+
+
+def test_object_is_keyed_on_source_content(tmp_path, monkeypatch):
+    """An object from an older challenge.cpp must never be loaded: the
+    object's name carries the source's hash, whatever its mtime."""
+    import shutil
+
+    src = tmp_path / "challenge.cpp"
+    shutil.copy(native._SRC, src)
+    monkeypatch.setattr(native, "_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_SRC", str(src))
+    monkeypatch.setattr(native, "_lib", None)
+    first = native._so_path()
+    native.load()
+    assert [p.name for p in tmp_path.glob("*.so")] == [first.split("/")[-1]]
+    src.write_text(src.read_text() + "\n// edited\n")
+    monkeypatch.setattr(native, "_lib", None)
+    second = native._so_path()
+    assert second != first
+    native.load()
+    # rebuilt for the new source; the stale object is gone
+    assert [p.name for p in tmp_path.glob("*.so")] == [second.split("/")[-1]]

@@ -326,7 +326,7 @@ def _auth_cluster(n, cfg):
 
 
 def test_authenticated_cluster_reaches_consensus():
-    """Positive path: MAC'd frames (incl. relayed catch-up VALs) flow."""
+    """Positive path: MAC'd frames (incl. forwarded catch-up VALs) flow."""
     import time
 
     n = 4
@@ -436,7 +436,7 @@ def test_forged_ready_quorum_over_grpc_does_not_deliver():
                 )
                 # no auth wrapper at all
                 frames.append(body)
-        # the forged VAL itself, relayed by 3 with a valid MAC (val relays
+        # the forged VAL itself, forwarded by 3 with a valid MAC (val forwards
         # are allowed through auth; Bracha still needs a READY quorum)
         val_body = codec.encode_message(
             BroadcastMessage(vertex=ghost, round=1, sender=2, kind="val")

@@ -169,7 +169,7 @@ def test_ladder_wires_pipeline_quarantine_to_next_tier(keys):
     assert rs["poisoned_windows"] >= 1
 
 
-# -- sidecar: retry, failure taxonomy, kill-and-restart -----------------
+# -- sidecar: retry, failure classes, kill-and-restart -----------------
 
 
 def test_remote_retry_distinguishes_transport_from_invalid(keys):
