@@ -2138,10 +2138,6 @@ def _measure() -> None:
     _mark(f"measure: python up (budget {budget:.0f}s), importing jax")
     import jax
 
-    from dag_rider_tpu.utils.jaxcache import enable_persistent_cache
-
-    enable_persistent_cache()
-
     import numpy as np
     import jax.numpy as jnp
 

@@ -1,6 +1,6 @@
 """chip_smoke.py — the served path, once, on the chip.
 
-    python3 chip_smoke.py [--seed N] [--phases ABCD]
+    python3 chip_smoke.py [--seed N]
 
 One process that owns the chip drives the system's main path through the
 entry points a user has and checks what comes out against the host
@@ -10,10 +10,13 @@ the phase functions at n=4 on the CPU backend instead).
 
 - **A** — n=256, threshold-BLS coin, mempool in front, the device
   verifier in the loop: ``Simulation(verifier="device")`` under a seeded
-  open-loop ``ClusterLoadDriver`` on the virtual clock. Accept masks of
-  an honest and an adversarial round must equal ``CPUVerifier``'s bit
-  for bit; every process decides >= 2 waves; every accepted transaction
-  is delivered; nothing on the verify path was contained or retried.
+  open-loop ``ClusterLoadDriver`` on the virtual clock, every knob at
+  its default (so the dispatch bucket is the one the system fixes: n
+  rounded up to a power of two). Accept masks of an honest and an
+  adversarial round must equal ``CPUVerifier``'s bit for bit; every
+  process decides >= 2 waves; every accepted transaction is delivered;
+  one program was compiled; nothing on the verify path was contained
+  or retried.
 - **B** — the deployed layout: this process hosts the sidecar that holds
   the chip, four ``cluster.runner`` OS processes reach it with
   ``"verifier": "remote"`` and never touch the chip themselves.
@@ -21,7 +24,8 @@ the phase functions at n=4 on the CPU backend instead).
   the G1 MSM with the Mosaic tree engine, and the Ed25519 group kernels
   at one 4-D and one 2-D block shape.
 - **D** — four chips (a stated skip with fewer): the sharded verifier
-  and the sharded MSM on a real mesh.
+  (``verify_bucket`` 512, so each shard's 128 rows take the Mosaic
+  tree) and the sharded MSM on a real mesh.
 
 Prints the device line, one JSON line per phase, and last
 ``{"ok": true, "device": {...}}``. Exits non-zero on the first failed
@@ -191,18 +195,18 @@ def adversarial_batch(registry, signers, rnd: int, seed: int):
     return reg, vs + forged
 
 
-def mask_checks(make_verifier, registry, signers, seed: int, bucket: int):
+def mask_checks(make_stack, registry, signers, seed: int):
     """Accept masks at full width against the host oracle: honest rounds
     (enough of them to keep two bucket-sized chunks in flight) and the
-    adversarial batch, each through a depth-K window — the stack a node
-    builds (``VerifierPipeline`` over the device verifier, whose
-    construction compiles the program). Returns (honest verifier,
-    masks)."""
+    adversarial batch, each through ``make_stack(registry)`` — the stack
+    a node builds, a ``VerifierPipeline`` over a device verifier of its
+    own, whose construction fixes the bucket and compiles its program.
+    Returns (honest stack, masks)."""
     from dag_rider_tpu.verifier.cpu import CPUVerifier
-    from dag_rider_tpu.verifier.pipeline import VerifierPipeline
 
-    honest = VerifierPipeline(make_verifier(registry), fixed_bucket=bucket)
-    note(f"program compiled: {honest.verifier.stats()['compile_s']}, "
+    honest = make_stack(registry)
+    bucket = honest.fixed_bucket
+    note(f"bucket {bucket} compiled: {honest.verifier.stats()['compile_s']}, "
          f"tables {honest.verifier.table_build_s:.1f}s")
     rounds = [
         signed_round(signers, r)
@@ -215,7 +219,7 @@ def mask_checks(make_verifier, registry, signers, seed: int, bucket: int):
     note("honest masks equal the CPU oracle")
 
     adv_reg, adv = adversarial_batch(registry, signers, 5, seed)
-    twin = VerifierPipeline(make_verifier(adv_reg), fixed_bucket=bucket)
+    twin = make_stack(adv_reg)
     got_adv = twin.verify_batch(adv)
     want_adv = CPUVerifier(adv_reg).verify_batch(adv)
     check(
@@ -240,7 +244,13 @@ def mask_checks(make_verifier, registry, signers, seed: int, bucket: int):
             "mask checks went through containment",
             stats=st,
         )
-    return honest.verifier, {
+        check(
+            list(pipe.verifier.stats()["compile_s"])
+            == [f"{bucket}x{st['impl']}"],
+            "the stack compiled more than its one program",
+            programs=pipe.verifier.stats()["compile_s"],
+        )
+    return honest, {
         "honest": got,
         "adversarial": got_adv,
         "adversarial_rejected": n_rej,
@@ -257,7 +267,6 @@ def phase_a(
     *,
     n: int = 256,
     seed: int = 0,
-    bucket: int = 256,
     rate: float = 4000.0,
     load_s: float = 0.35,
     dt: float = 0.05,
@@ -273,6 +282,7 @@ def phase_a(
     from dag_rider_tpu.consensus.scenarios import coin_factory
     from dag_rider_tpu.consensus.simulator import Simulation
     from dag_rider_tpu.mempool.loadgen import ClusterLoadDriver, LoadGenerator
+    from dag_rider_tpu.verifier.pipeline import VerifierPipeline
     from dag_rider_tpu.verifier.tpu import TPUVerifier
 
     cfg = Config(n=n, coin="threshold_bls", propose_empty=True, gc_depth=24)
@@ -291,26 +301,31 @@ def phase_a(
     signers = [p.signer for p in sim.processes]
     note(f"simulation built: n={n}")
 
-    # -- the mask, at full width, before any consensus: the honest
-    # rounds through the simulation's own verifier, the adversarial
-    # batch through a twin over the registry with the order-8 keys
-    _, masks = mask_checks(
-        lambda reg: verifier if reg is verifier.registry else TPUVerifier(reg),
-        verifier.registry, signers, seed, bucket,
+    # -- the mask, at full width, before any consensus: through what a
+    # node with no "verify_bucket" builds, over verifiers of their own.
+    # The simulation's verifier is not touched until its load is.
+    stack, masks = mask_checks(
+        lambda reg: VerifierPipeline(TPUVerifier(reg)),
+        verifier.registry, signers, seed,
     )
+    probe, bucket = stack.verifier, stack.fixed_bucket
+    check(bucket >= n, "one round does not fit one dispatch", bucket=bucket)
     check(
-        verifier.last_impl == expect_impl and verifier.last_size == bucket,
+        probe.last_impl == expect_impl and probe.last_size == bucket,
         "dispatch did not run the expected program",
-        impl=verifier.last_impl,
-        bucket=verifier.last_size,
+        impl=probe.last_impl,
+        bucket=probe.last_size,
     )
-    mask, count = verifier.dispatch_batch(signed_round(signers, 1))
+    mask, count = probe.dispatch_batch(signed_round(signers, 1))
     on = sorted(d.platform for d in mask.devices())
     check(on == [expect_platform], "mask not on the device", devices=on)
-    verifier.resolve_batch((mask, count))
+    probe.resolve_batch((mask, count))
 
     # -- the served path under load -----------------------------------
-    base = verifier.stats()
+    check(
+        verifier.fixed_bucket is None and not verifier.stats()["compile_s"],
+        "the simulation's verifier was touched before its load",
+    )
     gen = LoadGenerator(
         clients=32, rate=rate, tx_bytes=32, seed=seed, profile="poisson"
     )
@@ -346,19 +361,23 @@ def phase_a(
         audit=audit,
     )
 
+    # everything the simulation's verifier ever did, it did in the loop
     stats = verifier.stats()
-    d_disp = stats["dispatches"] - base["dispatches"]
-    d_sigs = stats["sigs_dispatched"] - base["sigs_dispatched"]
-    check(d_disp > 0, "the verifier dispatched nothing")
+    check(stats["dispatches"] > 0, "the verifier dispatched nothing")
     check(
-        d_sigs >= n * (min_round - 1),
+        stats["sigs_dispatched"] >= n * (min_round - 1),
         "fewer signatures dispatched than rounds run",
-        sigs=d_sigs,
+        sigs=stats["sigs_dispatched"],
         rounds=min_round - 1,
     )
+    # the bucket the system fixed by itself, and the one program for it
     check(
-        stats["impl"] == expect_impl and stats["bucket"] == bucket,
-        "in-loop dispatch left the expected program",
+        verifier.fixed_bucket == bucket
+        and stats["impl"] == expect_impl
+        and stats["bucket"] == bucket
+        and list(stats["compile_s"]) == [f"{bucket}x{expect_impl}"],
+        "in-loop dispatch left the one expected program",
+        fixed_bucket=verifier.fixed_bucket,
         stats=stats,
     )
     pipe = sim._verify_pipe
@@ -366,11 +385,6 @@ def phase_a(
     window = pipe.stats()
     quiet = {k: window[k] for k in QUIET}
     check(not any(quiet.values()), "contained or retried", **quiet)
-    check(
-        list(stats["compile_s"]) == [f"{bucket}x{expect_impl}"],
-        "more than the one program was compiled",
-        programs=list(stats["compile_s"]),
-    )
     mem = jax.devices()[0].memory_stats() or {}
     return {
         "phase": "A",
@@ -380,6 +394,7 @@ def phase_a(
         "device_kind": stats["device_kind"],
         "impl": stats["impl"],
         "bucket": bucket,
+        "bucket_set_by": "default",
         "decided_waves_min": min(decided),
         "decided_waves_max": max(decided),
         "rounds": rounds,
@@ -387,16 +402,22 @@ def phase_a(
         "delivered": audit["delivered"],
         "lost": audit["lost"],
         "duplicates": audit["duplicates"],
-        "dispatches": d_disp,
-        "sigs_dispatched": d_sigs,
+        "dispatches": stats["dispatches"],
+        "sigs_dispatched": stats["sigs_dispatched"],
         **quiet,
         "masks_equal_cpu": True,
         "adversarial_rejected": masks["adversarial_rejected"],
         "order8_forgeries_accepted": masks["order8_forgeries_accepted"],
-        "table_build_s": stats["table_build_s"],
-        "compile_s": stats["compile_s"],
+        "table_build_s": probe.stats()["table_build_s"],
+        "compile_s": probe.stats()["compile_s"],
+        "compile_s_from_cache": stats["compile_s"],
         "wall_s_per_round": round(wall / max(1, rounds), 3),
         "load_and_settle_s": round(wall, 1),
+        # host seconds inside that wall, on the host's clock: filling the
+        # transfer arrays, blocked on a mask, and the whole verify seam
+        "in_loop_prepare_s": stats["prepare_s"],
+        "in_loop_wait_s": window["wait_s"],
+        "in_loop_seam_s": window["seam_s"],
         "peak_bytes_in_use": mem.get("peak_bytes_in_use"),
     }
 
@@ -606,10 +627,12 @@ def group_kernel_checks(lanes: int, interpret: bool = False) -> None:
 
 def phase_c(*, seed: int = 0, msm_t: int = 128, expect_impl: str = "pallas") -> dict:
     from dag_rider_tpu.ops import bls_msm
+    from dag_rider_tpu.parallel.mesh import make_mesh
     from dag_rider_tpu.parallel.msm import ShardedMSM
 
-    # what a node reaches with "coin_msm": "device" / "cert_msm": "device"
-    sm = ShardedMSM()
+    # what a node reaches with "coin_msm": "device" / "cert_msm":
+    # "device", held to one chip whatever the host has (phase D shards)
+    sm = ShardedMSM(make_mesh(1))
     impl = bls_msm.msm_impl(msm_t // sm.n_shards)
     check(impl == expect_impl, "MSM tree engine", impl=impl)
     t0 = time.monotonic()
@@ -660,15 +683,20 @@ def phase_d(
     from dag_rider_tpu.parallel.msm import ShardedMSM
     from dag_rider_tpu.parallel.sharded_verifier import ShardedTPUVerifier
     from dag_rider_tpu.verifier.base import KeyRegistry, VertexSigner
+    from dag_rider_tpu.verifier.pipeline import VerifierPipeline
     from dag_rider_tpu.verifier.tpu import TPUVerifier
 
     mesh = make_mesh(chips)
     registry, seeds = KeyRegistry.generate(n)
     signers = [VertexSigner(s) for s in seeds]
-    sv, sharded = mask_checks(
-        lambda reg: ShardedTPUVerifier(reg, mesh),
-        registry, signers, seed, bucket,
+    # a node's "verifier": "sharded" with "verify_bucket": bucket
+    stack, sharded = mask_checks(
+        lambda reg: VerifierPipeline(
+            ShardedTPUVerifier(reg, mesh), fixed_bucket=bucket
+        ),
+        registry, signers, seed,
     )
+    sv = stack.verifier
     check(sv.mesh_devices == chips, "mesh size", mesh=sv.mesh_devices)
     check(
         sv.last_impl == expect_impl and sv.last_size == bucket,
@@ -691,8 +719,12 @@ def phase_d(
             and len(tab.sharding.device_set) == chips,
             "comb tables not replicated on every chip",
         )
-    # the same rounds on one chip, through the single-chip program
-    _, single = mask_checks(TPUVerifier, registry, signers, seed, bucket // 2)
+    # the same rounds on one chip, through the single-chip stack at its
+    # default bucket
+    _, single = mask_checks(
+        lambda reg: VerifierPipeline(TPUVerifier(reg)),
+        registry, signers, seed,
+    )
     k = len(single["honest"])  # the smaller bucket needed fewer rounds
     check(
         sharded["honest"][:k] == single["honest"]
@@ -711,6 +743,7 @@ def phase_d(
         "n": n,
         "mesh_devices": sv.mesh_devices,
         "bucket": bucket,
+        "bucket_set_by": "verify_bucket",
         "shard_rows": bucket // chips,
         "impl": sv.last_impl,
         "mask_devices": chips,
@@ -733,9 +766,6 @@ def _on_deadline(_sig, _frame):
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument(
-        "--phases", default="ABCD", help="subset to run, e.g. D (default all)"
-    )
     args = ap.parse_args(argv)
 
     import jax
@@ -756,19 +786,14 @@ def main(argv=None) -> int:
         return 2
 
     from dag_rider_tpu.utils import native
-    from dag_rider_tpu.utils.jaxcache import enable_persistent_cache
 
     signal.signal(signal.SIGALRM, _on_deadline)
     signal.alarm(DEADLINE_S)
-    enable_persistent_cache()
     native.load()  # built here, from the tracked source; raises if it cannot
-    phases = {"A": phase_a, "B": phase_b, "C": phase_c, "D": phase_d}
-    for name in "ABCD":
-        if name not in args.phases.upper():
-            continue
+    for name, phase in zip("ABCD", (phase_a, phase_b, phase_c, phase_d)):
         t0 = time.monotonic()
         try:
-            line = phases[name](seed=args.seed)
+            line = phase(seed=args.seed)
         except Exception as e:  # noqa: BLE001 — reported, then fatal
             traceback.print_exc()
             print(

@@ -21,10 +21,10 @@ Config (JSON):
   "keys": "keys.json",            // from keygen
   "rbc": true,                     // Bracha reliable broadcast stage
   "verifier": "device",            // | "sharded" | "cpu" | "remote" | "none"
-  "verify_bucket": 16384,          // optional: fixed dispatch bucket
+  "verify_bucket": 512,            // optional: dispatch bucket (default:
+                                   // n rounded up to a power of two)
   "verify_depth": 2,               // optional: in-flight dispatch window
   "verify_prep_workers": 4,        // optional: parallel host-prep workers
-  "verify_warmup": true,           // AOT-compile the bucket at startup
   "verify_fallback": "cpu",        // optional: degradation-ladder floor
                                    // under device/sharded/remote
                                    // (default DAGRIDER_VERIFY_FALLBACK)
@@ -326,18 +326,19 @@ class Node:
             )
 
         if kind in ("device", "sharded"):
-            # Wrap the device verifier (which brings the persistent
-            # compile cache and refuses a CPU backend nobody asked for)
-            # in a depth-K dispatch window whose construction compiles
-            # the program the committee will dispatch — the first
-            # consensus round must not eat a cold XLA compile, and a
-            # program the chip refuses must fail the start-up. "sharded"
-            # shares every knob (verify_bucket/verify_depth/
-            # verify_warmup) and lays the batch over a device mesh sized
-            # by DAGRIDER_MESH (virtual devices under JAX_PLATFORMS=cpu
-            # — parallel/mesh.py); its bucket rounds up to a mesh
-            # multiple internally, masks stay byte-identical to the
-            # single-chip program.
+            # Wrap the device verifier (which refuses a CPU backend
+            # nobody asked for) in a depth-K dispatch window whose
+            # construction fixes the bucket — verify_bucket, else n
+            # rounded up to a power of two — and compiles its program.
+            # Every later batch is padded or chunked to that shape, so
+            # the first consensus round eats no XLA compile, a program
+            # the chip refuses fails the start-up, and nothing compiles
+            # under the pump loop's catch-all. "sharded" shares every
+            # knob (verify_bucket/verify_depth) and lays the batch over
+            # a device mesh sized by DAGRIDER_MESH (virtual devices
+            # under JAX_PLATFORMS=cpu — parallel/mesh.py); its bucket
+            # rounds up to a mesh multiple internally, masks stay
+            # byte-identical to the single-chip program.
             from dag_rider_tpu.verifier.pipeline import VerifierPipeline
             from dag_rider_tpu.verifier.tpu import TPUVerifier
 
@@ -351,8 +352,6 @@ class Node:
             else:
                 base = TPUVerifier(reg)
             bucket = cfg.get("verify_bucket")
-            if bucket:
-                base.fixed_bucket = int(bucket)
             # parallel host-prep engine (verifier/prep.py): explicit
             # config beats the DAGRIDER_PREP_WORKERS env default
             prep = cfg.get("verify_prep_workers")
@@ -362,7 +361,7 @@ class Node:
             verifier = VerifierPipeline(
                 base,
                 depth=int(depth) if depth else None,
-                warmup=bool(cfg.get("verify_warmup", True)),
+                fixed_bucket=int(bucket) if bucket else None,
                 log=self.log,
             )
             if fallback:

@@ -3,8 +3,10 @@ compile cache lives, and which platform a device path may run on.
 
 The limb-field/curve programs cost tens of seconds each to compile; the
 test suite, the bench, the smoke, a node and a sidecar all want the same
-cache so repeated runs skip XLA entirely. One helper, so the rule cannot
-drift apart between them.
+cache so repeated runs skip XLA entirely. One rule, applied in one
+place: ``dag_rider_tpu/ops/__init__.py`` calls
+:func:`enable_persistent_cache` when the first device module loads;
+nothing else does.
 """
 
 from __future__ import annotations

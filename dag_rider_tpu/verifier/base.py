@@ -173,10 +173,11 @@ class VerifierUnavailableError(RuntimeError):
 
 class VerifierCompileError(RuntimeError):
     """A device program failed to lower or compile (a Mosaic refusal, a
-    VMEM overflow, an API the installed JAX no longer has). Unlike a
-    runtime fault this never clears on retry and says the deployment is
-    broken, so no containment, quarantine or ladder tier may absorb it:
-    every ``except Exception`` on the verify path re-raises it first."""
+    VMEM overflow, an API the installed JAX no longer has); the message
+    names the shape and the device. It never clears on retry and says
+    the deployment is broken. Serving stacks compile before they take a
+    batch (TPUVerifier.warmup), so this surfaces at construction — never
+    inside a window that contains faults."""
 
 
 class Verifier(abc.ABC):

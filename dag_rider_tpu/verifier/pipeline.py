@@ -23,12 +23,14 @@ fully synchronous chunk loop.
   mask — and therefore the commit order downstream of it — is
   byte-identical to ``verify_batch`` / ``CPUVerifier``
   (tests/test_pipeline.py);
-- **AOT warmup** — construction calls the verifier's :meth:`warmup`,
-  which ``jit(...).lower(...).compile()``-s the program the committee
-  will dispatch, so the first consensus round never eats the XLA compile
-  and a program the chip refuses fails construction. A compile failure
-  (:class:`VerifierCompileError`) is never contained: it propagates out
-  of every window below.
+- **one program, compiled outside the window** — construction calls the
+  verifier's :meth:`warmup`, which fixes the bucket (the one given, else
+  the verifier's, else the committee's n rounded to its bucket) and
+  ``jit(...).lower(...).compile()``-s its program, so the first
+  consensus round never eats the XLA compile and a program the chip
+  refuses fails construction. Every window asks again before it opens
+  (a lookup once compiled), so the containment below never sees a
+  compile.
 
 The mask is still a pure function of (vertex bytes, registry); the
 pipeline only changes WHEN the host blocks, never WHAT it computes.
@@ -43,7 +45,7 @@ from typing import Callable, Deque, List, Optional, Sequence
 from dag_rider_tpu import config
 from dag_rider_tpu.core.types import Vertex
 from dag_rider_tpu.utils.slog import NOOP, EventLog
-from dag_rider_tpu.verifier.base import Verifier, VerifierCompileError
+from dag_rider_tpu.verifier.base import Verifier
 
 
 def default_depth() -> int:
@@ -126,8 +128,10 @@ class VerifierPipeline(Verifier):
         self.last_wait_s = 0.0
         self.last_max_depth = 0
         self.warmup_compile_s = 0.0
-        if warmup and hasattr(verifier, "warmup"):
-            self.warmup_compile_s = verifier.warmup()
+        # warmup=False leaves the compile to the first window's _warm()
+        # (tests that build a pipeline they may never drive)
+        if warmup:
+            self._warm()
 
     # -- passthroughs: tune the wrapped verifier through the pipeline ----
 
@@ -145,11 +149,17 @@ class VerifierPipeline(Verifier):
 
     # -- window mechanics ------------------------------------------------
 
+    def _warm(self) -> None:
+        """Fix the bucket and compile its program (TPUVerifier.warmup; a
+        lookup once done). Called outside every ``except`` below, so a
+        compile failure propagates instead of poisoning a window."""
+        warm = getattr(self.verifier, "warmup", None)
+        if callable(warm):
+            self.warmup_compile_s += warm()
+
     def _dispatch(self, chunk: Sequence[Vertex]) -> None:
         try:
             handle = self.verifier.dispatch_batch(chunk)
-        except VerifierCompileError:
-            raise
         except Exception:  # noqa: BLE001 — prep/dispatch fault contained
             self._contain(chunk, failed_first=False)
             return
@@ -162,8 +172,6 @@ class VerifierPipeline(Verifier):
         _dispatch, prep already paid."""
         try:
             handle = self.verifier.dispatch_prepped(prepped)
-        except VerifierCompileError:
-            raise
         except Exception:  # noqa: BLE001 — dispatch fault contained
             self._contain(chunk, failed_first=False)
             return
@@ -219,8 +227,6 @@ class VerifierPipeline(Verifier):
             if self.quarantine_verifier is not None:
                 return self.quarantine_verifier.verify_batch(vs)
             return self.verifier.verify_batch(vs)
-        except VerifierCompileError:
-            raise
         except Exception:  # noqa: BLE001 — second failure fail-closes
             self.quarantine_rejected += 1
             return [False] * len(vs)
@@ -296,6 +302,7 @@ class VerifierPipeline(Verifier):
         device keeps crunching round r+1's tail while the host pumps
         round r+2 — the depth-K window spans round boundaries rather
         than re-filling from empty each cycle."""
+        self._warm()
         t0 = time.perf_counter()
         self.last_wait_s = 0.0
         self.last_max_depth = len(self._inflight)

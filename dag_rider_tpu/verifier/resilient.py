@@ -48,7 +48,7 @@ from typing import List, Optional, Sequence
 from dag_rider_tpu import config
 from dag_rider_tpu.core.types import Vertex
 from dag_rider_tpu.utils.slog import NOOP, EventLog
-from dag_rider_tpu.verifier.base import Verifier, VerifierCompileError
+from dag_rider_tpu.verifier.base import Verifier
 
 
 def default_verify_retry() -> int:
@@ -201,10 +201,6 @@ class ResilientVerifier(Verifier):
             for attempt in range(self.retries + 1):
                 try:
                     out = call(tier)
-                except VerifierCompileError:
-                    # a program the chip refuses never clears on retry,
-                    # and falling to the CPU floor would hide it
-                    raise
                 except Exception as e:  # noqa: BLE001 — any tier failure
                     # falls through the ladder; validity is never implied
                     last_exc = e

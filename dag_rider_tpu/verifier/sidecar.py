@@ -28,11 +28,7 @@ import grpc
 
 from dag_rider_tpu.core import codec
 from dag_rider_tpu.core.types import Vertex
-from dag_rider_tpu.verifier.base import (
-    Verifier,
-    VerifierCompileError,
-    VerifierUnavailableError,
-)
+from dag_rider_tpu.verifier.base import Verifier, VerifierUnavailableError
 
 _METHOD = "/dagrider.Verifier/VerifyBatch"
 _identity = lambda b: b  # noqa: E731
@@ -69,12 +65,7 @@ class _VerifyHandler(grpc.GenericRpcHandler):
                 context.abort(
                     grpc.StatusCode.INVALID_ARGUMENT, "malformed batch"
                 )
-            try:
-                mask = self._backend.verify_batch(batch)
-            except VerifierCompileError as e:
-                # not a transport fault: the client must not retry it or
-                # take it to a CPU tier (RemoteVerifier re-raises it)
-                context.abort(grpc.StatusCode.FAILED_PRECONDITION, str(e))
+            mask = self._backend.verify_batch(batch)
             return bytes(1 if ok else 0 for ok in mask)
 
         return grpc.unary_unary_rpc_method_handler(
@@ -92,7 +83,6 @@ class VerifierSidecarServer:
         backend: Verifier,
         listen_addr: str = "127.0.0.1:0",
         *,
-        warmup: bool = True,
         prep_workers: Optional[int] = None,
     ):
         from concurrent import futures
@@ -102,13 +92,15 @@ class VerifierSidecarServer:
         # before warmup so the first prep builds the right pool.
         if prep_workers is not None and hasattr(backend, "prep_workers"):
             backend.prep_workers = int(prep_workers)
-        # Device-backed sidecars compile the program their committee
-        # will dispatch BEFORE the port opens, so the first VerifyBatch
-        # RPC never eats a cold XLA compile and a program the chip
-        # refuses fails the start-up. Host-only backends (CPUVerifier
-        # oracle) have no warmup — no jax import.
+        # Device-backed sidecars fix their bucket and compile its
+        # program BEFORE the port opens (TPUVerifier.warmup): the first
+        # VerifyBatch RPC never eats a cold XLA compile, a program the
+        # chip refuses fails the start-up, and no RPC — whose handler
+        # would turn an exception into a status the client retries —
+        # ever compiles. Host-only backends (CPUVerifier oracle) have no
+        # warmup — no jax import.
         self.warmup_compile_s = 0.0
-        if warmup and hasattr(backend, "warmup"):
+        if hasattr(backend, "warmup"):
             self.warmup_compile_s = backend.warmup()
         # one worker: device dispatches serialize anyway, and a single
         # thread keeps per-backend batching deterministic.
@@ -225,11 +217,7 @@ class RemoteVerifier(Verifier):
         for attempt in range(self._retries + 1):
             try:
                 mask = self._invoke(payload)
-            except grpc.RpcError as e:
-                if e.code() == grpc.StatusCode.FAILED_PRECONDITION:
-                    raise VerifierCompileError(e.details()) from e
-                self.rpc_failures += 1
-            except VerifierUnavailableError:
+            except (grpc.RpcError, VerifierUnavailableError):
                 self.rpc_failures += 1
             else:
                 if len(mask) == len(vertices):

@@ -36,10 +36,7 @@ from dag_rider_tpu import config
 from dag_rider_tpu.core.types import Vertex
 from dag_rider_tpu.crypto import ed25519
 from dag_rider_tpu.ops import curve, field
-from dag_rider_tpu.utils.jaxcache import (
-    device_platform,
-    enable_persistent_cache,
-)
+from dag_rider_tpu.utils.jaxcache import device_platform
 from dag_rider_tpu.verifier.base import (
     KeyRegistry,
     Verifier,
@@ -270,8 +267,17 @@ class TPUVerifier(Verifier):
     Runs on jax's default backend. The CPU backend is accepted only when
     ``JAX_PLATFORMS`` names ``cpu`` (the tests do); a CPU backend jax fell
     back to because the TPU failed to initialise raises at construction
-    (utils/jaxcache.device_platform). Every user gets the persistent
-    compile cache (utils/jaxcache.py).
+    (utils/jaxcache.device_platform).
+
+    Two ways to run it. Raw, ``fixed_bucket`` unset: every batch pads to
+    its own power-of-two bucket and each new shape compiles on first
+    use — the bench's merged dispatches and the tests. Serving:
+    :meth:`warmup` fixes one bucket and compiles its program, and from
+    then on every batch is padded or chunked to that shape, so nothing
+    compiles after start-up. Every stack that contains faults
+    (VerifierPipeline, the sidecar, a node through either) is of the
+    second kind: a program the chip refuses fails construction, never a
+    window that would turn it into a rejected batch.
     """
 
     def __init__(self, registry: KeyRegistry, comb: Optional[bool] = None):
@@ -284,7 +290,6 @@ class TPUVerifier(Verifier):
         if comb is None:
             comb = config.env_flag("DAGRIDER_COMB")
         self._comb = comb
-        enable_persistent_cache()
         if _native_enabled():
             # build/load now: a broken toolchain fails construction,
             # not a prep inside the fault-contained window
@@ -633,11 +638,11 @@ class TPUVerifier(Verifier):
 
     def _program(self, size: int, impl: str):
         """The compiled comb program for a padded dispatch of ``size``
-        rows, built (tables included) on first use. This is the ONLY
-        place the served path compiles, and it runs before the dispatch
-        it serves is booked, so a lowering or compile failure leaves as
-        :class:`VerifierCompileError` — which no containment absorbs —
-        instead of as a rejected batch."""
+        rows, built (tables included) on first use of the shape — the
+        only place the verifier compiles. A serving stack has been
+        through :meth:`warmup`, so for it this is a lookup; a lowering
+        or compile failure is a :class:`VerifierCompileError` naming the
+        shape and the device."""
         key = self._aot_key(size, impl)
         exe = self._aot.get(key)
         if exe is None:
@@ -655,24 +660,28 @@ class TPUVerifier(Verifier):
         return exe
 
     def warmup(self, bucket: Optional[int] = None) -> float:
-        """Compile the device program the committee will dispatch —
-        ``bucket``, else the fixed bucket, else one round of the
-        registry's n vertices rounded to its bucket — and keep it for
-        dispatch_batch.
+        """Fix the dispatch bucket and compile its program: ``bucket``,
+        else the bucket already fixed, else one round of the registry's
+        n vertices rounded to its bucket.
 
-        Run at construction time (VerifierPipeline), node startup, and
-        VerifierSidecarServer startup so the first consensus round never
-        eats the XLA compile, and so a program the chip refuses fails the
-        start-up instead of the first round; with the persistent cache
-        the lower+compile is a disk hit after the first ever run.
-        Returns the seconds spent (cumulative in ``warmup_compile_s``).
-        The windowed (comb=False) oracle path keeps its lazy jit cache —
-        it is never on the hot path."""
+        What every serving stack does before it takes a batch
+        (VerifierPipeline at construction and again before each window
+        opens, VerifierSidecarServer before its port opens, a node
+        through either). From here on every batch is padded, or chunked
+        (verify_batch, verify_rounds), to this one shape, so the first
+        consensus round never eats the XLA compile and nothing compiles
+        inside a fault-contained window: a program the chip refuses
+        raises here. With the persistent cache the lower+compile is a
+        disk hit after the first ever run. Returns the seconds spent
+        (cumulative in ``warmup_compile_s``); 0.0 when the program is
+        already there. The windowed (comb=False) oracle path keeps its
+        lazy jit cache — it is never on the hot path."""
         if not self._comb:
             return 0.0
-        size = self._round_bucket(
-            int(bucket or self.fixed_bucket or _bucket(self.registry.n))
+        self.fixed_bucket = int(
+            bucket or self.fixed_bucket or _bucket(self.registry.n)
         )
+        size = self._round_bucket(self.fixed_bucket)
         impl = self._select_impl(size)
         key = self._aot_key(size, impl)
         if key in self._aot:
@@ -697,10 +706,11 @@ class TPUVerifier(Verifier):
     total_dispatches: int = 0
     total_sigs_dispatched: int = 0
 
-    #: When set, every dispatch pads to exactly this bucket (and
-    #: verify_rounds chunks larger merges into it) — ONE compiled program
-    #: shape for a whole consensus run, instead of a power-of-two ladder
-    #: of ~35 s XLA compiles as burst sizes wander (bench ladder sim64).
+    #: When set (warmup() sets it), every dispatch pads to exactly this
+    #: bucket and verify_batch/verify_rounds chunk larger batches into
+    #: it — ONE compiled program shape for a whole consensus run,
+    #: instead of a power-of-two ladder of XLA compiles as burst sizes
+    #: wander.
     fixed_bucket: Optional[int] = None
 
     #: Explicit A/B switch for the async seam. False forces every
@@ -863,8 +873,6 @@ class TPUVerifier(Verifier):
             if self.quarantine_verifier is not None:
                 return self.quarantine_verifier.verify_batch(vs)
             return self._resolve_timed(self.dispatch_batch(vs))
-        except VerifierCompileError:
-            raise
         except Exception:  # noqa: BLE001 — second failure fail-closes
             self.quarantine_rejected += 1
             return [False] * len(vs)
@@ -942,9 +950,9 @@ class TPUVerifier(Verifier):
         A prep/dispatch/resolve exception is CONTAINED, not propagated
         (round 9): the window is salvaged, the staging ring re-armed,
         and the failing chunk quarantined (_contain_stream) — the merge
-        always returns a full mask, wedging nothing upstream. The one
-        exception is :class:`VerifierCompileError`, which always
-        propagates.
+        always returns a full mask, wedging nothing upstream. The
+        bucket's program is compiled before the window opens, so a
+        compile failure is not among the faults it can see.
         """
         lens = [len(r) for r in rounds]
         flat = [v for r in rounds for v in r]
@@ -954,6 +962,7 @@ class TPUVerifier(Verifier):
         if cap and len(flat) > cap:
             from collections import deque
 
+            self.warmup()  # every chunk below runs this one program
             depth = self.pipeline_depth if self.pipeline_enabled else 1
             chunks = [flat[i : i + cap] for i in range(0, len(flat), cap)]
             inflight: deque = deque()  # (pending handle, chunk) FIFO
@@ -990,8 +999,6 @@ class TPUVerifier(Verifier):
                             inflight.append(
                                 (self.dispatch_prepped(prepped), chunk)
                             )
-                        except VerifierCompileError:
-                            raise
                         except Exception:  # noqa: BLE001 — dispatch fault
                             mask.extend(
                                 self._contain_stream(
@@ -1009,8 +1016,6 @@ class TPUVerifier(Verifier):
                         mask.extend(self._resolve_stream(inflight))
                     try:
                         inflight.append((self.dispatch_batch(chunk), chunk))
-                    except VerifierCompileError:
-                        raise
                     except Exception:  # noqa: BLE001 — prep/dispatch fault
                         mask.extend(
                             self._contain_stream(
@@ -1049,4 +1054,7 @@ class TPUVerifier(Verifier):
         # they label the host-prep vs device-dispatch split per round.
         if not vertices:
             return []
+        if self.fixed_bucket and len(vertices) > self.fixed_bucket:
+            # more than the one program holds: stream it in chunks
+            return self.verify_rounds([vertices])[0]
         return self._resolve_timed(self.dispatch_batch(vertices))

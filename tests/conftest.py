@@ -21,15 +21,12 @@ import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-# Persistent compilation cache: the limb-field/curve programs cost ~20s+
-# each to compile on CPU; caching them under the repo makes repeated suite
-# runs (and the driver's) skip the XLA compile entirely.
+# The persistent compilation cache (the limb-field/curve programs cost
+# ~20s+ each to compile on CPU) comes with the package: importing
+# dag_rider_tpu.ops switches it on (utils/jaxcache.py).
 import sys  # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-from dag_rider_tpu.utils.jaxcache import enable_persistent_cache  # noqa: E402
-
-enable_persistent_cache()
 
 # Dynamic lock-race harness (round 14, analysis/races.py): under
 # DAGRIDER_RACE=1 every package lock is order-tracked (deadlock cycles

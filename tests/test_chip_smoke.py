@@ -38,7 +38,6 @@ def test_script_refuses_to_run_without_a_tpu():
 def test_phase_a_rehearsal():
     line = chip_smoke.phase_a(
         n=4,
-        bucket=16,
         rate=2000.0,
         load_s=0.1,
         dt=0.01,
@@ -52,7 +51,10 @@ def test_phase_a_rehearsal():
     assert line["decided_waves_min"] >= 2
     assert line["lost"] == line["duplicates"] == 0
     assert line["masks_equal_cpu"] and line["order8_forgeries_accepted"] >= 2
+    assert line["bucket"] == 16 and line["bucket_set_by"] == "default"
     assert list(line["compile_s"]) == ["16xjnp"]
+    assert list(line["compile_s_from_cache"]) == ["16xjnp"]
+    assert line["in_loop_seam_s"] >= line["in_loop_wait_s"] >= 0.0
     for k in ("poisoned_windows", "quarantined", "retries", "fallbacks"):
         assert line[k] == 0
 

@@ -2,10 +2,12 @@
 
 The rules PR 21 put in place, each checked on the CPU backend: where the
 compile cache goes, which platform a device path accepts, what a mesh
-shortfall does, that a compile failure is never contained, and that the
-verifier says where it ran.
+shortfall does, that every serving stack compiles its one program before
+it takes a batch (so no fault-contained window ever sees a compile), and
+that the verifier says where it ran.
 """
 
+import dataclasses
 import json
 import os
 import subprocess
@@ -18,7 +20,6 @@ from dag_rider_tpu.utils import jaxcache
 from dag_rider_tpu.utils.slog import EventLog
 from dag_rider_tpu.verifier import (
     CPUVerifier,
-    ResilientVerifier,
     VerifierCompileError,
     VerifierPipeline,
 )
@@ -34,7 +35,7 @@ def keys():
 
 
 def _signed(keys, count=8):
-    _, seeds = keys
+    seeds = keys[1]
     signers = [VertexSigner(s) for s in seeds]
     return [
         signers[j % 4].sign_vertex(
@@ -54,8 +55,7 @@ def _signed(keys, count=8):
 _CACHE_PROBE = """
 import json, os, sys
 import jax, jax.numpy as jnp
-from dag_rider_tpu.utils.jaxcache import enable_persistent_cache
-enable_persistent_cache()
+import dag_rider_tpu.ops  # the one place that switches the cache on
 jax.jit(lambda x: x * 3 + 1)(jnp.arange(7)).block_until_ready()
 print(json.dumps({"dir": jax.config.jax_compilation_cache_dir}))
 """
@@ -148,63 +148,133 @@ def test_mesh_shortfall_is_an_error_on_an_accelerator(monkeypatch):
         mesh.mesh_from_env()
 
 
-# -- a compile failure is never contained ------------------------------
+# -- serving stacks compile before they take a batch -------------------
 
 
 def _refuse(self, size, impl, tables, b_tab):
     raise RuntimeError("Mosaic failed to compile TPU kernel: not implemented")
 
 
-def test_compile_error_escapes_every_containment(keys, monkeypatch):
+def _node_config(keys_path, **over):
+    return {
+        "index": 0,
+        "n": 4,
+        "listen": "127.0.0.1:0",
+        "peers": {},
+        "keys": str(keys_path),
+        "rbc": False,
+        "verifier": "device",
+        "coin": "round_robin",
+        **over,
+    }
+
+
+@pytest.fixture
+def keys_path(tmp_path):
+    from dag_rider_tpu import node as node_mod
+
+    path = tmp_path / "keys.json"
+    node_mod.main(["keygen", "--n", "4", "--threshold", "2", "--out", str(path)])
+    return path
+
+
+def test_compile_error_fails_construction_not_a_window(keys, monkeypatch):
+    from dag_rider_tpu.verifier.sidecar import VerifierSidecarServer
+
     reg, _ = keys
     vs = _signed(keys)
     monkeypatch.setattr(TPUVerifier, "_aot_lower", _refuse)
 
-    # the window: neither a False mask nor a quarantine
-    pipe = VerifierPipeline(TPUVerifier(reg), warmup=False)
+    # the stacks a node, a Simulation and a sidecar build
     with pytest.raises(VerifierCompileError, match="Mosaic failed"):
+        VerifierPipeline(TPUVerifier(reg))
+    with pytest.raises(VerifierCompileError):
+        VerifierSidecarServer(TPUVerifier(reg), "127.0.0.1:0")
+    # a pipeline told to put the compile off still compiles before its
+    # window opens: neither a False mask nor a quarantine
+    pipe = VerifierPipeline(TPUVerifier(reg), warmup=False)
+    with pytest.raises(VerifierCompileError):
         pipe.verify_batch(vs)
     assert pipe.stats()["poisoned_windows"] == 0
     assert pipe.stats()["quarantined"] == 0
-    # construction compiles the committee's program: it fails there
-    with pytest.raises(VerifierCompileError):
-        VerifierPipeline(TPUVerifier(reg))
-    # the verifier's own chunk-streaming window
+    # and so does the verifier's own chunk-streaming window
     v = TPUVerifier(reg)
     v.fixed_bucket = 4
     with pytest.raises(VerifierCompileError):
         v.verify_rounds([vs])
     assert v.poisoned_windows == 0
-    # the ladder: no quiet fall to the CPU floor
-    ladder = ResilientVerifier(
-        [VerifierPipeline(TPUVerifier(reg), warmup=False), CPUVerifier(reg)]
-    )
-    with pytest.raises(VerifierCompileError):
-        ladder.verify_batch(vs)
-    assert ladder.stats()["fallbacks"] == 0 and ladder.stats()["retries"] == 0
 
 
-def test_compile_error_crosses_the_sidecar(keys, monkeypatch):
+def test_node_refuses_to_start_on_a_compile_error(keys_path, monkeypatch):
+    """The pump loop logs and swallows every exception, so a node must
+    never compile under it: the refusal is raised by the constructor
+    (and the cluster runner, which builds the node first, exits
+    non-zero), with or without the CPU ladder under the verifier."""
+    from dag_rider_tpu import node as node_mod
+
+    monkeypatch.setattr(TPUVerifier, "_aot_lower", _refuse)
+    for over in ({}, {"verify_fallback": "cpu"}, {"verify_bucket": 64}):
+        with pytest.raises(VerifierCompileError, match="Mosaic failed"):
+            node_mod.Node(_node_config(keys_path, **over))
+
+
+def test_served_stacks_run_one_program_whatever_the_batch(keys, keys_path):
+    """After construction nothing is left to compile: a batch smaller
+    than the bucket is padded to it, a larger one is chunked into it."""
+    from dag_rider_tpu import node as node_mod
     from dag_rider_tpu.verifier.sidecar import (
         RemoteVerifier,
         VerifierSidecarServer,
     )
 
-    reg, _ = keys
-    backend = TPUVerifier(reg)
-    server = VerifierSidecarServer(backend, "127.0.0.1:0", warmup=False)
-    remote = RemoteVerifier(server.address, retries=2)
+    with open(keys_path) as fh:
+        node_keys = node_mod.load_keys(json.load(fh))
+    pool = _signed(node_keys, 40)
+    pool[7] = dataclasses.replace(pool[7], signature=bytes(64))
+    want = [True] * 40
+    want[7] = False
+    nd = node_mod.Node(_node_config(keys_path))
     try:
-        monkeypatch.setattr(TPUVerifier, "_aot_lower", _refuse)
-        with pytest.raises(VerifierCompileError, match="Mosaic failed"):
-            remote.verify_batch(_signed(keys))
-        assert remote.rpc_failures == 0 and remote.retries_total == 0
+        pipe = nd.process.verifier
+        base = pipe.verifier
+        assert pipe.fixed_bucket == 16 and list(base._aot) == [(16, "jnp", 4)]
+        for lo, hi in ((0, 1), (1, 17), (0, 40)):
+            assert pipe.verify_batch(pool[lo:hi]) == want[lo:hi]
+        assert list(base._aot) == [(16, "jnp", 4)]
+    finally:
+        nd.net.close()
+
+    reg, _ = keys
+    pool = _signed(keys, 40)
+    want = CPUVerifier(reg).verify_batch(pool)
+    backend = TPUVerifier(reg)
+    server = VerifierSidecarServer(backend, "127.0.0.1:0")
+    remote = RemoteVerifier(server.address)
+    try:
+        assert backend.fixed_bucket == 16 and len(backend._aot) == 1
+        assert remote.verify_batch(pool) == want
+        assert remote.verify_batch(pool[:3]) == want[:3]
+        assert len(backend._aot) == 1 and backend.stats()["bucket"] == 16
     finally:
         remote.close()
         server.stop()
 
 
-def test_warmup_compiles_the_committees_shape(monkeypatch):
+def test_simulation_fixes_the_committees_bucket():
+    """Simulation(verifier="device") takes the default path a node
+    takes: one bucket, n rounded up, compiled when the window is built."""
+    from dag_rider_tpu.config import Config
+    from dag_rider_tpu.consensus.simulator import Simulation
+
+    sim = Simulation(Config(n=4, propose_empty=True), verifier="device")
+    v = sim.processes[0].verifier
+    assert v.fixed_bucket is None and not v._aot
+    sim.run(max_messages=64)
+    assert v.fixed_bucket == 16 and list(v._aot) == [(16, "jnp", 4)]
+    assert v.stats()["dispatches"] > 0 and v.stats()["bucket"] == 16
+
+
+def test_warmup_fixes_and_compiles_the_committees_shape(monkeypatch):
     """Not the 16-row minimum bucket: one round of the registry's n
     vertices rounded to its bucket, or the fixed bucket when set."""
     lowered = []
@@ -217,11 +287,14 @@ def test_warmup_compiles_the_committees_shape(monkeypatch):
         lambda self, size, impl, tables, b_tab: lowered.append(size) or object(),
     )
     reg, _ = KeyRegistry.generate(40)
-    TPUVerifier(reg).warmup()
+    default = TPUVerifier(reg)
+    default.warmup()
     pinned = TPUVerifier(reg)
     pinned.fixed_bucket = 16
     pinned.warmup()
     assert lowered == [64, 16]
+    assert (default.fixed_bucket, pinned.fixed_bucket) == (64, 16)
+    assert default.warmup() == 0.0 and lowered == [64, 16]
 
 
 # -- the verifier says where it ran ------------------------------------
@@ -251,26 +324,12 @@ def test_stats_carry_platform_and_counters_unconditionally(keys):
     assert after["bucket"] == 16 and after["poisoned_windows"] == 0
 
 
-def test_node_started_event_names_the_verifier(tmp_path):
+def test_node_started_event_names_the_verifier(keys_path):
     from dag_rider_tpu import node as node_mod
 
-    keys_path = tmp_path / "keys.json"
-    node_mod.main(
-        ["keygen", "--n", "4", "--threshold", "2", "--out", str(keys_path)]
-    )
     events = []
     nd = node_mod.Node(
-        {
-            "index": 0,
-            "n": 4,
-            "listen": "127.0.0.1:0",
-            "peers": {},
-            "keys": str(keys_path),
-            "rbc": False,
-            "verifier": "device",
-            "coin": "round_robin",
-        },
-        log=EventLog(events.append, node=0),
+        _node_config(keys_path), log=EventLog(events.append, node=0)
     )
     try:
         nd.start()
