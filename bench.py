@@ -2235,27 +2235,18 @@ def _measure() -> None:
             return False
         compile_s = time.monotonic() - t0
         _mark(f"{tag}: compile+warm done in {compile_s:.1f}s; timing")
-        from dag_rider_tpu import config as _cfg
-
-        profile_dir = _cfg.env_str("DAGRIDER_PROFILE_DIR")
-        if profile_dir:
-            jax.profiler.start_trace(profile_dir)
-        try:
-            total = 0
-            t0 = time.monotonic()
-            prep_s = 0.0
-            for k, b in enumerate(batches[1 : 1 + timed_rounds]):
-                mask = verifier.verify_batch(b)
-                prep_s += verifier.last_prepare_s
-                total += len(b)
-                if not all(mask):
-                    _mark(f"{tag}: timed batch {k} failed")
-                    return False
-                _mark(f"{tag}: timed batch {k} done")
-            dt = time.monotonic() - t0
-        finally:
-            if profile_dir:
-                jax.profiler.stop_trace()
+        total = 0
+        t0 = time.monotonic()
+        prep_s = 0.0
+        for k, b in enumerate(batches[1 : 1 + timed_rounds]):
+            mask = verifier.verify_batch(b)
+            prep_s += verifier.last_prepare_s
+            total += len(b)
+            if not all(mask):
+                _mark(f"{tag}: timed batch {k} failed")
+                return False
+            _mark(f"{tag}: timed batch {k} done")
+        dt = time.monotonic() - t0
         sigs = total / dt
         _mark(
             f"{tag}: {sigs:,.0f} sigs/s  (host prep {1e3 * prep_s / timed_rounds:.1f}"

@@ -103,8 +103,6 @@ _register("DAGRIDER_MEMPOOL_TTL_S", "float", 60.0,
 _register("DAGRIDER_ADAPTIVE_DEADLINE", "flag", False,
           "drive the batcher's effective deadline from the live "
           "submit->deliver latency histogram (ISSUE 16 tentpole 3)")
-_register("DAGRIDER_PROFILE_DIR", "str", "",
-          "jax.profiler trace output directory for bench runs")
 _register("DAGRIDER_AGG_OUT", "str", "BENCH_r06.json",
           "aggregate-cert bench output path")
 _register("DAGRIDER_MULTICHIP_OUT", "str", "MULTICHIP_r06.json",

@@ -23,6 +23,8 @@ from __future__ import annotations
 import abc
 from typing import Optional
 
+from dag_rider_tpu import obs
+
 
 class CommonCoin(abc.ABC):
     """Leader-election oracle for waves.
@@ -175,6 +177,10 @@ class ThresholdCoin(CommonCoin):
         if self._tried_at.get(wave) == have:
             return  # no new shares since the last failed attempt
         self._tried_at[wave] = have
+        with obs.span("coin.combine"):
+            self._aggregate(wave, keys, shares)
+
+    def _aggregate(self, wave: int, keys, shares: dict) -> None:
         sigma = self._th.aggregate(shares, keys.threshold, msm=self._msm)
         if sigma is not None and self._th.verify_group(
             keys.group_pk, wave, sigma

@@ -40,6 +40,7 @@ from typing import Callable, Deque, Dict, List, Optional, Set
 
 import numpy as np
 
+from dag_rider_tpu import obs
 from dag_rider_tpu.config import Config
 from dag_rider_tpu.consensus.coin import CommonCoin, FixedCoin, RoundRobinCoin
 from dag_rider_tpu.consensus.dag_state import DagState
@@ -61,7 +62,7 @@ from dag_rider_tpu.epoch.manager import (
 )
 from dag_rider_tpu.obs import block_key
 from dag_rider_tpu.transport.base import Transport, resolve_unicast
-from dag_rider_tpu.utils.metrics import Metrics, Timer
+from dag_rider_tpu.utils.metrics import Metrics
 from dag_rider_tpu.utils.slog import NOOP, EventLog
 
 # a_deliver callback: (vertex) — the client-facing output of Algorithm 1.
@@ -104,6 +105,10 @@ class Process:
         #: _order_vertices reconciles and treats divergence as an
         #: invariant violation.
         self.on_deliver_early = on_deliver_early
+        #: called with each non-empty block as it leaves
+        #: ``blocks_to_propose`` for a vertex (a mempool closes its
+        #: submit -> vertex wait there)
+        self.on_propose: Optional[Callable[[Block], None]] = None
         # Structured event log (SURVEY §5 L5; the reference has 3 zap
         # Debug sites — here every state transition emits a typed event).
         # NOOP by default: one attribute test per call site.
@@ -872,7 +877,7 @@ class Process:
             and callable(rc)
             and callable(getattr(self.verifier, "drain", None))
         ):
-            with Timer() as t:
+            with obs.span("pump.verify") as t:
                 ok = rc(batch, hold_tail=True)
             self._verify_owed.extend(batch)
             if ok:
@@ -881,7 +886,7 @@ class Process:
                 ]
                 self.apply_verify_mask(front, ok, t.seconds)
             return
-        with Timer() as t:
+        with obs.span("pump.verify") as t:
             ok = self.verifier.verify_batch(batch)
         self.apply_verify_mask(batch, ok, t.seconds)
 
@@ -893,7 +898,7 @@ class Process:
         of progress left."""
         if not self._verify_owed:
             return False
-        with Timer() as t:
+        with obs.span("pump.verify") as t:
             ok = self.verifier.drain()
         front = [self._verify_owed.popleft() for _ in range(len(ok))]
         self.apply_verify_mask(front, ok, t.seconds)
@@ -1262,24 +1267,27 @@ class Process:
         while progress:
             progress = False
             if self._inbox:
-                self._process_inbox()
+                with obs.span("pump.inbox"):
+                    self._process_inbox()
             if self._cert:
+                with obs.span("pump.cert") as cert:
+                    progress |= self._cert_step()
                 if self.log.enabled:
-                    t0 = _time.perf_counter()
-                    progress |= self._cert_step()
-                    self.log.event(
-                        "phase_cert", dur_s=_time.perf_counter() - t0
-                    )
-                else:
-                    progress |= self._cert_step()
-            self._drain_verify()
-            progress |= self._drain_buffer()
-            progress |= self._try_advance()
+                    self.log.event("phase_cert", dur_s=cert.seconds)
+            with obs.span("pump.insert"):
+                self._drain_verify()
+                progress |= self._drain_buffer()
+            with obs.span("pump.propose"):
+                progress |= self._try_advance()
             if self._pipelined_waves:
-                progress |= self._try_waves_pipelined()
-            progress |= self._retry_pending_waves()
+                with obs.span("pump.wave"):
+                    progress |= self._try_waves_pipelined()
+            if self._pending_waves:
+                with obs.span("pump.wave"):
+                    progress |= self._retry_pending_waves()
             if self.epoch_mgr is not None:
-                progress |= self._epoch_retry_held_waves()
+                with obs.span("pump.wave"):
+                    progress |= self._epoch_retry_held_waves()
                 live = int(self.dag.exists.sum())
                 if live > self.metrics.counters["vertices_live_max"]:
                     self.metrics.counters["vertices_live_max"] = live
@@ -1600,7 +1608,8 @@ class Process:
                 w = r // self.cfg.wave_length
                 if w not in self._waves_tried:
                     self._waves_tried.add(w)
-                    self._try_wave(w)
+                    with obs.span("pump.wave"):
+                        self._try_wave(w)
             if self.epoch_mgr is not None and self.epoch_mgr.hold_round(
                 r + 1, self.cfg.wave_length
             ):
@@ -1617,6 +1626,7 @@ class Process:
                 break  # paper: wait until a block is available
             self.round += 1
             self.metrics.inc("rounds_advanced")
+            obs.count("pump.round_advance")
             self.log.event("round_advance", round=self.round)
             v = self._create_vertex(self.round)
             if self.log.enabled and v.block.transactions:
@@ -1674,6 +1684,8 @@ class Process:
             # (or the payload itself on degrade); plain blocks pass
             # through untouched
             block = self.lanes.materialize(block)
+        if self.on_propose is not None and block.transactions:
+            self.on_propose(block)
         # u.id IS VertexID(rnd-1, u.source) — reuse instead of
         # re-constructing n ids per proposal (a top allocation site of
         # the n=256 host profile)
@@ -1684,9 +1696,10 @@ class Process:
         share = None
         if rnd % self.cfg.wave_length == 0:
             wave = rnd // self.cfg.wave_length
-            share = self.coin.my_share(wave)
-            if share is not None:
-                self.coin.observe_share(wave, self.index, share)
+            with obs.span("coin.share"):
+                share = self.coin.my_share(wave)
+                if share is not None:
+                    self.coin.observe_share(wave, self.index, share)
         v = Vertex(
             id=VertexID(rnd, self.index),
             block=block,
@@ -1701,7 +1714,8 @@ class Process:
                 v, "cert_sig", self.cert_signer.sign_digest(v.digest())
             )
         if self.signer is not None:
-            v = self.signer.sign_vertex(v)
+            with obs.span("sign.vertex"):
+                v = self.signer.sign_vertex(v)
         # Own proposals satisfy the admission gate by construction
         # (strong = the full quorum-checked frontier, weak from the
         # sweep); pre-stamping the gate memo keeps dag.insert and sibling
@@ -1837,6 +1851,12 @@ class Process:
             return  # patience keeps accruing; request fires on cooldown
         self._stuck_steps = 0
         self._sync_last_request = now
+        with obs.span("pump.sync"):
+            self._request_sync()
+
+    def _request_sync(self) -> None:
+        """:meth:`_maybe_request_sync` once patience and cooldown have
+        run out: find the window and ask one peer for it."""
         lo: Optional[int] = None
         # Rounds at/below our GC floor — or the f+1-attested PEER floor —
         # are unservable everywhere (peers refuse pruned windows) and
@@ -2166,67 +2186,66 @@ class Process:
         # Retroactive leader chain (process.go:341-350): walk back through
         # undecided waves, committing every prior leader the current one
         # covers by a strong path.
-        t0 = _time.perf_counter()
-        leaders: Stack[Vertex] = Stack()
-        leaders.push(leader)
-        cur = leader
-        for w in range(wave - 1, self.decided_wave, -1):
-            if not self.coin.ready(w):
-                if self.cfg.wave_round(w, 1) <= self.dag.base_round:
-                    # The coin shares for w live below our GC window
-                    # (after a prune or state transfer), so the leader
-                    # is unknowable here — and every delivery this
-                    # chain link could produce sits at rounds <=
-                    # r1(w) <= base, all floor-excluded at this
-                    # process. Skipping the link keeps the total order
-                    # identical to processes that do walk it.
-                    continue
-                # An IN-WINDOW link whose shares are still in flight:
-                # skipping would diverge the total order (other
-                # processes may commit this leader), so defer the WHOLE
-                # commit and let _retry_pending_waves re-enter once the
-                # shares land — decided_wave is untouched, so the
-                # re-entry redoes the full walk.
-                self._pending_waves.add(wave)
-                self.log.event(
-                    "wave_pending_chain_coin", wave=wave, link=w
-                )
-                return
-            prior = self._wave_leader(w)
-            if prior is not None and (
-                self._leader_path(cur.id, prior.id)
-                if self._vector
-                else self.dag.path(cur.id, prior.id, strong_only=True)
-            ):
-                leaders.push(prior)
-                cur = prior
-        self.decided_wave = wave
-        self.metrics.inc("waves_decided")
-        # interval stamp at DECIDE time — a deferred flush that runs two
-        # waves' ordering walks back-to-back must not record ~0 cadence
-        self.metrics.observe_wave_decided()
-        self.log.event(
-            "wave_decided",
-            wave=wave,
-            leader=leader.source,
-            votes=votes,
-            chain=len(leaders),
-        )
-        if self._eager:
-            # surface the exact canonical chunks NOW, ahead of the
-            # (possibly deferred) on_deliver flush — list(leaders)
-            # iterates in pop order (oldest leader first) without
-            # consuming the stack the flush still owns
-            self._eager_surface(list(leaders), wave)
+        with obs.span("pump.chain") as chain:
+            leaders: Stack[Vertex] = Stack()
+            leaders.push(leader)
+            cur = leader
+            for w in range(wave - 1, self.decided_wave, -1):
+                if not self.coin.ready(w):
+                    if self.cfg.wave_round(w, 1) <= self.dag.base_round:
+                        # The coin shares for w live below our GC window
+                        # (after a prune or state transfer), so the leader
+                        # is unknowable here — and every delivery this
+                        # chain link could produce sits at rounds <=
+                        # r1(w) <= base, all floor-excluded at this
+                        # process. Skipping the link keeps the total order
+                        # identical to processes that do walk it.
+                        continue
+                    # An IN-WINDOW link whose shares are still in flight:
+                    # skipping would diverge the total order (other
+                    # processes may commit this leader), so defer the WHOLE
+                    # commit and let _retry_pending_waves re-enter once the
+                    # shares land — decided_wave is untouched, so the
+                    # re-entry redoes the full walk.
+                    self._pending_waves.add(wave)
+                    self.log.event(
+                        "wave_pending_chain_coin", wave=wave, link=w
+                    )
+                    return
+                prior = self._wave_leader(w)
+                if prior is not None and (
+                    self._leader_path(cur.id, prior.id)
+                    if self._vector
+                    else self.dag.path(cur.id, prior.id, strong_only=True)
+                ):
+                    leaders.push(prior)
+                    cur = prior
+            self.decided_wave = wave
+            self.metrics.inc("waves_decided")
+            # interval stamp at DECIDE time — a deferred flush that runs two
+            # waves' ordering walks back-to-back must not record ~0 cadence
+            self.metrics.observe_wave_decided()
+            self.log.event(
+                "wave_decided",
+                wave=wave,
+                leader=leader.source,
+                votes=votes,
+                chain=len(leaders),
+            )
+            if self._eager:
+                # surface the exact canonical chunks NOW, ahead of the
+                # (possibly deferred) on_deliver flush — list(leaders)
+                # iterates in pop order (oldest leader first) without
+                # consuming the stack the flush still owns
+                self._eager_surface(list(leaders), wave)
         if self.defer_delivery:
             # cur is the oldest leader in the chain — maybe_prune anchors
             # the GC floor on it until the deferred walk flushes.
-            self._deferred_orders.append(
-                (leaders, _time.perf_counter() - t0, cur.round)
-            )
+            self._deferred_orders.append((leaders, chain.seconds, cur.round))
             return
-        self._order_vertices(leaders)
-        self.metrics.observe_wave_commit(_time.perf_counter() - t0)
+        with obs.span("pump.order") as order:
+            self._order_vertices(leaders)
+        self.metrics.observe_wave_commit(chain.seconds + order.seconds)
         self.maybe_prune()
 
     def _eager_surface(self, chain: List[Vertex], wave: int) -> None:
@@ -2327,9 +2346,9 @@ class Process:
         sample, same as the inline path."""
         while self._deferred_orders:
             leaders, partial, _ = self._deferred_orders.popleft()
-            with Timer() as t:
+            with obs.span("pump.order") as order:
                 self._order_vertices(leaders)
-            self.metrics.observe_wave_commit(partial + t.seconds)
+            self.metrics.observe_wave_commit(partial + order.seconds)
         self.maybe_prune()
 
     def maybe_prune(self, floor: Optional[int] = None) -> int:
@@ -2365,6 +2384,11 @@ class Process:
                 floor = min(floor, oldest_round - (gc or 1))
         if floor <= self.dag.base_round:
             return 0
+        with obs.span("pump.prune"):
+            return self._prune_below(floor)
+
+    def _prune_below(self, floor: int) -> int:
+        """:meth:`maybe_prune` once it has a floor above the base."""
         old_base = self.dag.base_round
         removed = self.dag.prune_below(floor)
         shift = self.dag.base_round - old_base

@@ -10,7 +10,6 @@ interleave deliveries to explore asynchrony.
 from __future__ import annotations
 
 import random
-import time
 from typing import Callable, List, Optional
 
 from dag_rider_tpu import obs
@@ -20,7 +19,6 @@ from dag_rider_tpu.consensus.process import Process
 from dag_rider_tpu.core.types import Block, Vertex
 from dag_rider_tpu.transport.base import Transport
 from dag_rider_tpu.transport.memory import InMemoryTransport
-from dag_rider_tpu.utils.metrics import Timer
 from dag_rider_tpu.utils.slog import NOOP
 
 
@@ -337,6 +335,7 @@ class Simulation:
                 mp.observe_delivered(v.block)
 
             p.on_deliver = _deliver
+            p.on_propose = mp.observe_proposed
         return self.mempools
 
     def run(self, max_messages: int = 100_000) -> int:
@@ -407,214 +406,221 @@ class Simulation:
             p.defer_delivery = pipelined
         delivered = 0
         pump_wall = 0.0
-        try:
-            for p in self.processes:
-                p.start()
-            while True:
-                t0 = time.perf_counter()
-                got = pump(max_messages - delivered)
-                cycle_host = time.perf_counter() - t0
-                pump_wall += cycle_host
-                if coalesce:
-                    batches = [p.take_verify_batch() for p in self.processes]
-                    if any(batches):
-                        flat = [v for b in batches for v in b]
-                        # Dedup identical (digest, signature, source)
-                        # entries across the n sibling batches before
-                        # they reach the device: a broadcast vertex
-                        # appears in up to n-1 processes' batches, so a
-                        # coalesced round burst carries n*(n-1) entries
-                        # but only n unique signatures — a real cluster
-                        # spreads those checks over n chips, and one
-                        # chip simulating all n views should pay the
-                        # unique work, not the fan-out. The accept bit
-                        # is a pure function of the key, so every copy
-                        # gets exactly the mask bit it would have
-                        # computed (equivocating or corrupted copies
-                        # differ in digest/signature and stay separate
-                        # entries). Per-process metrics still count
-                        # APPLIED signatures; the verifier's breakdown
-                        # counts what the device actually dispatched.
-                        if self.dedup:
-                            uniq, inv = self._dedup(flat)
-                        else:
-                            uniq, inv = flat, []
-                        if pipelined:
-
-                            def _overlap():
-                                # deferred delivery walks, overlapped
-                                # with the in-flight tail
-                                for p in self.processes:
-                                    p.flush_deliveries()
-
-                            umask = pipe.run_coalesced(
-                                uniq, overlap=_overlap
-                            )
-                            # seam wall time excludes the overlapped
-                            # delivery flush (flush_deliveries already
-                            # observes it into the wave-commit metric —
-                            # charging it here too would double-count);
-                            # the pipeline books its resolve waits into
-                            # the verifier's cumulative breakdown itself.
-                            # NOTE (ADVICE r5 #1): with the window open,
-                            # the resolve waits the pipeline books as
-                            # device time are a LOWER BOUND — device
-                            # execution that completes under the flush
-                            # window (or under later chunks' host prep)
-                            # never blocks resolve and reads ~0 there,
-                            # so verifier_breakdown's device_s
-                            # understates true device occupancy on
-                            # pipelined runs.
-                            verify_s = pipe.last_seam_s
-                        else:
-                            with Timer() as t:
-                                # chunked, synchronous (verify_rounds
-                                # splits uniq at the fixed bucket; a
-                                # pipeline_enabled=False verifier keeps
-                                # its streaming window at depth 1)
-                                umask = [
-                                    m
-                                    for ms in shared.verify_rounds([uniq])
-                                    for m in ms
-                                ]
-                            verify_s = t.seconds
-                        if self.log.enabled:
-                            self.log.event(
-                                "phase_verify",
-                                dur_s=verify_s,
-                                batch=len(flat),
-                            )
-                        mask = [umask[j] for j in inv] if inv else umask
-                        # Attribute the merged dispatch time size-
-                        # proportionally and skip empty batches — charging
-                        # every process the full wall time would corrupt
-                        # per-process sigs_per_sec / p50 metrics. The
-                        # window gauges fan out the same way.
-                        total = len(flat)
-                        pos = 0
-                        # latest host-prep engine gauges, fanned out to
-                        # every participating process below
-                        ps = (
-                            shared.prep_stats()
-                            if callable(getattr(shared, "prep_stats", None))
-                            else None
-                        )
-                        # round-9 resilience gauges: from the window when
-                        # pipelined, else from the shared verifier itself
-                        # (a ResilientVerifier ladder takes the sync
-                        # verify_rounds path — its pipelining lives inside
-                        # the device tier). Fanned out when the stack IS
-                        # a ladder (zeros are meaningful there) or once
-                        # any fault was actually absorbed — a clean
-                        # non-resilient run keeps its snapshot unchanged.
-                        rs_fn = getattr(
-                            pipe if pipelined else shared,
-                            "resilience_stats",
-                            None,
-                        )
-                        rs = rs_fn() if callable(rs_fn) else None
-                        if rs is not None and not (
-                            hasattr(shared, "tier_health")
-                            or rs.get("retries")
-                            or rs.get("fallbacks")
-                            or rs.get("poisoned_windows")
-                            or rs.get("quarantined")
-                            or rs.get("sidecar_rpc_failures")
-                        ):
-                            rs = None
-                        for p, b in zip(self.processes, batches):
-                            if b:
-                                share = len(b) / total
-                                p.apply_verify_mask(
-                                    b,
-                                    mask[pos : pos + len(b)],
-                                    verify_s * share,
-                                )
+        # a full collection over n views' heap is a stall worth a name;
+        # watched from here on, so that a device verifier's compile above
+        # (its own garbage, outside any pump.run) is not the pump's
+        obs.spans.watch_gc()
+        with obs.span("pump.run"):
+            try:
+                for p in self.processes:
+                    p.start()
+                while True:
+                    with obs.span("pump.deliver") as deliver:
+                        got = pump(max_messages - delivered)
+                    cycle_host = deliver.seconds
+                    pump_wall += cycle_host
+                    if coalesce:
+                        with obs.span("pump.collect"):
+                            batches = [p.take_verify_batch() for p in self.processes]
+                        if any(batches):
+                            with obs.span("pump.collect"):
+                                flat = [v for b in batches for v in b]
+                                # Dedup identical (digest, signature, source)
+                                # entries across the n sibling batches before
+                                # they reach the device: a broadcast vertex
+                                # appears in up to n-1 processes' batches, so a
+                                # coalesced round burst carries n*(n-1) entries
+                                # but only n unique signatures — a real cluster
+                                # spreads those checks over n chips, and one
+                                # chip simulating all n views should pay the
+                                # unique work, not the fan-out. The accept bit
+                                # is a pure function of the key, so every copy
+                                # gets exactly the mask bit it would have
+                                # computed (equivocating or corrupted copies
+                                # differ in digest/signature and stay separate
+                                # entries). Per-process metrics still count
+                                # APPLIED signatures; the verifier's breakdown
+                                # counts what the device actually dispatched.
                                 if self.dedup:
-                                    # per-process verify timings are
-                                    # AMORTIZED under the dedup'd shared
-                                    # verifier: each process is charged
-                                    # its size-proportional share of one
-                                    # union dispatch, so the n series do
-                                    # not sum to n independent verify
-                                    # costs (ADVICE r5 #2)
-                                    p.metrics.mark_verify_amortized()
-                                if ps is not None:
-                                    p.metrics.observe_prep(
-                                        ps["workers"],
-                                        ps["parallel_fraction"],
-                                    )
-                                if rs is not None:
-                                    p.metrics.observe_resilience(
-                                        rs.get("retries", 0),
-                                        rs.get("fallback_tier", 0),
-                                        rs.get("quarantined", 0),
-                                        sidecar_health=rs.get(
-                                            "sidecar_health"
-                                        ),
-                                        rpc_failures=rs.get(
-                                            "sidecar_rpc_failures", 0
-                                        ),
-                                    )
-                                if pipelined:
-                                    p.metrics.observe_verify_queue_depth(
-                                        pipe.last_max_depth
-                                    )
-                                    p.metrics.observe_verify_overlap(
-                                        pipe.last_wait_s * share,
-                                        verify_s * share,
-                                    )
-                                if getattr(shared, "mesh_devices", 0):
-                                    # mesh-sharded dispatch: how evenly
-                                    # the cycle's last chunk filled the
-                                    # shards (ShardedTPUVerifier gauge)
-                                    p.metrics.observe_shard_imbalance(
-                                        shared.last_shard_imbalance
-                                    )
-                                pos += len(b)
-                            # empty batches advance nothing
-                t0 = time.perf_counter()
-                for p in self.processes:
-                    p.step()
-                step_wall = time.perf_counter() - t0
-                pump_wall += step_wall
-                cycle_host += step_wall
-                if self.log.enabled:
-                    # per-cycle host-pump phase span (delivery + steps)
-                    self.log.event(
-                        "phase_pump", dur_s=cycle_host, msgs=got
-                    )
-                if got == 0 or delivered + got >= max_messages:
+                                    uniq, inv = self._dedup(flat)
+                                else:
+                                    uniq, inv = flat, []
+                            if pipelined:
+
+                                def _overlap():
+                                    # deferred delivery walks, overlapped
+                                    # with the in-flight tail
+                                    for p in self.processes:
+                                        p.flush_deliveries()
+
+                                umask = pipe.run_coalesced(
+                                    uniq, overlap=_overlap
+                                )
+                                # seam wall time excludes the overlapped
+                                # delivery flush (flush_deliveries already
+                                # observes it into the wave-commit metric —
+                                # charging it here too would double-count);
+                                # the pipeline books its resolve waits into
+                                # the verifier's cumulative breakdown itself.
+                                # NOTE (ADVICE r5 #1): with the window open,
+                                # the resolve waits the pipeline books as
+                                # device time are a LOWER BOUND — device
+                                # execution that completes under the flush
+                                # window (or under later chunks' host prep)
+                                # never blocks resolve and reads ~0 there,
+                                # so verifier_breakdown's device_s
+                                # understates true device occupancy on
+                                # pipelined runs.
+                                verify_s = pipe.last_seam_s
+                            else:
+                                with obs.span("pump.verify") as t:
+                                    # chunked, synchronous (verify_rounds
+                                    # splits uniq at the fixed bucket; a
+                                    # pipeline_enabled=False verifier keeps
+                                    # its streaming window at depth 1)
+                                    umask = [
+                                        m
+                                        for ms in shared.verify_rounds([uniq])
+                                        for m in ms
+                                    ]
+                                verify_s = t.seconds
+                            if self.log.enabled:
+                                self.log.event(
+                                    "phase_verify",
+                                    dur_s=verify_s,
+                                    batch=len(flat),
+                                )
+                            with obs.span("pump.apply"):
+                                mask = [umask[j] for j in inv] if inv else umask
+                                # Attribute the merged dispatch time size-
+                                # proportionally and skip empty batches — charging
+                                # every process the full wall time would corrupt
+                                # per-process sigs_per_sec / p50 metrics. The
+                                # window gauges fan out the same way.
+                                total = len(flat)
+                                pos = 0
+                                # latest host-prep engine gauges, fanned out to
+                                # every participating process below
+                                ps = (
+                                    shared.prep_stats()
+                                    if callable(getattr(shared, "prep_stats", None))
+                                    else None
+                                )
+                                # round-9 resilience gauges: from the window when
+                                # pipelined, else from the shared verifier itself
+                                # (a ResilientVerifier ladder takes the sync
+                                # verify_rounds path — its pipelining lives inside
+                                # the device tier). Fanned out when the stack IS
+                                # a ladder (zeros are meaningful there) or once
+                                # any fault was actually absorbed — a clean
+                                # non-resilient run keeps its snapshot unchanged.
+                                rs_fn = getattr(
+                                    pipe if pipelined else shared,
+                                    "resilience_stats",
+                                    None,
+                                )
+                                rs = rs_fn() if callable(rs_fn) else None
+                                if rs is not None and not (
+                                    hasattr(shared, "tier_health")
+                                    or rs.get("retries")
+                                    or rs.get("fallbacks")
+                                    or rs.get("poisoned_windows")
+                                    or rs.get("quarantined")
+                                    or rs.get("sidecar_rpc_failures")
+                                ):
+                                    rs = None
+                                for p, b in zip(self.processes, batches):
+                                    if b:
+                                        share = len(b) / total
+                                        p.apply_verify_mask(
+                                            b,
+                                            mask[pos : pos + len(b)],
+                                            verify_s * share,
+                                        )
+                                        if self.dedup:
+                                            # per-process verify timings are
+                                            # AMORTIZED under the dedup'd shared
+                                            # verifier: each process is charged
+                                            # its size-proportional share of one
+                                            # union dispatch, so the n series do
+                                            # not sum to n independent verify
+                                            # costs (ADVICE r5 #2)
+                                            p.metrics.mark_verify_amortized()
+                                        if ps is not None:
+                                            p.metrics.observe_prep(
+                                                ps["workers"],
+                                                ps["parallel_fraction"],
+                                            )
+                                        if rs is not None:
+                                            p.metrics.observe_resilience(
+                                                rs.get("retries", 0),
+                                                rs.get("fallback_tier", 0),
+                                                rs.get("quarantined", 0),
+                                                sidecar_health=rs.get(
+                                                    "sidecar_health"
+                                                ),
+                                                rpc_failures=rs.get(
+                                                    "sidecar_rpc_failures", 0
+                                                ),
+                                            )
+                                        if pipelined:
+                                            p.metrics.observe_verify_queue_depth(
+                                                pipe.last_max_depth
+                                            )
+                                            p.metrics.observe_verify_overlap(
+                                                pipe.last_wait_s * share,
+                                                verify_s * share,
+                                            )
+                                        if getattr(shared, "mesh_devices", 0):
+                                            # mesh-sharded dispatch: how evenly
+                                            # the cycle's last chunk filled the
+                                            # shards (ShardedTPUVerifier gauge)
+                                            p.metrics.observe_shard_imbalance(
+                                                shared.last_shard_imbalance
+                                            )
+                                        pos += len(b)
+                                    # empty batches advance nothing
+                    with obs.span("pump.step") as step:
+                        for p in self.processes:
+                            p.step()
+                    pump_wall += step.seconds
+                    cycle_host += step.seconds
+                    if self.log.enabled:
+                        # per-cycle host-pump phase span (delivery + steps)
+                        self.log.event(
+                            "phase_pump", dur_s=cycle_host, msgs=got
+                        )
+                    if got == 0 or delivered + got >= max_messages:
+                        delivered += got
+                        break
                     delivered += got
-                    break
-                delivered += got
-        finally:
-            for p in self.processes:
-                p.defer_steps = False
-                if pipelined:
-                    p.flush_deliveries()
-                    p.defer_delivery = False
-            # chaos observability: a FaultyTransport's injected-fault
-            # counters land in every process's snapshot next to the
-            # verifier resilience gauges
-            tstats = getattr(self.transport, "stats", None)
-            if isinstance(tstats, dict):
+            finally:
                 for p in self.processes:
-                    p.metrics.observe_transport_faults(tstats)
-            # Host-pump accounting (ISSUE 8): CLUSTER-level delivered
-            # messages and pump+step wall seconds, mirrored to every
-            # process (same convention as the fault stats) — so
-            # pump_msgs_per_s reads cluster throughput; the per-round
-            # gauge divides by each process's own rounds_advanced.
-            if delivered:
-                for p in self.processes:
-                    p.metrics.observe_pump(
-                        delivered,
-                        pump_wall,
-                        "vector"
-                        if getattr(p, "_vector", False)
-                        else "scalar",
-                    )
+                    p.defer_steps = False
+                    if pipelined:
+                        p.flush_deliveries()
+                        p.defer_delivery = False
+                # chaos observability: a FaultyTransport's injected-fault
+                # counters land in every process's snapshot next to the
+                # verifier resilience gauges
+                tstats = getattr(self.transport, "stats", None)
+                if isinstance(tstats, dict):
+                    for p in self.processes:
+                        p.metrics.observe_transport_faults(tstats)
+                # Host-pump accounting (ISSUE 8): CLUSTER-level delivered
+                # messages and pump+step wall seconds, mirrored to every
+                # process (same convention as the fault stats) — so
+                # pump_msgs_per_s reads cluster throughput; the per-round
+                # gauge divides by each process's own rounds_advanced.
+                if delivered:
+                    for p in self.processes:
+                        p.metrics.observe_pump(
+                            delivered,
+                            pump_wall,
+                            "vector"
+                            if getattr(p, "_vector", False)
+                            else "scalar",
+                        )
         return delivered
 
     # -- assertions for tests ---------------------------------------------

@@ -36,7 +36,7 @@ from dag_rider_tpu.core.types import Block
 from dag_rider_tpu.mempool.admission import AdmissionController
 from dag_rider_tpu.mempool.batcher import BlockBatcher
 from dag_rider_tpu.mempool.pool import TransactionPool
-from dag_rider_tpu.obs import block_key, sample_tx, tx_key
+from dag_rider_tpu.obs import block_key, sample_tx, spans, tx_key
 from dag_rider_tpu.utils.slog import NOOP, EventLog
 
 __all__ = [
@@ -272,6 +272,23 @@ class Mempool:
             prev_ms=round(prev, 3),
             p50_ms=round(p50_ms, 3),
         )
+
+    def observe_proposed(
+        self, block: Block, now: Optional[float] = None
+    ) -> None:
+        """``Process.on_propose`` callback: the block has left the
+        proposal queue for a vertex. Books ``mempool.wait`` — from the
+        earliest submit stamp of its transactions to now — once per
+        block that carries one of ours."""
+        with self._lock:
+            known = self._inflight
+            first = min(
+                (known[tx] for tx in block.transactions if tx in known),
+                default=None,
+            )
+            if first is not None:
+                t = self.clock() if now is None else now
+                spans.record("mempool.wait", int(max(0.0, t - first) * 1e9))
 
     def observe_delivered(
         self, block: Block, now: Optional[float] = None

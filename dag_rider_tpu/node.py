@@ -496,6 +496,7 @@ class Node:
                     metrics=self.process.metrics,
                     log=self.process.log,
                 )
+                self.process.on_propose = self.mempool.observe_proposed
             self.net.attach_metrics(self.process.metrics)
             if self.tracing is not None:
                 self.tracing.flight.add_metrics_source(

@@ -9,6 +9,12 @@ cost therefore collapses to the ``EventLog.event`` attribute test when
 the knob is off, and commit order is unaffected either way (events
 observe; they never feed consensus state).
 
+Timing has one primitive, always on: :func:`span` / :func:`count`
+(``obs/spans.py``). The ``phase_*`` events below take their ``dur_s``
+from a span; the same spans fill the process-wide book
+(``obs.spans.snapshot()``) and, under a ``jax.profiler`` session, the
+device trace's timeline.
+
 Transaction sampling is a pure function of the payload
 (``crc32(tx) / 2**32 < rate``): every process samples the *same*
 transactions with no RNG and no clock, keeping the determinism rules
@@ -24,6 +30,7 @@ from typing import Callable, List, Optional, Tuple
 from dag_rider_tpu.config import env_flag, env_float, env_str
 from dag_rider_tpu.obs.flight import TRIGGERS, FlightRecorder
 from dag_rider_tpu.obs.recorder import TraceRecorder
+from dag_rider_tpu.obs.spans import count, span
 from dag_rider_tpu.utils import slog
 
 __all__ = [
@@ -35,7 +42,9 @@ __all__ = [
     "Tracing",
     "block_key",
     "build_tracing",
+    "count",
     "sample_tx",
+    "span",
     "trace_enabled",
     "tx_key",
 ]

@@ -505,14 +505,3 @@ class Metrics:
             )
         return out
 
-
-class Timer:
-    """Context manager: ``with Timer() as t: ...; t.seconds``."""
-
-    def __enter__(self) -> "Timer":
-        self._start = time.perf_counter()
-        self.seconds = 0.0
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self.seconds = time.perf_counter() - self._start

@@ -38,11 +38,10 @@ pipeline only changes WHEN the host blocks, never WHAT it computes.
 
 from __future__ import annotations
 
-import time
 from collections import deque
 from typing import Callable, Deque, List, Optional, Sequence
 
-from dag_rider_tpu import config
+from dag_rider_tpu import config, obs
 from dag_rider_tpu.core.types import Vertex
 from dag_rider_tpu.utils.slog import NOOP, EventLog
 from dag_rider_tpu.verifier.base import Verifier
@@ -198,13 +197,13 @@ class VerifierPipeline(Verifier):
             # older than anything in _inflight by construction
             return self._salvaged.popleft()
         handle, chunk = self._inflight.popleft()
-        t0 = time.perf_counter()
-        try:
-            out = self.verifier.resolve_batch(handle)
-        except Exception:  # noqa: BLE001 — resolve fault contained
-            self._contain(chunk, failed_first=True)
-            out = self._salvaged.popleft()
-        dt = time.perf_counter() - t0
+        with obs.span("verify_batch.resolve") as resolve:
+            try:
+                out = self.verifier.resolve_batch(handle)
+            except Exception:  # noqa: BLE001 — resolve fault contained
+                self._contain(chunk, failed_first=True)
+                out = self._salvaged.popleft()
+        dt = resolve.seconds
         self.wait_s += dt
         self.last_wait_s += dt
         # device share of the verifier's cumulative seam breakdown (its
@@ -303,7 +302,15 @@ class VerifierPipeline(Verifier):
         round r+2 — the depth-K window spans round boundaries rather
         than re-filling from empty each cycle."""
         self._warm()
-        t0 = time.perf_counter()
+        with obs.span("seam.window") as window:
+            mask, overlap_s = self._stream(vertices, overlap, hold_tail)
+        self.last_seam_s = max(0.0, window.seconds - overlap_s)
+        self.seam_s += self.last_seam_s
+        return mask
+
+    def _stream(self, vertices, overlap, hold_tail):
+        """:meth:`run_coalesced` inside its span: the mask, and the
+        seconds ``overlap()`` took."""
         self.last_wait_s = 0.0
         self.last_max_depth = len(self._inflight)
         # pipeline_enabled off (bench's sync A/B side) caps the window at
@@ -364,15 +371,13 @@ class VerifierPipeline(Verifier):
                 self._dispatch(chunk)
         overlap_s = 0.0
         if overlap is not None:
-            t1 = time.perf_counter()
-            overlap()
-            overlap_s = time.perf_counter() - t1
+            with obs.span("seam.overlap") as overlapped:
+                overlap()
+            overlap_s = overlapped.seconds
         keep = max(0, depth - 1) if hold_tail else 0
         while self._pending() > keep:
             mask.extend(self._resolve_oldest())
-        self.last_seam_s = max(0.0, (time.perf_counter() - t0) - overlap_s)
-        self.seam_s += self.last_seam_s
-        return mask
+        return mask, overlap_s
 
     # -- Verifier interface ----------------------------------------------
 

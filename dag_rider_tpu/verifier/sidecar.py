@@ -26,6 +26,7 @@ from typing import List, Optional, Sequence
 
 import grpc
 
+from dag_rider_tpu import obs
 from dag_rider_tpu.core import codec
 from dag_rider_tpu.core.types import Vertex
 from dag_rider_tpu.verifier.base import Verifier, VerifierUnavailableError
@@ -53,20 +54,37 @@ def _decode_batch(payload: bytes) -> List[Vertex]:
 class _VerifyHandler(grpc.GenericRpcHandler):
     def __init__(self, backend: Verifier):
         self._backend = backend
+        #: when the last ``unary`` returned, on the spans' clock; the
+        #: server has one worker thread, so from there to the next entry
+        #: is gRPC's share and nothing else (``sidecar.between_rpcs``)
+        self._left_ns: Optional[int] = None
 
     def service(self, handler_call_details):
         if handler_call_details.method != _METHOD:
             return None
 
-        def unary(request: bytes, context) -> bytes:
+        def serve(request: bytes, context) -> bytes:
             try:
-                batch = _decode_batch(request)
+                with obs.span("sidecar.decode"):
+                    batch = _decode_batch(request)
             except ValueError:
                 context.abort(
                     grpc.StatusCode.INVALID_ARGUMENT, "malformed batch"
                 )
             mask = self._backend.verify_batch(batch)
             return bytes(1 if ok else 0 for ok in mask)
+
+        def unary(request: bytes, context) -> bytes:
+            if self._left_ns is not None:
+                obs.spans.record(
+                    "sidecar.between_rpcs",
+                    obs.spans.clock_ns() - self._left_ns,
+                )
+            try:
+                with obs.span("sidecar.rpc"):
+                    return serve(request, context)
+            finally:
+                self._left_ns = obs.spans.clock_ns()
 
         return grpc.unary_unary_rpc_method_handler(
             unary, request_deserializer=_identity, response_serializer=_identity
@@ -102,6 +120,9 @@ class VerifierSidecarServer:
         self.warmup_compile_s = 0.0
         if hasattr(backend, "warmup"):
             self.warmup_compile_s = backend.warmup()
+        # a full collection over the heap the program's tracing leaves
+        # is a stall worth a name
+        obs.spans.watch_gc()
         # one worker: device dispatches serialize anyway, and a single
         # thread keeps per-backend batching deterministic.
         self._server = grpc.server(futures.ThreadPoolExecutor(max_workers=1))

@@ -32,7 +32,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from dag_rider_tpu import config
+from dag_rider_tpu import config, obs
 from dag_rider_tpu.core.types import Vertex
 from dag_rider_tpu.crypto import ed25519
 from dag_rider_tpu.ops import curve, field
@@ -814,17 +814,14 @@ class TPUVerifier(Verifier):
             size = self._round_bucket(int(self.fixed_bucket))
         else:
             size = self._round_bucket(_bucket(len(vertices)))
-        t0 = time.perf_counter()
-        with jax.profiler.TraceAnnotation("verify_batch.prepare"):
+        with obs.span("verify_batch.prepare") as prepare:
             out = (
                 self._stage(size, 67 if self._comb_bits == 8 else 131)
                 if self._comb
                 else None
             )
             args = self._prepare(vertices, size, comb=self._comb, out=out)
-        return PreppedBatch(
-            args, size, len(vertices), time.perf_counter() - t0
-        )
+        return PreppedBatch(args, size, len(vertices), prepare.seconds)
 
     def prep_batch_async(self, vertices: Sequence[Vertex]):
         """:meth:`prep_batch` queued on the engine's dedicated FIFO seam
@@ -851,7 +848,7 @@ class TPUVerifier(Verifier):
         self.total_dispatches += 1
         self.total_sigs_dispatched += count
         self._note_dispatch(size, count)
-        with jax.profiler.TraceAnnotation("verify_batch.dispatch"):
+        with obs.span("verify_batch.dispatch"):
             if self._comb:
                 u8, i32 = args
                 tables, b_tab = self._comb_tables_dev()
@@ -1042,16 +1039,13 @@ class TPUVerifier(Verifier):
         """resolve_batch plus the device-seconds accounting the seam
         breakdown expects (verify_batch and the chunk-streaming
         verify_rounds both resolve through here)."""
-        t0 = time.perf_counter()
-        out = self.resolve_batch(pending)
-        self.last_dispatch_s = time.perf_counter() - t0
+        with obs.span("verify_batch.resolve") as resolve:
+            out = self.resolve_batch(pending)
+        self.last_dispatch_s = resolve.seconds
         self.total_dispatch_s += self.last_dispatch_s
         return out
 
     def verify_batch(self, vertices: Sequence[Vertex]) -> List[bool]:
-        # Trace annotations are free when no profiler is attached; under
-        # jax.profiler.trace() (bench.py DAGRIDER_PROFILE_DIR / SURVEY §5)
-        # they label the host-prep vs device-dispatch split per round.
         if not vertices:
             return []
         if self.fixed_bucket and len(vertices) > self.fixed_bucket:
