@@ -120,8 +120,9 @@ class VerifierSidecarServer:
         self.warmup_compile_s = 0.0
         if hasattr(backend, "warmup"):
             self.warmup_compile_s = backend.warmup()
-        # a full collection over the heap the program's tracing leaves
-        # is a stall worth a name
+        # full collections from here on walk what the handler makes
+        # (the heap the program's tracing left was frozen by warmup);
+        # they keep their name so that a heap that grows back shows
         obs.spans.watch_gc()
         # one worker: device dispatches serialize anyway, and a single
         # thread keeps per-backend batching deterministic.

@@ -24,6 +24,7 @@ makes CPU-vs-TPU commit order byte-identical (tests/test_verifier_tpu.py).
 from __future__ import annotations
 
 import functools
+import gc
 import hashlib
 import time
 from typing import List, NamedTuple, Optional, Sequence, Tuple
@@ -672,7 +673,11 @@ class TPUVerifier(Verifier):
         consensus round never eats the XLA compile and nothing compiles
         inside a fault-contained window: a program the chip refuses
         raises here. With the persistent cache the lower+compile is a
-        disk hit after the first ever run. Returns the seconds spent
+        disk hit after the first ever run. A call that compiled then
+        collects the heap once and freezes it (``gc.freeze``), so the
+        served path's full collections walk only what it makes itself;
+        a call that found the program there freezes nothing. Returns
+        the seconds spent
         (cumulative in ``warmup_compile_s``); 0.0 when the program is
         already there. The windowed (comb=False) oracle path keeps its
         lazy jit cache — it is never on the hot path."""
@@ -688,6 +693,21 @@ class TPUVerifier(Verifier):
             return 0.0
         self._program(size, impl)
         self.warmup_compile_s += self.compile_s[key]
+        # Tracing the program leaves over a million tracked objects
+        # that nothing will ever free, and whatever else the stack has
+        # built by now is as long-lived. Unfrozen, every full collection
+        # of the served path walks them all (0.35-0.5 s on the chip's
+        # host, PERF.md section 6, PR 27). Frozen, they are in no
+        # generation: later collections walk and count only what was
+        # made after this line. The collection comes first so that no
+        # garbage is frozen with the rest. A cycle that is alive now and
+        # dies later is never reclaimed (reference counts still free
+        # everything else), so this runs only here, where a program was
+        # just compiled: once per program, never per call.
+        gc.collect()
+        held = gc.get_freeze_count()
+        gc.freeze()
+        obs.count("heap.frozen_objects", gc.get_freeze_count() - held)
         return self.compile_s[key]
 
     #: host-prep / device-dispatch seconds of the most recent

@@ -17,7 +17,10 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+import gc  # noqa: E402
+
 import jax  # noqa: E402
+import pytest  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
@@ -43,6 +46,16 @@ if _RACE:
     from dag_rider_tpu.analysis import races as _races  # noqa: E402
 
     _races.install()
+
+
+@pytest.fixture(autouse=True)
+def _unfreeze_heap_after_each_test():
+    """A ``TPUVerifier.warmup`` that compiles freezes the heap
+    (``gc.freeze``), once in a served process's life. A test worker
+    compiles many verifiers: without this, every cycle a test leaves
+    alive at a later test's freeze would stay for the worker's life."""
+    yield
+    gc.unfreeze()
 
 
 def pytest_sessionfinish(session, exitstatus):
