@@ -14,6 +14,7 @@ One workspace directory per cluster run:
         delivery.jsonl      — committed-vertex log (one JSON line each)
         events.jsonl        — structured event log (slog records)
         final.json          — clean-shutdown state report
+        spans.json          — the process's span book at clean shutdown
         ready               — liveness marker (written when serving)
         stdout.log / stderr.log
 
@@ -30,7 +31,7 @@ import json
 import os
 import socket
 from dataclasses import dataclass, field
-from typing import Dict, List, Optional
+from typing import Dict, List, Mapping, Optional, Sequence
 
 #: mempool TTL for cluster runs: the default 60 s is tuned for a live
 #: simulator; across a kill -9 + restart-from-checkpoint window an
@@ -51,6 +52,7 @@ class NodeFiles:
     delivery_log: str
     events_log: str
     final_report: str
+    span_book: str
     ready_marker: str
     stdout: str
     stderr: str
@@ -68,6 +70,7 @@ class NodeFiles:
             delivery_log=os.path.join(wd, "delivery.jsonl"),
             events_log=os.path.join(wd, "events.jsonl"),
             final_report=os.path.join(wd, "final.json"),
+            span_book=os.path.join(wd, "spans.json"),
             ready_marker=os.path.join(wd, "ready"),
             stdout=os.path.join(wd, "stdout.log"),
             stderr=os.path.join(wd, "stderr.log"),
@@ -171,6 +174,8 @@ def build_cluster(
     checkpoint_every_s: float = 0.5,
     adversaries: Optional[Dict[int, dict]] = None,
     wan: Optional[dict] = None,
+    regions: Optional[Sequence[str]] = None,
+    verifiers: Optional[Mapping[int, Mapping[str, str]]] = None,
     node_overrides: Optional[dict] = None,
 ) -> ClusterSpec:
     """Lay out a cluster workspace: keys, addresses, per-node configs.
@@ -178,11 +183,28 @@ def build_cluster(
     ``adversaries`` maps node index -> {"kind": ..., "seed": ...} for
     Byzantine-over-sockets scenarios; ``wan`` is a WanFault config dict
     applied to EVERY node's transport (delay/drop at the real gRPC send
-    seam). ``node_overrides`` merges extra keys into every node config
-    (e.g. {"cert": "agg"} or mempool tuning).
+    seam). ``regions`` names the region of every node, by index: with
+    it ``wan`` carries ``one_way_ms`` (region -> region -> one-way ms)
+    and each node delays a send by its link's entry. ``verifiers`` maps
+    node index -> {"kind": ..., "address": ...}: a node with a chip
+    behind it has {"kind": "remote", "address": <its sidecar's>}; a
+    node not named verifies on the host ({"kind": "cpu"}).
+    ``node_overrides`` merges extra keys into every node config (e.g.
+    {"cert": "agg"} or mempool tuning).
     """
     if n < 4:
         raise ValueError(f"cluster needs n >= 4 (3f+1, f >= 1), got {n}")
+    if regions is not None:
+        if len(regions) != n:
+            raise ValueError(f"{len(regions)} regions for n={n}")
+        if not wan or "one_way_ms" not in wan:
+            raise ValueError('regions need a "wan" with "one_way_ms"')
+        wan = {**wan, "regions": list(regions)}
+    for i, v in (verifiers or {}).items():
+        if not 0 <= i < n:
+            raise ValueError(f"verifier for node {i} of {n}")
+        if v["kind"] == "remote" and not v.get("address"):
+            raise ValueError(f'node {i}: a "remote" verifier needs an address')
     os.makedirs(root, exist_ok=True)
     addrs = allocate_addresses(root, n, transport)
 
@@ -206,6 +228,7 @@ def build_cluster(
     auth_master = _derive_auth_master(seed)
     for i in range(n):
         nf = NodeFiles.for_node(spec.root, i)
+        verifier = (verifiers or {}).get(i, {"kind": "cpu"})
         os.makedirs(nf.workdir, exist_ok=True)
         os.makedirs(nf.checkpoint_dir, exist_ok=True)
         os.makedirs(nf.flight_dir, exist_ok=True)
@@ -218,11 +241,10 @@ def build_cluster(
             "rbc": rbc,
             # cpu: real Ed25519 on every vertex, on the host — what the
             # CPU test lanes run. The runners never own a chip (the
-            # supervisor pins them to JAX_PLATFORMS=cpu); a deployment
-            # with one overrides this with "verifier": "remote" and the
-            # address of the sidecar that holds it (chip_smoke.py
-            # phase B).
-            "verifier": "cpu",
+            # supervisor pins them to JAX_PLATFORMS=cpu): a node with
+            # one behind it is given, in ``verifiers``, "remote" and
+            # the address of the sidecar that holds it.
+            "verifier": verifier["kind"],
             "coin": coin,
             "cert": cert,
             "gc_depth": gc_depth,
@@ -233,6 +255,8 @@ def build_cluster(
             "auth_master": auth_master,
             "snapshot_min_interval_s": 0.2,
         }
+        if verifier.get("address"):
+            node_cfg["verifier_address"] = verifier["address"]
         if wan:
             node_cfg["wan"] = dict(wan)
         if adversaries and i in adversaries:

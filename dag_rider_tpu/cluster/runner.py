@@ -9,16 +9,21 @@ seams the kill -9 chaos suite audits against:
   it once the syscall returns), so every acknowledged transaction is
   recoverable even when the process dies between checkpoints.
 - **Delivery log**: every a_delivered vertex appends one JSON line
-  (round, source, digest, payload hexes, wall stamp) — the audit's
-  commit-order record AND the latency join point for wire-level
-  submit→deliver percentiles.
+  (round, source, digest, payload hexes, wall stamp, and what the
+  vertex attests to besides: strong and weak edges, coin share,
+  signature) — the audit's commit-order record, the latency join point
+  for wire-level submit→deliver percentiles, and enough for a reader
+  that holds only the log to explain the order from the edges and to
+  verify every delivered signature again.
 - **Re-injection**: on restart the WAL is replayed minus what the
   delivery log, the restored checkpoint state (mempool pending, staged
   blocks, DAG payloads), and the supervisor's cluster-delivered hint
   already cover — zero loss without duplicate delivery.
 - **Clean stop**: SIGTERM drains, checkpoints, and writes ``final.json``
   (metrics snapshot + retained transaction set) for the audit's
-  accepted ⊆ delivered ∪ retained accounting.
+  accepted ⊆ delivered ∪ retained accounting, and beside it
+  ``spans.json``: this process's span book (``obs.spans.snapshot()``),
+  which is per process and has to leave it to be read.
 
 Trace ids cross the process boundary for free: the round-16 trace key is
 content-derived (``obs.tx_key`` = crc32 of the transaction bytes), so
@@ -40,9 +45,15 @@ import threading
 import time
 from typing import Callable, Set
 
+from dag_rider_tpu import obs
 from dag_rider_tpu.core.types import Block
 from dag_rider_tpu.node import Node
 from dag_rider_tpu.utils.slog import EventLog
+
+
+#: how long a booting runner waits for its peers' sockets before it
+#: starts its pump without them
+PEER_WAIT_S = 10.0
 
 
 def read_wal(path: str) -> list:
@@ -201,6 +212,10 @@ class NodeRunner:
             "s": vertex.id.source,
             "d": vertex.digest().hex(),
             "tx": txs,
+            "se": [[e.round, e.source] for e in vertex.strong_edges],
+            "we": [[e.round, e.source] for e in vertex.weak_edges],
+            "cs": (vertex.coin_share or b"").hex(),
+            "sig": (vertex.signature or b"").hex(),
         }
         with self._dlog_lock:
             try:
@@ -230,7 +245,7 @@ class NodeRunner:
             # per RPC, so accepted>0 means THE transaction is in. (A
             # dedup hit means a prior ack already covered these bytes.)
             if accepted:
-                with self._wal_lock:
+                with obs.span("wal.append"), self._wal_lock:
                     for tx in txs:
                         self._wal.write(tx.hex() + "\n")
         return json.dumps(
@@ -272,6 +287,14 @@ class NodeRunner:
     # -- lifecycle -----------------------------------------------------
 
     def run(self, duration: float = 0.0) -> int:
+        # The gRPC server is bound since Node construction; the pump
+        # starts once every peer's is (or PEER_WAIT_S have passed: a
+        # peer that is down stays down, a committee booting together is
+        # a few seconds apart). A vertex broadcast to a peer that does
+        # not listen yet is lost after two retries.
+        away = self.node.net.wait_for_peers(PEER_WAIT_S)
+        if away:
+            self.node.log.event("boot_peers_away", peers=away)
         self.node.start()
         # Ready marker AFTER start: the gRPC server is bound during Node
         # construction, the pump is live now — the supervisor's boot
@@ -317,10 +340,13 @@ class NodeRunner:
             "retained": sorted(tx.hex() for tx in retained),
             "metrics": self.node.process.metrics.snapshot(),
         }
-        tmp = self.files["final_report"] + ".tmp"
-        with open(tmp, "w") as fh:
-            json.dump(final, fh)
-        os.replace(tmp, self.files["final_report"])
+        for path, blob in (
+            (self.files["final_report"], final),
+            (self.files["span_book"], obs.spans.snapshot()),
+        ):
+            with open(path + ".tmp", "w") as fh:
+                json.dump(blob, fh)
+            os.replace(path + ".tmp", path)
         for fh in (self._wal, self._dlog, self._events):
             try:
                 fh.close()

@@ -331,6 +331,21 @@ class Config:
     # still extracts at most one window per cooldown.
     sync_request_cooldown_s: float = 0.5
     sync_serve_cooldown_s: float = 0.2
+    # Patience is counted in quiescent step() passes, which a node over
+    # real sockets makes every ~2 ms: over links that take 50-150 ms,
+    # sync_patience passes of silence are every round's ordinary pause,
+    # and under reliable broadcast one request is answered by n-1 peers
+    # re-broadcasting a window each (~n^2 x window frames). With this set
+    # (node.py sets 2.0; 0 = passes alone, the lockstep simulator) a
+    # request also waits until nothing at all has arrived — no delivered
+    # message and, under reliable broadcast, no echo or ready either —
+    # for that many of the process's own recent round times: a silence
+    # measured against the pace the process has itself observed, on a
+    # LAN a few milliseconds and on a WAN a round trip or two — or until it has
+    # heard its peers for four such silences without advancing a round
+    # itself: it is behind them. A process that has not yet advanced two
+    # rounds has no pace of its own and takes a second for one.
+    sync_silence_rounds: float = 0.0
     # Garbage-collection depth in rounds (None = unbounded, matching the
     # reference's grow-forever state, process.go:72-85). When set, the
     # ordering rule deterministically EXCLUDES vertices with

@@ -68,6 +68,10 @@ from dag_rider_tpu.utils.slog import NOOP, EventLog
 # a_deliver callback: (vertex) — the client-facing output of Algorithm 1.
 DeliverCallback = Callable[[Vertex], None]
 
+#: cfg.sync_silence_rounds before a process knows its own round time:
+#: the silence it waits for, in seconds, in place of one
+_NO_PACE_YET_S = 1.0
+
 
 class Process:
     """One DAG-Rider participant."""
@@ -177,6 +181,11 @@ class Process:
         #: _maybe_request_sync (a node still being fed is throttled, not
         #: partitioned)
         self._rx_at_patience = 0
+        #: cfg.sync_silence_rounds: when traffic last reached us, when
+        #: the current round began, and our recent round time (None
+        #: until two rounds have been advanced)
+        self._rx_changed_at = self._round_began_at = _time.monotonic()
+        self._round_s: Optional[float] = None
         self._sync_last_request = float("-inf")
         #: round-robin cursor over peers for pull-based sync requests;
         #: start offset by our index so n stuck nodes don't all probe
@@ -1627,6 +1636,8 @@ class Process:
             self.round += 1
             self.metrics.inc("rounds_advanced")
             obs.count("pump.round_advance")
+            if self.cfg.sync_silence_rounds:
+                self._note_round_time()
             self.log.event("round_advance", round=self.round)
             v = self._create_vertex(self.round)
             if self.log.enabled and v.block.transactions:
@@ -1842,6 +1853,8 @@ class Process:
             # deployment would have: a partitioned node sees silence and
             # correctly keeps accruing toward a sync request.
             self._rx_at_patience = rx
+            if self.cfg.sync_silence_rounds:
+                self._rx_changed_at = _time.monotonic()
             return
         self._stuck_steps += 1
         if self._stuck_steps < self.cfg.sync_patience:
@@ -1849,10 +1862,40 @@ class Process:
         now = _time.monotonic()
         if now - self._sync_last_request < self.cfg.sync_request_cooldown_s:
             return  # patience keeps accruing; request fires on cooldown
+        if self.cfg.sync_silence_rounds:
+            # anything at all from the network counts as being heard: a
+            # reliable-broadcast stage below sees every echo and ready.
+            # A process that hears its peers but has not advanced for
+            # four such silences is behind them, not pausing.
+            silence = self.cfg.sync_silence_rounds * (
+                _NO_PACE_YET_S if self._round_s is None else self._round_s
+            )
+            heard_at = max(
+                self._rx_changed_at,
+                getattr(self.transport, "last_frame_at", 0.0),
+            )
+            if (
+                now - heard_at < silence
+                and now - self._round_began_at < 4.0 * silence
+            ):
+                return  # an ordinary pause at this process's own pace
         self._stuck_steps = 0
         self._sync_last_request = now
         with obs.span("pump.sync"):
             self._request_sync()
+
+    def _note_round_time(self) -> None:
+        """This process's recent round time, for cfg.sync_silence_rounds:
+        a running mean that follows a change of pace within a few
+        rounds."""
+        now = _time.monotonic()
+        if self.round > 1:  # round 1 began when the process was made
+            took = now - self._round_began_at
+            self._round_s = (
+                took if self._round_s is None
+                else 0.75 * self._round_s + 0.25 * took
+            )
+        self._round_began_at = now
 
     def _request_sync(self) -> None:
         """:meth:`_maybe_request_sync` once patience and cooldown have

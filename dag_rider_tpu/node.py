@@ -40,6 +40,12 @@ Config (JSON):
   "cert_msm": "host",              // | "device" | "sharded" — certificate
                                    // aggregation seam (DAGRIDER_CERT_MSM)
 
+  "wan": {"seed": 0,               // optional: delay/drop at the send seam.
+          "regions": ["a", "b", ...],  // a delay per link: every node's
+          "one_way_ms": {"a": {"a": 1, "b": 31}},  // region, the one-way
+          "jitter": 0.02},         // ms between regions, +/- a fraction;
+                                   // or one class: "delay_ms": [lo, hi]
+                                   // ("delay_rate", "drop" either way)
   "checkpoint_dir": "ckpt/node0",  // optional, periodic + on shutdown
   "checkpoint_every_s": 30,
   "submit_interval_s": 0.5,        // synthetic client load (0: none)
@@ -72,6 +78,7 @@ import time
 from collections import deque
 from typing import Deque, Dict, Optional
 
+from dag_rider_tpu import obs
 from dag_rider_tpu.config import Config
 from dag_rider_tpu.consensus.coin import FixedCoin, RoundRobinCoin, ThresholdCoin
 from dag_rider_tpu.consensus.process import Process
@@ -208,6 +215,9 @@ class Node:
             # for share aggregation over real sockets, so the cluster
             # harness overrides it per node
             cert_patience=int(cfg.get("cert_patience", 6)),
+            # the loop below steps every ~2 ms: a sync request waits for
+            # a silence as long as two of this node's own recent rounds
+            sync_silence_rounds=2.0,
         )
         with open(cfg["keys"]) as fh:
             keyblob = json.load(fh)
@@ -219,8 +229,6 @@ class Node:
         # tee the ring recorder and the flight trigger watch into
         # whatever sink the caller brought (e.g. --verbose's stdlib
         # bridge), so pump_error / verify_exhausted leave a post-mortem.
-        from dag_rider_tpu import obs
-
         self.tracing = None
         if obs.trace_enabled():
             self.tracing = obs.build_tracing(
@@ -234,19 +242,39 @@ class Node:
         # is the optional [net] extra — keygen must work without it.
         from dag_rider_tpu.transport.net import GrpcTransport, WanFault
 
-        # WAN emulation at the real send seam (ISSUE 19): the cluster
-        # harness sets {"wan": {"delay_ms": [lo, hi], "delay_rate": p,
-        # "drop": p, "seed": s}} so delay/drop apply to genuine gRPC
-        # sends between OS processes, not a simulator queue. Seed is
-        # offset by index so peers do not fault in lockstep.
+        # WAN emulation at the real send seam (ISSUE 19): delay/drop
+        # apply to genuine gRPC sends between OS processes, not a
+        # simulator queue. {"wan": {"seed": s, "drop": p, "delay_rate":
+        # p, ...}} with either one link class for every peer —
+        # "delay_ms": [lo, hi] — or a delay per link: "regions" (the
+        # region of every node, by index), "one_way_ms" (region ->
+        # region -> one-way ms) and "jitter" (a fraction of the link's
+        # delay, uniform either way). Seed is offset by index so peers
+        # do not fault in lockstep.
         wan = cfg.get("wan")
         send_fault = None
         if wan:
+            links = {}
+            if "one_way_ms" in wan:
+                regions = list(wan["regions"])
+                if len(regions) != n:
+                    raise ValueError(
+                        f'"wan" names {len(regions)} regions for n={n}'
+                    )
+                links = {
+                    "region": regions[index],
+                    "peer_regions": {
+                        j: r for j, r in enumerate(regions) if j != index
+                    },
+                    "one_way_ms": wan["one_way_ms"],
+                    "jitter": float(wan.get("jitter", 0.0)),
+                }
             send_fault = WanFault(
                 seed=int(wan.get("seed", 0)) + index,
                 delay_ms=tuple(wan.get("delay_ms", (0.0, 0.0))),
                 delay_rate=float(wan.get("delay_rate", 1.0)),
                 drop=float(wan.get("drop", 0.0)),
+                **links,
             )
 
         auth = None
@@ -671,9 +699,10 @@ class Node:
                     and now - last_ckpt >= self.ckpt_every
                 ):
                     last_ckpt = now
-                    checkpoint.save(
-                        self.process, self.ckpt_dir, mempool=self.mempool
-                    )
+                    with obs.span("node.checkpoint"):
+                        checkpoint.save(
+                            self.process, self.ckpt_dir, mempool=self.mempool
+                        )
                     self.log.event("checkpointed", round=self.process.round)
             except Exception as e:  # noqa: BLE001 — a BFT node must not
                 # die silently: before this guard, any exception
@@ -704,6 +733,12 @@ class Node:
                 raise
 
     def _pump_once(self) -> None:
+        with obs.span("node.tick"):
+            moved = self._tick()
+        if not moved:
+            time.sleep(0.002)
+
+    def _tick(self) -> int:
         self._drain_submissions()
         if self.mempool is not None:
             # the pump pulls BUILT blocks (size-or-deadline batches), not
@@ -718,8 +753,7 @@ class Node:
             self._state_transfer()
         moved = self.net.pump(256)
         self.process.step()
-        if not moved:
-            time.sleep(0.002)
+        return moved
 
     def _state_transfer(self) -> None:
         """f+1 peers reported GC floors above our round (sync_nack):

@@ -73,6 +73,31 @@ KNOWN_SPANS = frozenset(
         "sidecar.between_rpcs",
         # mempool/ — submit -> vertex, one closed span per block
         "mempool.wait",
+        # node.py — one pass of the validator's loop (its idle sleep
+        # left out)
+        "node.tick",
+        # the periodic checkpoint the same thread writes between passes
+        # (three files and a manifest, an fsync each)
+        "node.checkpoint",
+        # transport/net.py — a logical broadcast on the caller's thread
+        # (encode, MAC, WAN verdict, hand-over), one network attempt
+        # handed to gRPC (a frame, or the frames for one peer that fell
+        # due together), one received frame (MAC check, decode, inbox),
+        # and how long a held frame really waited
+        "net.broadcast",
+        "net.send",
+        "net.recv",
+        "net.delay",
+        # transport/rbc.py — one received frame of reliable broadcast,
+        # by kind (the upward delivery and the votes it sends included)
+        "rbc.val",
+        "rbc.echo",
+        "rbc.ready",
+        # cluster/runner.py — acknowledged transactions into the WAL
+        "wal.append",
+        # verifier/sidecar.py — one RemoteVerifier.verify_batch, encode
+        # to mask
+        "remote.verify",
         # generation-2 collections of the interpreter's garbage
         "host.gc",
     }
@@ -82,6 +107,9 @@ KNOWN_SPANS = frozenset(
 KNOWN_COUNTS = frozenset(
     {
         "pump.round_advance",
+        # transport/net.py — frames handed to gRPC (a ``net.send`` may
+        # carry several)
+        "net.messages",
         # verifier/tpu.py — objects TPUVerifier.warmup took out of the
         # collector's reach after compiling its program
         "heap.frozen_objects",

@@ -19,16 +19,18 @@ stays single-threaded (SURVEY.md D4's fix) — only the inbox is shared.
 
 from __future__ import annotations
 
+import heapq
 import math
 import random
 import struct
 import threading
 import time
 from collections import deque
-from typing import Callable, Deque, Dict, Optional, Tuple
+from typing import Callable, Deque, Dict, List, Mapping, Optional, Tuple
 
 import grpc
 
+from dag_rider_tpu import obs
 from dag_rider_tpu.core import codec
 from dag_rider_tpu.core.types import BroadcastMessage
 from dag_rider_tpu.transport.base import Handler, Transport
@@ -36,10 +38,14 @@ from dag_rider_tpu.utils.metrics import Metrics
 
 _SERVICE = "dagrider.Transport"
 _METHOD = f"/{_SERVICE}/Deliver"
+_MANY_METHOD = f"/{_SERVICE}/DeliverMany"
 _SNAPSHOT_METHOD = f"/{_SERVICE}/Snapshot"
 _SUBMIT_METHOD = f"/{_SERVICE}/Submit"
 
 _identity = lambda b: b  # noqa: E731 — bytes in, bytes out
+
+#: sender threads a transport (each holds one peer's RPC at a time)
+_SENDERS = 8
 
 
 _SNAP_DOMAIN = b"dagrider-snapshot-req-v2"  # v2: timestamped request body
@@ -52,8 +58,17 @@ class WanFault:
     verdict: negative = drop this attempt (the bytes never leave the
     host), positive = hold the attempt for that many seconds before it
     goes out, zero = send immediately. Seeded so a cluster scenario's
-    fault schedule replays; ``delay_ms`` is a (low, high) uniform window
-    and ``rate`` the fraction of attempts delayed at all.
+    fault schedule replays.
+
+    The delay of an attempt is drawn uniformly from the window of its
+    LINK. With ``one_way_ms`` (region -> region -> one-way milliseconds,
+    either direction of a pair filled in), ``region`` (this endpoint's)
+    and ``peer_regions`` (peer index -> region), the link to ``peer`` is
+    the pair of regions and its window the matrix entry +/- ``jitter``
+    (a fraction: 0.02 is +/-2%). Without a matrix every peer shares one
+    link class, whose window is ``delay_ms`` (low, high). ``delay_rate``
+    is the fraction of attempts delayed at all and ``drop`` the fraction
+    lost, on every link alike.
     """
 
     def __init__(
@@ -63,6 +78,10 @@ class WanFault:
         delay_ms: Tuple[float, float] = (0.0, 0.0),
         delay_rate: float = 1.0,
         drop: float = 0.0,
+        region: Optional[str] = None,
+        peer_regions: Optional[Mapping[int, str]] = None,
+        one_way_ms: Optional[Mapping[str, Mapping[str, float]]] = None,
+        jitter: float = 0.0,
     ) -> None:
         lo, hi = float(delay_ms[0]), float(delay_ms[1])
         if lo < 0 or hi < lo:
@@ -71,26 +90,131 @@ class WanFault:
             raise ValueError(f"drop must be in [0, 1], got {drop}")
         if not 0.0 <= delay_rate <= 1.0:
             raise ValueError(f"delay_rate must be in [0, 1], got {delay_rate}")
+        if not 0.0 <= jitter < 1.0:
+            raise ValueError(f"jitter must be in [0, 1), got {jitter}")
         self._rng = random.Random(seed)
         self._delay = (lo, hi)
+        #: peer -> its link's (low, high) window; empty = one link class
+        self._links: Dict[int, Tuple[float, float]] = {}
+        if one_way_ms is not None:
+            if region is None or peer_regions is None:
+                raise ValueError(
+                    "one_way_ms needs this endpoint's region and peer_regions"
+                )
+            for peer, there in peer_regions.items():
+                ms = one_way_ms.get(region, {}).get(there)
+                if ms is None:
+                    ms = one_way_ms.get(there, {}).get(region)
+                if ms is None or ms < 0:
+                    raise ValueError(
+                        f"one_way_ms has no delay for {region!r} <-> {there!r}"
+                    )
+                self._links[int(peer)] = (
+                    ms * (1.0 - jitter),
+                    ms * (1.0 + jitter),
+                )
         self._delay_rate = delay_rate
         self._drop = drop
         self._lock = threading.Lock()
 
+    def window_ms(self, peer: int) -> Tuple[float, float]:
+        """The (low, high) delay window of the link to ``peer``."""
+        if self._links:
+            return self._links[peer]
+        return self._delay
+
     def __call__(self, peer: int) -> float:
-        # _send runs on the owner thread AND retry-timer threads; the
+        # _send runs on the owner thread AND the delay thread; the
         # generator state must not interleave or the seeded schedule
         # stops being a schedule.
         with self._lock:
             if self._drop and self._rng.random() < self._drop:
                 return -1.0
-            lo, hi = self._delay
+            lo, hi = self.window_ms(peer)
             if hi > 0 and (
                 self._delay_rate >= 1.0
                 or self._rng.random() < self._delay_rate
             ):
                 return self._rng.uniform(lo, hi) / 1e3
         return 0.0
+
+
+class _DelayQueue:
+    """Every held attempt of one transport — WAN delays and retry
+    backoffs — in one heap, released by one thread, however many wait
+    (a ``threading.Timer`` each was an OS thread a message: a round of
+    reliable broadcast at n=20 holds 800 at once per validator).
+
+    ``on_due`` gets every item that has fallen due by the time the
+    thread looks, as ``[(held_ns, item), ...]`` in the order they fell
+    due: one at a time on a quiet host, many at once on a loaded one."""
+
+    def __init__(
+        self,
+        on_due: Callable[[List[tuple]], None],
+        clock: Callable[[], float] = time.monotonic,
+    ) -> None:
+        self._on_due = on_due
+        self._clock = clock
+        self._heap: List[tuple] = []
+        self._seq = 0
+        self._cond = threading.Condition()
+        self._closed = False
+        self._thread: Optional[threading.Thread] = None
+
+    def __len__(self) -> int:
+        with self._cond:
+            return len(self._heap)
+
+    def push(self, delay_s: float, item) -> None:
+        self.push_many([(delay_s, item)])
+
+    def push_many(self, held: List[tuple]) -> None:
+        """``held`` is ``[(delay_s, item), ...]``: one turn at the lock
+        and at most one wake-up for a whole fan-out."""
+        now = self._clock()
+        with self._cond:
+            if self._closed or not held:
+                return
+            head = self._heap[0][0] if self._heap else float("inf")
+            for delay_s, item in held:
+                self._seq += 1
+                heapq.heappush(
+                    self._heap, (now + delay_s, self._seq, now, item)
+                )
+            if self._thread is None:
+                self._thread = threading.Thread(
+                    target=self._run, name="net-delay", daemon=True
+                )
+                self._thread.start()
+            if self._heap[0][0] < head:
+                self._cond.notify()  # a new head: wake to re-time
+
+    def _run(self) -> None:
+        while True:
+            with self._cond:
+                while not self._closed:
+                    if not self._heap:
+                        self._cond.wait()
+                        continue
+                    wait = self._heap[0][0] - self._clock()
+                    if wait <= 0:
+                        break
+                    self._cond.wait(wait)
+                if self._closed:
+                    return
+                now = self._clock()
+                due = []
+                while self._heap and self._heap[0][0] <= now:
+                    _, _, pushed, item = heapq.heappop(self._heap)
+                    due.append((int((now - pushed) * 1e9), item))
+            self._on_due(due)
+
+    def close(self) -> None:
+        with self._cond:
+            self._closed = True
+            self._heap.clear()
+            self._cond.notify()
 
 
 class _DeliverHandler(grpc.GenericRpcHandler):
@@ -181,6 +305,25 @@ class _DeliverHandler(grpc.GenericRpcHandler):
 
             return grpc.unary_unary_rpc_method_handler(
                 unary,
+                request_deserializer=_identity,
+                response_serializer=_identity,
+            )
+        if handler_call_details.method == _MANY_METHOD:
+            # Frames that fell due together at the sender (see
+            # GrpcTransport._release), each whole and with its own MAC.
+
+            def many(request: bytes, context) -> bytes:
+                offset = 0
+                while offset < len(request):
+                    item = codec.read_frame(request, offset)
+                    if item is None:
+                        break  # a truncated tail: the whole frames counted
+                    frame, offset = item
+                    self._sink(frame)
+                return b"\x01"
+
+            return grpc.unary_unary_rpc_method_handler(
+                many,
                 request_deserializer=_identity,
                 response_serializer=_identity,
             )
@@ -401,13 +544,17 @@ class GrpcTransport(Transport):
         self._lock = threading.Lock()
         self._inbox: Deque[BroadcastMessage] = deque()
         self._channels: Dict[int, grpc.Channel] = {}
-        self._stubs: Dict[int, Callable] = {}
-        self._inflight: Dict[int, object] = {}
-        self._inflight_seq = 0
+        self._stubs: Dict[int, Tuple[Callable, Callable]] = {}
+        #: frames waiting for a peer's sender, and the peers that have a
+        #: sender at work: one RPC in flight a peer, whatever was handed
+        #: over for it meanwhile goes in the next (see _send_now)
+        self._outbox: Dict[int, List[Tuple[bytes, int]]] = {}
+        self._sending: set = set()
         self._retries = retries
         self._retry_backoff_s = retry_backoff_s
         self._rpc_timeout_s = rpc_timeout_s
-        self._timers: set = set()
+        #: held attempts (WAN delays, retry backoffs): one heap, one thread
+        self._held = _DelayQueue(self._release)
         self._closed = False
         self._snap_req_ts = float("-inf")  # monotone request-ts floor
         #: injected WAN policy (cluster chaos): per-attempt delay/drop
@@ -433,6 +580,10 @@ class GrpcTransport(Transport):
         self._consec_fail: Dict[int, int] = {}
         from concurrent import futures
 
+        #: the senders: threads start as work comes, none before
+        self._senders = futures.ThreadPoolExecutor(
+            max_workers=_SENDERS, thread_name_prefix="net-send"
+        )
         self._server = grpc.server(
             futures.ThreadPoolExecutor(max_workers=max_workers)
         )
@@ -473,6 +624,10 @@ class GrpcTransport(Transport):
     # -- wire ----------------------------------------------------------------
 
     def _on_rpc(self, payload: bytes) -> None:
+        with obs.span("net.recv"):
+            self._receive(payload)
+
+    def _receive(self, payload: bytes) -> None:
         if self._auth is not None:
             # Authenticated frame: <u32 relayer> || codec message || MAC,
             # MAC'd with the (relayer, me) pair key. The relayer is the
@@ -509,17 +664,21 @@ class GrpcTransport(Transport):
             self._inbox.append(msg)
 
     def _stub(self, peer: int):
-        # Called from the owner thread AND retry-timer threads: channel
+        # Called from the owner thread AND the delay thread: channel
         # creation must be locked or two threads can race a first send to
         # the same peer and leak the losing channel.
         with self._lock:
             if peer not in self._stubs:
                 chan = grpc.insecure_channel(self._peers[peer])
                 self._channels[peer] = chan
-                self._stubs[peer] = chan.unary_unary(
-                    _METHOD,
-                    request_serializer=_identity,
-                    response_deserializer=_identity,
+                #: (one frame, many frames)
+                self._stubs[peer] = tuple(
+                    chan.unary_unary(
+                        method,
+                        request_serializer=_identity,
+                        response_deserializer=_identity,
+                    )
+                    for method in (_METHOD, _MANY_METHOD)
                 )
             return self._stubs[peer]
 
@@ -541,22 +700,27 @@ class GrpcTransport(Transport):
         self._handler = None
 
     def broadcast(self, msg: BroadcastMessage) -> None:
+        with obs.span("net.broadcast"):
+            self._fan_out(msg)
+
+    def _fan_out(self, msg: BroadcastMessage) -> None:
         payload = codec.encode_message(msg)
-        if self._auth is not None:
-            prefix = struct.pack("<I", self.index)
-            for peer in sorted(self._peers):
-                if peer == self.index:
-                    continue
-                self._send(
-                    peer,
-                    prefix + payload + self._auth.tag(peer, payload),
-                    attempt=0,
-                )
-            return
+        prefix = struct.pack("<I", self.index) if self._auth is not None else b""
+        held: List[tuple] = []
         for peer in sorted(self._peers):
             if peer == self.index:
                 continue
-            self._send(peer, payload, attempt=0)
+            frame = payload
+            if self._auth is not None:
+                frame = prefix + payload + self._auth.tag(peer, payload)
+            item = self._route(peer, frame, attempt=0)
+            if item is not None:
+                held.append(item)
+        if held:
+            # the whole fan-out's delayed frames in one hand-over
+            with self._lock:
+                self.metrics.inc("net_wan_delays", len(held))
+            self._held.push_many(held)
 
     #: keep this enqueue OUT of honest protocol routing
     #: (base.resolve_unicast): single-copy sync serves over a real
@@ -589,9 +753,12 @@ class GrpcTransport(Transport):
         the fully built node, which needs this transport first."""
         self._submit_fn = fn
 
-    def _send(self, peer: int, payload: bytes, attempt: int) -> None:
+    def _route(self, peer: int, payload: bytes, attempt: int) -> Optional[tuple]:
+        """One attempt through the WAN policy: dropped, or sent at once
+        (None either way), or to be held — then ``(delay_s, item)`` for
+        the delay queue, which the caller hands over."""
         if self._closed:
-            return
+            return None
         if self._send_fault is not None:
             verdict = self._send_fault(peer)
             if verdict < 0:
@@ -600,69 +767,110 @@ class GrpcTransport(Transport):
                 # lossy link is not a down peer, and consensus recovers
                 # through later broadcasts / anti-entropy.
                 self._inc("net_wan_drops")
-                return
+                return None
             if verdict > 0:
-                self._inc("net_wan_delays")
-                timer = threading.Timer(
-                    verdict,
-                    lambda: (
-                        self._timers.discard(timer),
-                        self._send_now(peer, payload, attempt),
-                    ),
-                )
-                timer.daemon = True
-                self._timers.add(timer)
-                timer.start()
-                return
-        self._send_now(peer, payload, attempt)
+                return verdict, (peer, payload, attempt, True)
+        self._send_now(peer, [(payload, attempt)])
+        return None
 
-    def _send_now(self, peer: int, payload: bytes, attempt: int) -> None:
+    def _send(self, peer: int, payload: bytes, attempt: int) -> None:
+        held = self._route(peer, payload, attempt)
+        if held is not None:
+            self._inc("net_wan_delays")
+            self._held.push(*held)
+
+    def _release(self, due: List[tuple]) -> None:
+        """The delay thread's hand-over of everything that fell due
+        together. A backed-off retry goes through ``_send`` again (the
+        WAN has its say on every attempt); what the WAN held goes out,
+        and where several frames for one peer fell due at once — the
+        thread was late: a loaded host — they share one RPC, each whole
+        and with its own MAC. None leaves before its delay has passed."""
+        by_peer: Dict[int, List[Tuple[bytes, int]]] = {}
+        for held_ns, (peer, payload, attempt, delayed) in due:
+            if not delayed:
+                self._send(peer, payload, attempt)
+                continue
+            obs.spans.record("net.delay", held_ns)
+            by_peer.setdefault(peer, []).append((payload, attempt))
+        for peer, frames in by_peer.items():
+            self._send_now(peer, frames)
+
+    def _send_now(self, peer: int, frames: List[Tuple[bytes, int]]) -> None:
+        """Hand ``frames`` — (payload, attempt) each — to the peer's
+        sender. At most one RPC is in flight a peer: frames handed over
+        while one is share the next, so a slow peer is sent fewer,
+        larger RPCs and holds up no other peer's. The senders are a few
+        pooled threads making blocking calls: an asynchronous call costs
+        gRPC a thread of its own each time its channel has gone quiet."""
         if self._closed:
             return
-        self._inc("net_sends")
+        obs.count("net.messages", len(frames))
+        with self._lock:
+            self.metrics.inc("net_sends", len(frames))
+            self._outbox.setdefault(peer, []).extend(frames)
+            if peer in self._sending:
+                return
+            self._sending.add(peer)
         try:
-            # async send; the future must be retained until it settles
-            # (grpc cancels calls whose handle is dropped). Consensus
-            # tolerates drops — a missing vertex only delays admission
-            # until a later broadcast covers it — but every failure is
-            # counted and retried with backoff before giving up.
-            fut = self._stub(peer).future(payload, timeout=self._rpc_timeout_s)
-        except (grpc.RpcError, ValueError):
+            self._senders.submit(self._drain_outbox, peer)
+        except RuntimeError:  # close() shut the pool down meanwhile
+            pass
+
+    def _drain_outbox(self, peer: int) -> None:
+        """A sender's turn at ``peer``: RPC after RPC until nothing is
+        left for it."""
+        while not self._closed:
+            with self._lock:
+                frames = self._outbox.pop(peer, None)
+                if not frames:
+                    self._sending.discard(peer)
+                    return
+            self._call(peer, frames)
+        with self._lock:
+            self._sending.discard(peer)
+
+    def _call(self, peer: int, frames: List[Tuple[bytes, int]]) -> None:
+        """One network attempt, to its answer. Consensus tolerates drops
+        — a missing vertex only delays admission until a later broadcast
+        covers it — but every failure is counted and every frame of a
+        failed attempt retried with backoff before giving up."""
+        try:
+            with obs.span("net.send"):
+                one, many = self._stub(peer)
+                if len(frames) == 1:
+                    one(frames[0][0], timeout=self._rpc_timeout_s)
+                else:
+                    many(
+                        b"".join(codec.frame(p) for p, _ in frames),
+                        timeout=self._rpc_timeout_s,
+                    )
+        except (grpc.RpcError, ValueError) as exc:
             # ValueError: update_peer closed the cached channel between
-            # _stub() and .future() — same remedy as an RPC error (the
+            # _stub() and the call — same remedy as an RPC error (the
             # retry re-resolves through _stub, which builds the new
             # channel)
-            self._on_failure(peer, payload, attempt)
+            if self._closed:
+                # close() cancels what is in flight; a clean shutdown
+                # must not leave the counter signature of a flaky peer
+                return
+            # which failure, for whoever reads the counters: a deadline
+            # that passed is a loaded peer, UNAVAILABLE one that is gone
+            code = getattr(exc, "code", None)
+            self._inc(
+                "net_rpc_"
+                + (code().name.lower() if callable(code) else "closed_channel")
+            )
+            for payload, attempt in frames:
+                self._on_failure(peer, payload, attempt)
             return
         with self._lock:
-            self._inflight_seq += 1
-            key = self._inflight_seq
-            self._inflight[key] = fut
-        fut.add_done_callback(
-            lambda f, k=key, p=peer, a=attempt: self._on_done(f, k, p, payload, a)
-        )
-
-    def _on_done(self, fut, key: int, peer: int, payload: bytes, attempt: int) -> None:
-        with self._lock:
-            self._inflight.pop(key, None)
-        if self._closed:
-            # close() cancels in-flight calls; a clean shutdown must not
-            # leave the counter signature of a flaky peer behind.
-            return
-        try:
-            exc = fut.exception()
-        except Exception:  # cancelled: treat as failure
-            exc = fut
-        if exc is None:
-            with self._lock:
-                self.metrics.inc("net_sends_ok")
-                was_down = self._consec_fail.get(peer, 0) >= self.down_after
-                self._consec_fail[peer] = 0
-            if was_down:
-                self._inc("net_peer_recovered")
-                self.log.event("net_peer_recovered", peer=peer)
-            return
-        self._on_failure(peer, payload, attempt)
+            self.metrics.inc("net_sends_ok", len(frames))
+            was_down = self._consec_fail.get(peer, 0) >= self.down_after
+            self._consec_fail[peer] = 0
+        if was_down:
+            self._inc("net_peer_recovered")
+            self.log.event("net_peer_recovered", peer=peer)
 
     def _on_failure(self, peer: int, payload: bytes, attempt: int) -> None:
         if self._closed:
@@ -712,13 +920,7 @@ class GrpcTransport(Transport):
             # thundering burst.
             jitter = 0.75 + 0.5 * self._jitter.random()
         delay = self._retry_backoff_s * (2**attempt) * jitter
-        timer = threading.Timer(
-            delay, lambda: (self._timers.discard(timer),
-                            self._send(peer, payload, attempt + 1))
-        )
-        timer.daemon = True
-        self._timers.add(timer)
-        timer.start()
+        self._held.push(delay, (peer, payload, attempt + 1, False))
 
     # -- pump (same contract as InMemoryTransport) ---------------------------
 
@@ -790,6 +992,31 @@ class GrpcTransport(Transport):
             return None
         return bytes(blob) if blob else None
 
+    def wait_for_peers(self, timeout_s: float) -> List[int]:
+        """Block until a channel to every peer is connected, at most
+        ``timeout_s`` in all; returns the peers still out of reach. A
+        send to a peer that does not listen yet fails at once and is
+        dropped two retries later (150 ms): a committee whose members
+        boot within seconds of each other loses its first rounds' frames
+        that way and pays for each with a catch-up sync. Whoever starts
+        the node calls this first; a peer that stays away is waited for
+        once, for the timeout, and consensus goes on without it."""
+        deadline = time.monotonic() + timeout_s
+        away = []
+        for peer in sorted(self._peers):
+            if peer == self.index:
+                continue
+            self._stub(peer)
+            with self._lock:
+                chan = self._channels.get(peer)
+            try:
+                grpc.channel_ready_future(chan).result(
+                    timeout=max(0.0, deadline - time.monotonic())
+                )
+            except grpc.FutureTimeoutError:
+                away.append(peer)
+        return away
+
     def update_peer(self, peer: int, addr: str) -> None:
         """Repoint a peer to a new address, dropping the cached channel.
 
@@ -827,8 +1054,8 @@ class GrpcTransport(Transport):
 
     def close(self) -> None:
         self._closed = True
-        for t in list(self._timers):
-            t.cancel()
+        self._held.close()
+        self._senders.shutdown(wait=False, cancel_futures=True)
         self._server.stop(grace=None)
         with self._lock:
             channels = list(self._channels.values())

@@ -34,8 +34,10 @@ pin them to the peer connection).
 
 from __future__ import annotations
 
+import time as _time
 from typing import Dict, Optional, Set, Tuple
 
+from dag_rider_tpu import obs
 from dag_rider_tpu.core.types import BroadcastMessage
 from dag_rider_tpu.transport.base import Handler, Transport
 
@@ -73,6 +75,10 @@ class RbcTransport(Transport):
         self._ready_refresh_at: Dict[Slot, float] = {}
         self._echoes: Dict[Tuple[Slot, bytes], Set[int]] = {}
         self._readies: Dict[Tuple[Slot, bytes], Set[int]] = {}
+        #: when the last frame of any kind came up from the inner
+        #: transport: a process that hears echoes is not cut off,
+        #: whatever it is still waiting for (Process sync patience)
+        self.last_frame_at = 0.0
         #: slots below this round are retired (see prune_below): their
         #: state is dropped and new frames for them are discarded, so a
         #: replayed VAL cannot regrow the books.
@@ -155,6 +161,7 @@ class RbcTransport(Transport):
     # -- protocol -----------------------------------------------------------
 
     def _on_inner(self, msg: BroadcastMessage) -> None:
+        self.last_frame_at = _time.monotonic()
         if (
             self.floor
             and msg.kind in ("val", "echo", "ready", "fetch")
@@ -162,11 +169,14 @@ class RbcTransport(Transport):
         ):
             return  # retired slot (see prune_below): drop, don't regrow
         if msg.kind == "val" and msg.vertex is not None:
-            self._on_val(msg)
+            with obs.span("rbc.val"):
+                self._on_val(msg)
         elif msg.kind == "echo":
-            self._on_echo(msg)
+            with obs.span("rbc.echo"):
+                self._on_echo(msg)
         elif msg.kind == "ready":
-            self._on_ready(msg)
+            with obs.span("rbc.ready"):
+                self._on_ready(msg)
         elif msg.kind == "fetch":
             self._on_fetch(msg)
         elif self._handler is not None:
@@ -223,8 +233,6 @@ class RbcTransport(Transport):
             # (rate-limited per slot) lets 2f+1 up-to-date peers rebuild
             # that quorum — consistency is untouched because only the
             # decided digest is ever refreshed.
-            import time as _time
-
             now = _time.monotonic()
             if (
                 now - self._ready_refresh_at.get(slot, float("-inf"))

@@ -95,6 +95,7 @@ KNOWN_EVENTS = frozenset(
         "lane_restore",
         # transport wire health
         "net_peer_down",
+        "boot_peers_away",
         "net_peer_recovered",
         # cluster harness (ISSUE 19): crash-recovery lifecycle
         "checkpoint_corrupt",

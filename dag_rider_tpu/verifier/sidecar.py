@@ -234,6 +234,10 @@ class RemoteVerifier(Verifier):
     def verify_batch(self, vertices: Sequence[Vertex]) -> List[bool]:
         if not vertices:
             return []
+        with obs.span("remote.verify"):
+            return self._verify(vertices)
+
+    def _verify(self, vertices: Sequence[Vertex]) -> List[bool]:
         payload = _encode_batch(vertices)
         delay = self._backoff_s
         for attempt in range(self._retries + 1):
