@@ -19,6 +19,8 @@ from __future__ import annotations
 import struct
 from typing import List, Optional, Sequence, Tuple
 
+import numpy as np
+
 from dag_rider_tpu.core.types import (
     Block,
     BroadcastMessage,
@@ -57,7 +59,36 @@ def encode_vertex(v: Vertex) -> bytes:
     return b"".join(out)
 
 
+def _in_canonical_order(data: bytes, at: int, count: int) -> bool:
+    """Whether the ``count`` ``<II`` edges from ``at`` are in the order
+    ``sorted()`` gives their ids (duplicates allowed) — what
+    :func:`encode_vertex` writes and ``signing_bytes()`` signs."""
+    if count < 2:
+        return True
+    # each little-endian u32 byte-swapped in place, then pairs read as
+    # one big-endian u64: round in the high half, source in the low
+    keys = (
+        np.frombuffer(data, "<u4", 2 * count, at)
+        .byteswap()
+        .view(">u8")
+        .astype(np.uint64)
+    )
+    return bool((keys[1:] >= keys[:-1]).all())
+
+
 def decode_vertex(data: bytes, offset: int = 0) -> Tuple[Vertex, int]:
+    """One vertex from ``data`` at ``offset``; ``(vertex, next offset)``.
+
+    Every length the frame states is checked here against ``data``'s
+    end, so a malformed or truncated frame is a ``ValueError`` now and
+    nothing can fail at a later read. No :class:`VertexID` is built for
+    an edge: the vertex keeps both lists as the frame's bytes until
+    someone reads them (:meth:`Vertex.from_packed`), and where the wire
+    has them in canonical order — what :func:`encode_vertex` writes —
+    its signed bytes are joined from slices of the frame, which is what
+    ``signing_bytes()`` would serialise them back into. A list out of
+    order leaves the memo unseeded and ``signing_bytes()`` sorts as ever.
+    """
     magic = data[offset : offset + 4]
     if magic == _MAGIC:
         nblobs = 2
@@ -65,37 +96,57 @@ def decode_vertex(data: bytes, offset: int = 0) -> Tuple[Vertex, int]:
         nblobs = 3
     else:
         raise ValueError("bad vertex magic")
-    offset += 4
-    rnd, source = struct.unpack_from("<II", data, offset)
-    offset += 8
-    block, offset = Block.decode(data, offset)
-    edge_sets = []
-    for _ in range(2):
-        (count,) = struct.unpack_from("<I", data, offset)
-        offset += 4
-        edges = []
-        for _ in range(count):
-            er, es = struct.unpack_from("<II", data, offset)
-            offset += 8
-            edges.append(VertexID(er, es))
-        edge_sets.append(tuple(edges))
-    blobs = []
-    for _ in range(nblobs):
-        (ln,) = struct.unpack_from("<i", data, offset)
-        offset += 4
-        if ln < 0:
-            blobs.append(None)
-        else:
+    end = len(data)
+    try:
+        signed_at = offset + 4
+        vid = VertexID._make(struct.unpack_from("<II", data, signed_at))
+        block, offset = Block.decode(data, signed_at + 8)
+        id_and_block = data[signed_at:offset]
+        canonical = True
+        lists = []
+        for _ in range(2):
+            (count,) = struct.unpack_from("<I", data, offset)
+            after = offset + 4 + 8 * count
+            if after > end:
+                raise ValueError("edge list overruns the frame")
+            canonical = canonical and _in_canonical_order(
+                data, offset + 4, count
+            )
+            lists.append(data[offset:after])
+            offset = after
+        blobs = []
+        for _ in range(nblobs):
+            (ln,) = struct.unpack_from("<i", data, offset)
+            offset += 4
+            if ln < 0:
+                blobs.append(None)
+                continue
+            if offset + ln > end:
+                raise ValueError("blob overruns the frame")
             blobs.append(data[offset : offset + ln])
             offset += ln
-    v = Vertex(
-        id=VertexID(rnd, source),
-        block=block,
-        strong_edges=edge_sets[0],
-        weak_edges=edge_sets[1],
-        coin_share=blobs[0],
+    except struct.error as exc:
+        raise ValueError(f"truncated vertex frame: {exc}") from None
+    signed = None
+    if canonical:
+        share = blobs[0] or b""
+        signed = b"".join(
+            (
+                b"dagrider-vertex-v1", id_and_block,
+                b"S", lists[0],
+                b"W", lists[1],
+                b"C", struct.pack("<I", len(share)), share,
+            )
+        )
+    v = Vertex.from_packed(
+        vid,
+        block,
+        lists[0],
+        lists[1],
         signature=blobs[1],
+        coin_share=blobs[0],
         cert_sig=blobs[2] if nblobs == 3 else None,
+        signing_bytes=signed,
     )
     return v, offset
 

@@ -23,6 +23,8 @@ import hashlib
 import struct
 from typing import NamedTuple, Optional, Tuple
 
+from dag_rider_tpu import obs
+
 
 class VertexID(NamedTuple):
     """Unique vertex identity: (round, source).
@@ -79,6 +81,8 @@ class Block:
         for _ in range(count):
             (ln,) = struct.unpack_from("<I", data, offset)
             offset += 4
+            if offset + ln > len(data):
+                raise ValueError("transaction overruns the buffer")
             txs.append(data[offset : offset + ln])
             offset += ln
         return Block(tuple(txs)), offset
@@ -154,6 +158,35 @@ class Vertex:
     #: the per-vertex oracle path verifies.
     cert_sig: Optional[bytes] = None
 
+    @classmethod
+    def from_packed(
+        cls,
+        id: VertexID,
+        block: Block,
+        strong: bytes,
+        weak: bytes,
+        signature: Optional[bytes],
+        coin_share: Optional[bytes],
+        cert_sig: Optional[bytes],
+        signing_bytes: Optional[bytes] = None,
+    ) -> "Vertex":
+        """A vertex whose edge lists are still the wire's bytes — a u32
+        count, then ``<II`` per edge, each list validated by the caller
+        — and become tuples the first time either is read
+        (:class:`_PackedEdges`). ``signing_bytes`` seeds the memo where
+        the caller holds the canonical encoding already."""
+        v = object.__new__(cls)
+        d = v.__dict__
+        d["id"] = id
+        d["block"] = block
+        d["signature"] = signature
+        d["coin_share"] = coin_share
+        d["cert_sig"] = cert_sig
+        d["_packed_edges"] = (strong, weak)
+        if signing_bytes is not None:
+            d["_signing_bytes"] = signing_bytes
+        return v
+
     @property
     def round(self) -> int:
         return self.id.round
@@ -227,6 +260,60 @@ class Vertex:
         )
         object.__setattr__(self, "_edge_arrays", arrs)
         return arrs
+
+
+_EDGE_FIELDS = ("strong_edges", "weak_edges")
+
+
+class _PackedEdges:
+    """``Vertex.strong_edges`` / ``Vertex.weak_edges`` on the class.
+
+    A non-data descriptor (``functools.cached_property``'s kind): an
+    instance that holds the field in its ``__dict__`` — every vertex
+    ``Vertex(...)`` built — never reaches it. One from
+    :meth:`Vertex.from_packed` holds ``_packed_edges`` instead; the
+    first read of either field unpacks both lists into the tuples of
+    :class:`VertexID` an eager decoder would have built, stores them
+    under the fields' names and drops the bytes, so equality, hash,
+    repr, ``dataclasses.replace``, pickle and ``copy`` (all through
+    attribute access or ``__dict__``) see an ordinary vertex. A caller
+    that never reads an edge — the verifier wants source, signature and
+    signing bytes — never pays for 2f+1 ids.
+
+    The dataclass left the fields' default ``()`` where this now sits,
+    so an instance with NEITHER entry must not read as edgeless: it
+    raises.
+    """
+
+    __slots__ = ("name",)
+
+    def __init__(self, name: str) -> None:
+        self.name = name
+
+    def __get__(self, obj, cls=None):
+        if obj is None:
+            return ()  # what the class attribute read as: the default
+        d = obj.__dict__
+        packed = d.get("_packed_edges")
+        if packed is not None:
+            for name, buf in zip(_EDGE_FIELDS, packed):
+                it = iter(struct.unpack_from(f"<{len(buf) // 4 - 1}I", buf, 4))
+                d[name] = tuple(map(VertexID._make, zip(it, it)))
+            # two threads may both have unpacked (equal tuples): the one
+            # that takes the bytes away counts
+            if d.pop("_packed_edges", None) is not None:
+                obs.count("codec.edges_unpacked")
+        try:
+            return d[self.name]
+        except KeyError:
+            raise AttributeError(
+                f"Vertex holds neither {self.name!r} nor packed edges"
+            ) from None
+
+
+for _name in _EDGE_FIELDS:
+    setattr(Vertex, _name, _PackedEdges(_name))
+del _name
 
 
 @dataclasses.dataclass(frozen=True)

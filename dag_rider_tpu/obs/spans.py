@@ -113,6 +113,11 @@ KNOWN_COUNTS = frozenset(
         # verifier/tpu.py — objects TPUVerifier.warmup took out of the
         # collector's reach after compiling its program
         "heap.frozen_objects",
+        # verifier/sidecar.py — vertices a request's frames decoded to,
+        # and core/types.py — those of any decoder's whose packed edge
+        # lists someone read, and so became tuples of ids
+        "sidecar.vertices_decoded",
+        "codec.edges_unpacked",
     }
 )
 

@@ -48,6 +48,7 @@ def _decode_batch(payload: bytes) -> List[Vertex]:
             raise ValueError("truncated batch frame")
         blob, offset = item
         out.append(codec.decode_vertex(blob)[0])
+    obs.count("sidecar.vertices_decoded", len(out))
     return out
 
 
