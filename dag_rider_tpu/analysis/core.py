@@ -54,8 +54,8 @@ class Allow:
 
 
 def discover(repo_root: str) -> List[SourceFile]:
-    """Every .py file of the package plus the repo-root bench.py and
-    chip_smoke.py, in a deterministic order."""
+    """Every .py file of the package plus the repo-root chip_smoke.py,
+    in a deterministic order."""
     paths: List[str] = []
     pkg = os.path.join(repo_root, "dag_rider_tpu")
     for dirpath, dirnames, filenames in os.walk(pkg):
@@ -67,10 +67,9 @@ def discover(repo_root: str) -> List[SourceFile]:
             for fn in sorted(filenames)
             if fn.endswith(".py")
         )
-    for root_script in ("bench.py", "chip_smoke.py"):
-        full = os.path.join(repo_root, root_script)
-        if os.path.exists(full):
-            paths.append(full)
+    full = os.path.join(repo_root, "chip_smoke.py")
+    if os.path.exists(full):
+        paths.append(full)
     files: List[SourceFile] = []
     for full in paths:
         rel = os.path.relpath(full, repo_root).replace(os.sep, "/")

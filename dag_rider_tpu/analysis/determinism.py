@@ -1,8 +1,7 @@
 """Determinism discipline: the consensus stack must be a pure function
 of its inputs.
 
-Three rules over the package (bench.py is exempt — measuring wall time
-is its job):
+Three rules over the package:
 
 1. No ``time.time()`` *calls* anywhere in the package. Monotonic /
    perf-counter clocks are fine (latency measurement), and passing
@@ -108,8 +107,6 @@ def _is_set_expr(node: ast.AST, set_attrs: Set[str]) -> bool:
 def run(files: Sequence[SourceFile], repo_root: str) -> List[Finding]:
     findings: List[Finding] = []
     for rel, tree, _src in files:
-        if rel == "bench.py":
-            continue
         in_consensus = rel.startswith("dag_rider_tpu/consensus/")
         set_attrs = _set_attrs_of_file(tree) if in_consensus else set()
         for node in ast.walk(tree):

@@ -49,8 +49,8 @@ __all__ = [
 
 def module_name(rel: str) -> str:
     """`dag_rider_tpu/ops/field.py` -> `dag_rider_tpu.ops.field`;
-    `bench.py` -> `bench` (matching ``__name__`` at runtime, which is
-    how races.py keys dynamic lock sites)."""
+    `chip_smoke.py` -> `chip_smoke` (matching ``__name__`` at runtime,
+    which is how races.py keys dynamic lock sites)."""
     name = rel[:-3] if rel.endswith(".py") else rel
     name = name.replace("/", ".")
     if name.endswith(".__init__"):
@@ -137,8 +137,8 @@ class _ModuleIndex:
                 self.functions[node.name] = node
             elif isinstance(node, ast.ClassDef):
                 self.classes[node.name] = node
-        # function-local imports fill gaps (bench.py and the lazy seams
-        # defer heavy deps into function bodies); top-level bindings win
+        # function-local imports fill gaps (the lazy seams defer heavy
+        # deps into function bodies); top-level bindings win
         top = set(map(id, tree.body))
         for node in ast.walk(tree):
             if isinstance(node, (ast.Import, ast.ImportFrom)) and (

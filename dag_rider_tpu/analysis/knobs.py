@@ -3,9 +3,7 @@
 Three rules:
 
 1. No direct ``os.environ`` / ``os.getenv`` read of a ``DAGRIDER_*``
-   name outside ``dag_rider_tpu/config.py``. bench.py may read the
-   ``DAGRIDER_BENCH_*`` namespace directly (bench-local tuning the
-   package never sees) but nothing else.
+   name outside ``dag_rider_tpu/config.py``.
 2. Every ``DAGRIDER_*`` literal passed to a config ``env_*`` accessor
    must be registered in ``config.KNOBS`` (the accessors also enforce
    this at runtime; the static rule catches dead/typo'd reads on paths
@@ -78,14 +76,11 @@ def run(files: Sequence[SourceFile], repo_root: str) -> List[Finding]:
     findings: List[Finding] = []
     for rel, tree, _src in files:
         in_config = rel == _CONFIG_PATH
-        in_bench = rel == "bench.py"
         for node in ast.walk(tree):
             name_node = _direct_env_read(node)
             if name_node is not None and not in_config:
                 name = _literal(name_node)
                 if name is None or not name.startswith("DAGRIDER_"):
-                    continue
-                if in_bench and name.startswith("DAGRIDER_BENCH_"):
                     continue
                 findings.append(
                     Finding(

@@ -2,13 +2,11 @@
 
 The five knob-gated fast paths share long-lived objects (the device
 verifier, the fault injector, the transports) whose state individual
-rungs and tests *borrow*: set ``fixed_bucket`` for one measurement,
-arm a fault plan for one chaos window, flip ``pipeline_enabled`` for
-one A/B side. A borrow that is not returned on the exception path
-leaks — ADVICE r5 #3 (bench.py's sim256 rung leaking a sim-sized
-bucket into the deferred merged headline phase) was a live instance,
-fixed by hand in round 8; this checker makes the whole class
-impossible to reintroduce.
+drives and tests *borrow*: set ``fixed_bucket`` for one measurement,
+arm a fault plan for one chaos window. A borrow that is not returned
+on the exception path leaks (a run once leaked a sim-sized bucket into
+the phase after it; fixed by hand in round 8); this checker makes the
+whole class impossible to reintroduce.
 
 Two rules, both path-sensitive over the AST's try/finally structure:
 
@@ -22,7 +20,7 @@ not flagged (that is the transports' subscribe idiom: handlers live
 for the transport's life).
 
 **R2 — borrowed-attribute save/restore.** :data:`RESTORED_ATTRS` names
-the shared-verifier state attributes that rungs borrow. Writing one on
+the shared-verifier state attributes that drives borrow. Writing one on
 a *shared* receiver (a parameter, an outer-scope name, anything not
 constructed in the same function) must happen inside a ``try`` whose
 ``finally`` writes the same attribute back. Exempt: ``self`` receivers
@@ -57,10 +55,8 @@ CALL_PAIRS: Tuple[Tuple[str, str], ...] = (
     ("subscribe", "unsubscribe"),
 )
 
-#: shared-verifier state attributes rungs borrow (R2)
-RESTORED_ATTRS = frozenset(
-    {"fixed_bucket", "prep_workers", "pipeline_enabled"}
-)
+#: shared-verifier state attributes drives borrow (R2)
+RESTORED_ATTRS = frozenset({"fixed_bucket", "prep_workers"})
 
 
 @dataclasses.dataclass
@@ -220,7 +216,7 @@ def _check_function(
                 line,
                 f"{recv}.{attr} mutated on a shared object without a "
                 "finally-restore on the exception path — borrow it "
-                "under try/finally (ADVICE r5 #3 class)",
+                "under try/finally",
             )
         )
 
@@ -296,7 +292,7 @@ def run(
         if fi.rel.startswith("dag_rider_tpu/analysis/"):
             continue
         scopes = [fi]
-        # nested defs (bench rung helpers) are their own borrow scopes
+        # nested defs are their own borrow scopes
         for node in ast.walk(fi.node):
             if (
                 isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef))

@@ -20,9 +20,7 @@ from typing import Dict, Optional, Tuple
 # read through one of the env_* accessors below; the driderlint knob checker
 # (dag_rider_tpu/analysis/knobs.py) rejects any direct ``os.environ`` read of
 # a DAGRIDER_* name outside this module, and cross-checks that every
-# registered knob appears in the README knob table. bench.py's
-# DAGRIDER_BENCH_* namespace is the one carve-out (bench-local tuning, never
-# read by the package).
+# registered knob appears in the README knob table.
 # ---------------------------------------------------------------------------
 
 #: shared env-flag convention: anything but these (case-insensitive) is on
@@ -82,10 +80,6 @@ _register("DAGRIDER_PREP_WORKERS", "int", 1,
           "parallel host-prep worker count", minimum=1)
 _register("DAGRIDER_NATIVE", "flag", True,
           "native challenge hashing (hashlib fallback when off)")
-_register("DAGRIDER_COMB", "flag", True,
-          "fixed-key comb tables for the TPU verifier")
-_register("DAGRIDER_COMB_BITS", "choice", "",
-          "comb table window width", choices=("", "4", "8"))
 _register("DAGRIDER_PALLAS_GROUP", "flag", True,
           "Pallas group-op kernels on real TPU backends")
 _register("DAGRIDER_MSM_PALLAS", "flag", True,
@@ -103,10 +97,6 @@ _register("DAGRIDER_MEMPOOL_TTL_S", "float", 60.0,
 _register("DAGRIDER_ADAPTIVE_DEADLINE", "flag", False,
           "drive the batcher's effective deadline from the live "
           "submit->deliver latency histogram (ISSUE 16 tentpole 3)")
-_register("DAGRIDER_AGG_OUT", "str", "BENCH_r06.json",
-          "aggregate-cert bench output path")
-_register("DAGRIDER_MULTICHIP_OUT", "str", "MULTICHIP_r06.json",
-          "multichip bench output path")
 _register("DAGRIDER_RACE", "flag", False,
           "install the dynamic lock-race harness under pytest")
 _register("DAGRIDER_CERT_SIGN", "choice", "host",
@@ -120,8 +110,6 @@ _register("DAGRIDER_CERT_SPAN", "int", 0,
           minimum=0)
 _register("DAGRIDER_CERT_SELFCHECK", "flag", True,
           "aggregator self-verifies certificates before gossip")
-_register("DAGRIDER_CERT2_OUT", "str", "BENCH_r07.json",
-          "certificate-phase-2 bench output path")
 _register("DAGRIDER_TRACE", "flag", False,
           "causal tracing layer (ring recorder + lifecycle/phase spans)")
 _register("DAGRIDER_TRACE_SAMPLE", "float", 1.0,
@@ -139,8 +127,6 @@ _register("DAGRIDER_WAVE_PIPELINE", "flag", False,
 _register("DAGRIDER_EAGER_DELIVER", "flag", False,
           "optimistic early delivery: surface each decided chunk via "
           "on_deliver_early ahead of the deferred canonical flush")
-_register("DAGRIDER_FINALITY_OUT", "str", "BENCH_r08.json",
-          "finality-ladder bench output path")
 _register("DAGRIDER_LANES", "flag", False,
           "sharded dissemination lanes: vertices carry certified batch "
           "digests while worker lanes move the payload bytes (ISSUE 17)")
@@ -149,8 +135,6 @@ _register("DAGRIDER_LANE_WORKERS", "int", 4,
 _register("DAGRIDER_LANE_BATCH_BYTES", "int", 1024,
           "minimum encoded block size worth a lane round-trip; smaller "
           "blocks ship inline (the oracle path)", minimum=1)
-_register("DAGRIDER_LANES_OUT", "str", "BENCH_r09.json",
-          "lanes-ladder bench output path")
 _register("DAGRIDER_CLUSTER_TRANSPORT", "choice", "uds",
           "address family for multi-process cluster harness sockets",
           choices=("uds", "tcp"))
@@ -160,8 +144,6 @@ _register("DAGRIDER_CLUSTER_BOOT_S", "float", 15.0,
 _register("DAGRIDER_CLUSTER_KEEP", "flag", False,
           "keep the cluster harness workspace (logs, checkpoints, flight "
           "dumps) after a run instead of deleting it")
-_register("DAGRIDER_CLUSTER_OUT", "str", "BENCH_r20.json",
-          "cluster-e2e ladder bench output path")
 _register("DAGRIDER_EPOCH", "flag", False,
           "epoch reconfiguration: validator-set changes ordered through "
           "consensus as control txs, taking effect at deterministic "
@@ -180,8 +162,6 @@ _register("DAGRIDER_EPOCH_ROTATE", "choice", "seed",
           "keys from the committed transcript), dkg = full joint-Feldman "
           "resharing over crypto/dkg.py, none = epoch bump only",
           choices=("seed", "dkg", "none"))
-_register("DAGRIDER_EPOCH_OUT", "str", "BENCH_r21.json",
-          "epoch ladder bench output path")
 
 
 def _raw(name: str) -> str:

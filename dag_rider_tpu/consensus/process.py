@@ -141,7 +141,7 @@ class Process:
         #: arrival order (dicts preserve insertion order; the source key
         #: doubles as the duplicate-membership probe — within one round a
         #: (round, source) collision IS a vid collision, and an int key
-        #: skips the VertexID tuple hash the PROFILE round-12 flame chart
+        #: skips the VertexID tuple hash the round-12 flame chart
         #: charges ~0.5s of dict.get to).
         self._buffer_rounds: Dict[int, Dict[int, Vertex]] = {}
         #: scalar-mode buffer membership mirror; vector mode keys the
@@ -708,7 +708,7 @@ class Process:
                 # presence snapshot: nothing in this loop inserts into
                 # the dag, so one .tolist() per round-run turns the
                 # per-message VertexID dict probe into a C list index
-                # (PROFILE round 12: those probes were ~0.5s of the
+                # (round 12: those probes were ~0.5s of the
                 # remaining 2.9s at n=256)
                 rr = r - base
                 exists_row = exists[rr].tolist() if rr < n_rows else None

@@ -291,9 +291,8 @@ class Simulation:
         if isinstance(shared, VerifierPipeline):
             return shared
         if self._verify_pipe is None or self._verify_pipe.verifier is not shared:
-            # construction fixes the bucket and compiles its program (a
-            # lookup when the bench warmed the shape outside its timed
-            # box), outside the window's containment
+            # construction fixes the bucket and compiles its program,
+            # outside the window's containment
             self._verify_pipe = VerifierPipeline(shared)
         return self._verify_pipe
 
@@ -372,7 +371,7 @@ class Simulation:
             if hasattr(self.transport, "fanout_sentinel"):
                 self.transport.fanout_sentinel = True
         # Cross-process dispatch coalescing: when every process shares ONE
-        # Verifier instance (the bench's device configuration), all n
+        # Verifier instance (the device configuration), all n
         # processes' burst batches merge into a single padded device
         # dispatch per pump cycle (Verifier.verify_rounds) — n-1 fewer
         # fixed per-dispatch costs per cycle, identical accept bits.
@@ -398,7 +397,6 @@ class Simulation:
             coalesce
             and callable(getattr(shared, "dispatch_batch", None))
             and callable(getattr(shared, "resolve_batch", None))
-            and getattr(shared, "pipeline_enabled", True)
         )
         pipe = self._pipeline_for(shared) if pipelined else None
         for p in self.processes:
@@ -462,7 +460,7 @@ class Simulation:
                                 # charging it here too would double-count);
                                 # the pipeline books its resolve waits into
                                 # the verifier's cumulative breakdown itself.
-                                # NOTE (ADVICE r5 #1): with the window open,
+                                # NOTE: with the window open,
                                 # the resolve waits the pipeline books as
                                 # device time are a LOWER BOUND — device
                                 # execution that completes under the flush
@@ -474,10 +472,9 @@ class Simulation:
                                 verify_s = pipe.last_seam_s
                             else:
                                 with obs.span("pump.verify") as t:
-                                    # chunked, synchronous (verify_rounds
-                                    # splits uniq at the fixed bucket; a
-                                    # pipeline_enabled=False verifier keeps
-                                    # its streaming window at depth 1)
+                                    # synchronous: a host verifier, a
+                                    # ladder, or a control in the verifier's
+                                    # place (no dispatch/resolve seam)
                                     umask = [
                                         m
                                         for ms in shared.verify_rounds([uniq])
@@ -544,7 +541,7 @@ class Simulation:
                                             # its size-proportional share of one
                                             # union dispatch, so the n series do
                                             # not sum to n independent verify
-                                            # costs (ADVICE r5 #2)
+                                            # costs
                                             p.metrics.mark_verify_amortized()
                                         if ps is not None:
                                             p.metrics.observe_prep(

@@ -42,7 +42,7 @@ class VertexID(NamedTuple):
     n=256 host profile.
 
     Being a NamedTuple, a VertexID hashes and compares equal to the bare
-    tuple ``(round, source)`` — INTENTIONAL (ADVICE r5 #4): hot paths
+    tuple ``(round, source)`` — INTENTIONAL: hot paths
     may probe dicts/sets keyed by VertexID with plain tuples (skipping
     even the NamedTuple constructor) and membership answers must agree.
     Do not "fix" this by overriding __eq__/__hash__; code must not rely

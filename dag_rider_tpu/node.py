@@ -597,8 +597,7 @@ class Node:
         thread (a caller-thread process.submit racing the pump's step()
         corrupted state rarely enough to be a flaky-suite heisenbug).
         Either way, after stop() nothing is drained again, so a late
-        submit raises instead of silently swallowing the block
-        (ADVICE r3)."""
+        submit raises instead of silently swallowing the block."""
         with self._submit_lock:
             if self._stopped:
                 raise RuntimeError(

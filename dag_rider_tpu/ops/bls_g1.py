@@ -21,7 +21,7 @@ the caller re-signs that row on the host, preserving byte-identity.
 
 Like the sharded MSM, this lane is about where the work runs, not local
 wall-clock: on this 1-core CPU host the limb kernels lose to the cffi
-native lane (see PROFILE round 15); the lane exists so committee-scale
+native lane (round 15); the lane exists so committee-scale
 signing has a real accelerator story next to `ops/bls_msm.py`.
 """
 

@@ -165,7 +165,7 @@ def window_sums(nibbles: jax.Array, p: Point, impl: str = "jnp") -> Point:
     """Per-window partial sums S_w = sum_i [d_{i,w}] P_i, coords [64, L].
 
     The TPU-shaped half of the MSM (round-4; same restructuring that took
-    the Ed25519 comb from a sequential walk to a wide tree — PROFILE.md):
+    the Ed25519 comb from a sequential walk to a wide tree):
     radix-16 tables per point, ONE take_along_axis gathering every
     window's digit entry ([T, 64, L]), then a pairwise tree reduction
     over the point axis with full batch-level ILP. Work is

@@ -21,9 +21,9 @@ schedule (impossible for r-order G2 points, whose schedule never hits
 the point at infinity mid-walk) — those pairs route to the host oracle.
 
 Like the sharded MSM and the G1 signing lane, this is a where-the-work-
-runs lane: on the 1-core CPU host it loses to the host replay (PROFILE
-round 15 has the A/B); the lane is the committee-scale accelerator story
-for the verify side.
+runs lane: on the 1-core CPU host it loses to the host replay (round
+15's A/B); the lane is the committee-scale accelerator story for the
+verify side.
 """
 
 from __future__ import annotations

@@ -166,122 +166,6 @@ def base_table_xyzt() -> np.ndarray:
     return np.stack([xs, ys, ones, ts], axis=2)  # [64, 16, 4, 22]
 
 
-WINDOWS8 = 32  # 8-bit windows over 256-bit scalars
-ENTRIES8 = 256
-
-
-# Digit -> table-position permutation for the 8-bit tables. The build
-# stores each level's entries block-ordered ([all evens; all odds] of the
-# previous level's order) instead of digit-ordered: an interleaving
-# stack+reshape INSIDE a lax.scan body miscompiles on the TPU backend
-# for n >= 64 (silently wrong values from level 2 on; the identical
-# unrolled body and the CPU backend are both correct — reproduced and
-# bisected in round 3, see PROFILE.md). Position order is defined by
-# L_0 = [1], L_{l+1} = [2d for d in L_l] + [2d+1 for d in L_l].
-def _digit_pos8() -> np.ndarray:
-    order = [0, 1]
-    cur = [1]
-    for _ in range(7):
-        cur = [2 * d for d in cur] + [2 * d + 1 for d in cur]
-        order += cur
-    pos = np.zeros(ENTRIES8, dtype=np.int32)
-    for p, d in enumerate(order):
-        pos[d] = p
-    return pos
-
-
-DIGIT_POS8 = _digit_pos8()
-
-
-@jax.jit
-def build_key_tables8(
-    a_x: jax.Array, a_y: jax.Array, a_t: jax.Array
-) -> jax.Array:
-    """8-bit-window comb tables: [n, 32, 256, 4, 22] int32.
-
-    TABLE[key, w, DIGIT_POS8[d]] = d * 256^w * A_key (block-ordered — see
-    :data:`DIGIT_POS8`). Halves both the gather rows and the tree levels
-    of the verify dispatch vs the 4-bit tables (the two dominant on-chip
-    costs after the Pallas kernels — PROFILE.md), at 16x the HBM
-    (1.07 GB padded at n=256; selected only for n <= 512).
-
-    Each window's 256 entries are built in 8 doubling levels (evens are
-    doubles of the previous level, odds add the base), so the whole
-    build is ~32 * 16 wide batched point ops — still one dispatch.
-    """
-    n = a_x.shape[0]
-    one = jnp.broadcast_to(jnp.asarray(F.ONE), (n, F.LIMBS))
-    base0 = jnp.stack([a_x, a_y, one, a_t], axis=-2)  # [n, 4, 22]
-    ident = pack_point(curve.identity((n,)))
-
-    def window_step(b, _):
-        b_cached = to_cached(b)
-        levels = [ident[:, None], b[:, None]]  # positions 0 and 1
-        prev = b[:, None]  # [n, 1, 4, 22]
-        for _lvl in range(7):
-            evens = pdouble_packed(prev)
-            odds = padd_cached(evens, b_cached[:, None])
-            lvl = jnp.concatenate([evens, odds], axis=1)  # block order
-            levels.append(lvl)
-            prev = lvl
-        table_w = jnp.concatenate(levels, axis=1)  # [n, 256, 4, 22]
-        nb = b
-        for _ in range(8):
-            nb = pdouble_packed(nb)
-        return nb, table_w
-
-    _, tables = jax.lax.scan(window_step, base0, None, length=WINDOWS8)
-    # [32, n, 256, 4, 22] -> [n, 32, 256, 4, 22]
-    return jnp.transpose(tables, (1, 0, 2, 3, 4))
-
-
-def comb_verify_core8(
-    s_bytes: jax.Array,
-    k_bytes: jax.Array,
-    key_idx: jax.Array,
-    key_tables: jax.Array,
-    b_table: jax.Array,
-    a_valid: jax.Array,
-    r_y: jax.Array,
-    r_sign: jax.Array,
-    prevalid: jax.Array,
-    impl: str = "jnp",
-) -> jax.Array:
-    """8-bit-window twin of :func:`comb_verify_core`.
-
-    s_bytes/k_bytes: int32[B, 32] little-endian byte digits (the raw
-    scalar bytes — no nibble split); tables from
-    :func:`build_key_tables8` via :func:`pad_rows`. Identical accept
-    mask; only the window decomposition differs (the scalar sum is the
-    same group element).
-    """
-    # digits -> block-ordered table positions (see DIGIT_POS8)
-    pos = jnp.asarray(DIGIT_POS8)
-    s_pos = jnp.take(pos, s_bytes, axis=0)
-    k_pos = jnp.take(pos, k_bytes, axis=0)
-    wins = jnp.arange(WINDOWS8, dtype=jnp.int32)[None, :]
-    b_rows = jnp.take(b_table, wins * ENTRIES8 + s_pos, axis=0)
-    a_idx = (key_idx[:, None] * WINDOWS8 + wins) * ENTRIES8 + k_pos
-    a_rows = jnp.take(key_tables, a_idx, axis=0)
-    stacked = jnp.stack([b_rows, a_rows], axis=1)  # [B, 2, 32, 128]
-    entries = stacked[..., : 4 * F.LIMBS].reshape(
-        (*stacked.shape[:-1], 4, F.LIMBS)
-    )
-    if impl in ("pallas", "pallas_interpret"):
-        from dag_rider_tpu.ops import pallas_group
-
-        interp = impl == "pallas_interpret"
-        acc = pallas_group.tree_sum_xyzt(entries, interpret=interp)
-        ok = pallas_group.finish_check(r_y, r_sign, acc, interpret=interp)
-        return ok & a_valid & prevalid
-    acc = tree_sum_packed(entries)
-    lhs = unpack_point(acc[:, 0])
-    ka = unpack_point(acc[:, 1])
-    r_point, r_valid = curve.decompress(r_y, r_sign)
-    rhs = curve.padd(r_point, ka)
-    return curve.points_equal(lhs, rhs) & a_valid & r_valid & prevalid
-
-
 ROW_PAD = 128  # gather-row width: one aligned lane tile
 
 
@@ -290,7 +174,7 @@ def pad_rows(tables: jax.Array) -> jax.Array:
 
     TPU row-gathers run ~2.2x faster from 512-byte lane-aligned rows
     than from the raw 352-byte [4, 22] entries (measured on-chip,
-    PROFILE.md round 3); the 40 pad lanes are sliced off after gather.
+    round 3); the 40 pad lanes are sliced off after gather.
     """
     flat = tables.reshape((-1, 4 * F.LIMBS))
     return jnp.pad(flat, ((0, 0), (0, ROW_PAD - 4 * F.LIMBS)))
@@ -309,7 +193,7 @@ def tree_sum_packed(entries: jax.Array) -> jax.Array:
     half)); log2(M) levels of WIDE ops — the whole reduction is ~20 XLA
     ops regardless of M, so the VPU sees huge elementwise ops instead of
     a long dependent chain (the sequential 64-step walk was
-    latency-bound — PROFILE.md round 3). The TPU fast path is
+    latency-bound — round 3). The TPU fast path is
     :func:`dag_rider_tpu.ops.pallas_group.tree_sum_xyzt` (bit-identical).
     """
     acc = entries

@@ -148,7 +148,7 @@ def _columns(a: jax.Array, b: jax.Array) -> jax.Array:
     anti-diagonal formulation in on-chip speed but peaks at 2x the input
     footprint instead of 22x (the [..., 22, 46] intermediate made wide
     batched ops HBM-traffic-bound and OOM'd the 8k-sig merged dispatch —
-    PROFILE.md round 3). Static shapes; no gathers.
+    round 3). Static shapes; no gathers.
     """
     batch = jnp.broadcast_shapes(a.shape[:-1], b.shape[:-1])
     a = jnp.broadcast_to(a, (*batch, LIMBS))

@@ -1,6 +1,6 @@
 """Pallas TPU kernels for whole Edwards group ops — the comb tree's engine.
 
-Why these exist (measured on-chip, PROFILE.md round 3): the jnp field
+Why these exist (measured on-chip, round 3): the jnp field
 multiply runs its 484 MACs at near-VPU-peak *inside* one fused op, but a
 group addition is ~10 multiplies with stacks/slices/carries between them,
 and XLA materializes the intermediate columns between every step — the

@@ -1,7 +1,7 @@
 """Pallas TPU kernel for whole BLS12-381 G1 additions — the MSM tree engine.
 
 Same rationale as :mod:`dag_rider_tpu.ops.pallas_group` (measured on-chip,
-PROFILE.md round 3): a group addition is ~12 field multiplies with
+round 3): a group addition is ~12 field multiplies with
 stacks/slices/carries between them, and XLA materializes the intermediate
 columns of every step in HBM — the Ed25519 comb tree ran ~20x above its
 compute floor until its additions became single kernel launches. The MSM

@@ -11,8 +11,8 @@ data-parallel) — XLA inserts the result all-gather; ICI carries it.
 First-class on the async seam (round 7): this class overrides ONLY the
 placement hooks of :class:`~dag_rider_tpu.verifier.tpu.TPUVerifier`
 (``_round_bucket``/``_put``/``_aot_lower``/...), so
-``dispatch_batch``/``resolve_batch``/``warmup``/the chunk-streaming
-``verify_rounds`` — and therefore every caller: ``VerifierPipeline``,
+``dispatch_batch``/``resolve_batch``/``warmup``/the chunked
+``verify_batch`` — and therefore every caller: ``VerifierPipeline``,
 ``Simulation.run``'s coalesced window, node.py — ride the mesh without a
 single duplicated line of dispatch logic. Before round 7 those methods
 were silently inherited un-overridden and every async caller dispatched
@@ -33,11 +33,11 @@ rows are sliced off before any consumer sees them. So CPU / 1-chip /
 N-chip runs agree bit-for-bit at every pipeline depth (test_pipeline.py
 on the virtual 8-device CPU mesh).
 
-Round-9 fault containment inherits the same way: ``reset_staging`` /
-``_quarantine`` / the contained ``verify_rounds`` streaming loop and the
-``quarantine_verifier`` slot all live above the placement hooks, so a
-poisoned sharded window salvages, re-arms its (full-batch host) staging
-ring and quarantines exactly like single-chip — and the chaos harness
+Fault containment is ``VerifierPipeline``'s (verifier/pipeline.py) and
+sits above the placement hooks too: a poisoned window over this class
+salvages, re-arms the (full-batch host) staging ring through
+``reset_staging`` and quarantines exactly as over the single-chip
+verifier; like it, this class raises on a fault. The chaos harness
 (verifier/faults.py) arms this class through the identical instance-
 attribute shadows (tests/test_chaos.py runs its suite on both).
 """
@@ -45,7 +45,6 @@ attribute shadows (tests/test_chaos.py runs its suite on both).
 from __future__ import annotations
 
 import functools
-import warnings
 from typing import Optional
 
 import numpy as np
@@ -78,27 +77,17 @@ class ShardedTPUVerifier(TPUVerifier):
         self,
         registry: KeyRegistry,
         mesh: Optional[Mesh] = None,
-        comb: Optional[bool] = None,
+        comb: bool = True,
     ):
         super().__init__(registry, comb=comb)
-        # Replicating the 8-bit tables (1.07 GB at n=256) on every chip
-        # is the wrong trade for a mesh; the sharded comb program is
-        # pinned to 4-bit windows.
-        if self._comb_bits != 4:
-            warnings.warn(
-                f"ShardedTPUVerifier pins comb windows to 4 bits; ignoring "
-                f"DAGRIDER_COMB_BITS={self._comb_bits}",
-                stacklevel=2,
-            )
-        self._comb_bits = 4
         self.mesh = mesh if mesh is not None else make_mesh()
         self._n_shards = int(np.prod(self.mesh.devices.shape))
         self._mesh_key = tuple(int(d) for d in self.mesh.devices.shape)
         self._batch_sharding = batch_sharding(self.mesh)
         self._repl_tables = None
 
-        #: per-shard gauges of the most recent dispatch (the bench's
-        #: verifier_breakdown / pipeline stats() surface them)
+        #: per-shard gauges of the most recent dispatch (pipeline
+        #: stats() surfaces them)
         self.mesh_devices = self._n_shards
         self.last_shard_batch = 0
         self.last_shard_imbalance = 0.0
@@ -186,12 +175,12 @@ class ShardedTPUVerifier(TPUVerifier):
     def _aot_key(self, size: int, impl: str) -> tuple:
         # mesh shape in the key: a warmup for an 8-device mesh must not
         # be served to a reconfigured 4-device run of the same bucket
-        return (size, impl, self._comb_bits, self._mesh_key)
+        return (size, impl, self._mesh_key)
 
     def _put(self, arr: np.ndarray) -> jax.Array:
         # one NamedSharding device_put = n_shards per-device sub-buffer
         # transfers; each staging-ring slot stays a full-batch host array
-        # so the ring discipline (pipeline_depth + 2 slots) is unchanged
+        # so the ring discipline (the window's depth + 2 slots) is unchanged
         return jax.device_put(arr, self._batch_sharding)
 
     def _comb_tables_dev(self):

@@ -211,10 +211,16 @@ class _DelayQueue:
             self._on_due(due)
 
     def close(self) -> None:
+        """Drop what is held and stop the thread. The thread has exited
+        when this returns, so none outlives a closed transport (unless a
+        hand-over in progress outlasts the wait)."""
         with self._cond:
             self._closed = True
             self._heap.clear()
             self._cond.notify()
+            thread = self._thread
+        if thread is not None and thread is not threading.current_thread():
+            thread.join(timeout=5.0)
 
 
 class _DeliverHandler(grpc.GenericRpcHandler):

@@ -217,7 +217,7 @@ class Metrics:
         #: simulator's dedup'd shared verifier the per-process verify
         #: timings remain AMORTIZED (each process is charged a
         #: size-proportional share of one union dispatch — see
-        #: mark_verify_amortized / ADVICE r5 #2), so summing them never
+        #: mark_verify_amortized), so summing them never
         #: yields cluster cost; the submit→deliver histogram has no such
         #: caveat — each sample is one real client transaction's wait.
         self.submit_deliver_seconds = Histogram()
@@ -330,7 +330,7 @@ class Metrics:
         pump + step, and the active path ("scalar" | "vector"). The
         1.2 s/round floor ISSUE 8 attacks becomes first-class
         observable as host_pump_ms_per_round / pump_msgs_per_s in the
-        snapshot instead of hand-derived in PROFILE."""
+        snapshot."""
         self.pump_msgs_total += int(msgs)
         self.pump_seconds_total += float(seconds)
         self.pump_path = path
@@ -339,8 +339,8 @@ class Metrics:
         """Flag this process's verify timings as AMORTIZED: under the
         simulator's dedup'd shared verifier one process pays the wall
         time for a union batch whose masks all n processes consume, so
-        per-process verify_seconds/sigs do not sum to cluster cost
-        (ADVICE r5 #2). Consumers must treat the per-process series as
+        per-process verify_seconds/sigs do not sum to cluster cost.
+        Consumers must treat the per-process series as
         attribution of shared work, not as independent spend."""
         self.counters["verify_timings_amortized"] = 1
 

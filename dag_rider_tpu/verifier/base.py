@@ -195,5 +195,5 @@ class Verifier(abc.ABC):
         """Accept masks for several rounds' batches. Semantically
         equivalent to mapping :meth:`verify_batch`; device backends
         override this to merge the rounds into one padded dispatch
-        (amortizing the fixed per-dispatch cost — see PROFILE.md)."""
+        (amortizing the fixed per-dispatch cost)."""
         return [self.verify_batch(r) for r in rounds]

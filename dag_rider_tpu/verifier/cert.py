@@ -192,8 +192,7 @@ class CertVerifier:
 
     def _pairing_check(self, pairs: Sequence[tuple]) -> bool:
         """Route one product check through the pairing seam; the counter
-        is what the span path's <1-check-per-round claim is measured on
-        (bench.py cert_phase2 rung)."""
+        is what the span path's <1-check-per-round claim is measured on."""
         self.stats["pairing_checks"] += 1
         if self.pair == "device":
             from dag_rider_tpu.ops import bls_pairing

@@ -85,20 +85,15 @@ class VerifierPipeline(Verifier):
                 "(dispatch_batch/resolve_batch)"
             )
         self.verifier = verifier
-        # explicit depth > the verifier's own pipeline_depth > env default
-        self.depth = (
-            int(depth)
-            if depth is not None
-            else int(getattr(verifier, "pipeline_depth", 0) or default_depth())
-        )
+        self.depth = int(depth) if depth is not None else default_depth()
         if self.depth < 1:
             raise ValueError(f"pipeline depth must be >= 1, got {depth!r}")
-        # the verifier sizes its host staging ring from pipeline_depth —
-        # it must cover THIS window or a slot could be rewritten while
-        # its dispatch is still in flight (CPU PJRT may alias host
-        # buffers zero-copy into the program)
-        if getattr(verifier, "pipeline_depth", self.depth) < self.depth:
-            verifier.pipeline_depth = self.depth
+        # the verifier's host staging ring must cover THIS window or a
+        # slot could be rewritten while its dispatch is still in flight
+        # (CPU PJRT may alias host buffers zero-copy into the program)
+        cover = getattr(verifier, "cover_in_flight", None)
+        if callable(cover):
+            cover(self.depth)
         if fixed_bucket is not None:
             verifier.fixed_bucket = fixed_bucket
         #: (pending handle, chunk) FIFO — the chunk rides along so a
@@ -116,7 +111,7 @@ class VerifierPipeline(Verifier):
         self.poisoned_windows = 0
         self.quarantined = 0
         self.quarantine_rejected = 0
-        #: cumulative window accounting (the bench's amortization gauges)
+        #: cumulative window accounting
         self.dispatches = 0
         self.sigs_dispatched = 0
         self.wait_s = 0.0  # host blocked in resolve (unhidden device time)
@@ -313,19 +308,12 @@ class VerifierPipeline(Verifier):
         seconds ``overlap()`` took."""
         self.last_wait_s = 0.0
         self.last_max_depth = len(self._inflight)
-        # pipeline_enabled off (bench's sync A/B side) caps the window at
-        # 1: dispatch-then-resolve, the pre-pipeline serial shape
-        depth = (
-            self.depth
-            if getattr(self.verifier, "pipeline_enabled", True)
-            else 1
-        )
         cap = getattr(self.verifier, "fixed_bucket", None) or len(vertices)
         cap = max(int(cap), 1)
         mask: List[bool] = []
         chunks = [vertices[i : i + cap] for i in range(0, len(vertices), cap)]
         async_prep = (
-            depth > 1
+            self.depth > 1
             and len(chunks) > 1
             and callable(getattr(self.verifier, "prep_batch_async", None))
             and callable(getattr(self.verifier, "dispatch_prepped", None))
@@ -336,9 +324,9 @@ class VerifierPipeline(Verifier):
             # k executes on the device. At most 2 preps outstanding, and
             # a new prep is submitted only AFTER the window has drained
             # below depth and the current chunk has dispatched — so when
-            # prep j+2 claims staging slot (j+2) mod (pipeline_depth+2),
-            # that slot's previous dispatch (chunk <= j-depth) has
-            # already resolved.
+            # prep j+2 claims staging slot (j+2) mod (depth + 2), that
+            # slot's previous dispatch (chunk <= j-depth) has already
+            # resolved.
             preps: Deque = deque()
             nxt = 0
             while nxt < len(chunks) and len(preps) < 2:
@@ -353,7 +341,7 @@ class VerifierPipeline(Verifier):
                 except Exception:  # noqa: BLE001 — prep fault contained
                     self._contain(chunk, failed_first=False)
                 else:
-                    while self._pending() >= depth:
+                    while self._pending() >= self.depth:
                         mask.extend(self._resolve_oldest())
                     self._dispatch_prepped(prepped, chunk)
                 if nxt < len(chunks):
@@ -366,7 +354,7 @@ class VerifierPipeline(Verifier):
                     nxt += 1
         else:
             for chunk in chunks:
-                while self._pending() >= depth:
+                while self._pending() >= self.depth:
                     mask.extend(self._resolve_oldest())
                 self._dispatch(chunk)
         overlap_s = 0.0
@@ -374,7 +362,7 @@ class VerifierPipeline(Verifier):
             with obs.span("seam.overlap") as overlapped:
                 overlap()
             overlap_s = overlapped.seconds
-        keep = max(0, depth - 1) if hold_tail else 0
+        keep = max(0, self.depth - 1) if hold_tail else 0
         while self._pending() > keep:
             mask.extend(self._resolve_oldest())
         return mask, overlap_s
@@ -466,20 +454,16 @@ class VerifierPipeline(Verifier):
         return out
 
     def resilience_stats(self) -> dict:
-        """Round-9 containment gauges, pipeline window + wrapped
-        verifier's own chunk-streaming path combined. Same key shape as
+        """The window's containment gauges. Same key shape as
         ResilientVerifier.resilience_stats so consumers (Simulation's
-        metrics fan-out, the bench's verifier_breakdown) read either."""
+        metrics fan-out) read either."""
         return {
             "retries": getattr(self.verifier, "retries_total", 0),
             "fallback_tier": 0,
             "fallbacks": 0,
-            "poisoned_windows": self.poisoned_windows
-            + getattr(self.verifier, "poisoned_windows", 0),
-            "quarantined": self.quarantined
-            + getattr(self.verifier, "quarantined_chunks", 0),
-            "quarantine_rejected": self.quarantine_rejected
-            + getattr(self.verifier, "quarantine_rejected", 0),
+            "poisoned_windows": self.poisoned_windows,
+            "quarantined": self.quarantined,
+            "quarantine_rejected": self.quarantine_rejected,
             "sidecar_rpc_failures": getattr(self.verifier, "rpc_failures", 0),
             "sidecar_health": None,
         }

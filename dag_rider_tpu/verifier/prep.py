@@ -1,7 +1,7 @@
 """Parallel host-prep engine (round-8 tentpole).
 
-PROFILE.md round 7 leaves the verify hot path HOST-bound: `_prepare`
-runs single-threaded at ~115k rows/s clean and degrades to ~9.5 ms/round
+Round 7 left the verify hot path HOST-bound: `_prepare`
+ran single-threaded at ~115k rows/s clean and degrades to ~9.5 ms/round
 under consensus contention, while the dedup analysis caps the in-loop
 applied rate at ~58k msg/s of host path. The device is no longer the
 ceiling — one Python thread feeding it is. This module owns the two
@@ -24,21 +24,20 @@ computed:
   destination arrays zero-copy.
 - **seam executor** — a single dedicated FIFO thread
   (:meth:`PrepEngine.submit`) that the pipeline callers
-  (``VerifierPipeline.run_coalesced``, the chunk-streaming
-  ``TPUVerifier.verify_rounds``) queue whole `prep_batch` calls on:
+  (``VerifierPipeline.run_coalesced``) queue whole `prep_batch` calls on:
   chunk k+2's prep runs concurrently with chunk k+1's prep (queued
   behind it) and chunk k's device execution, deepening the overlap the
   depth-K window already buys. One thread — never more — so
   staging-ring slots are still claimed strictly in chunk order and the
-  ring's ``pipeline_depth + 2`` slots cover the at-most-2 outstanding
+  ring's depth + 2 slots cover the at-most-2 outstanding
   preps plus the depth-K in-flight dispatches.
 
 Knobs: ``DAGRIDER_PREP_WORKERS`` (env, default 1 = serial — the
 pre-round-8 shape) and ``verify_prep_workers`` (node.py config) /
 ``TPUVerifier.prep_workers`` (attribute) for per-instance overrides.
 Gauges (`workers`, `last_blocks`, `parallel_fraction`) surface through
-``TPUVerifier.prep_stats`` into pipeline stats, the bench's
-``verifier_breakdown`` and the per-process metrics snapshot.
+``TPUVerifier.prep_stats`` into pipeline stats and the per-process
+metrics snapshot.
 """
 
 from __future__ import annotations

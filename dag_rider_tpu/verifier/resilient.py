@@ -21,9 +21,9 @@ fallback, permanently rejecting valid vertices from the DAG.
   does — else a zero-cost empty verify); the first successful probe
   promotes the tier back, so recovery is automatic and the ladder does
   not stay pinned to its floor forever.
-- **quarantine wiring** — tiers exposing a ``quarantine_verifier`` slot
-  (VerifierPipeline, TPUVerifier) get their NEXT tier wired into it, so
-  a chunk a poisoned pipeline window quarantines is re-verified once on
+- **quarantine wiring** — a tier exposing a ``quarantine_verifier`` slot
+  (VerifierPipeline) gets its NEXT tier wired into it, so a chunk a
+  poisoned pipeline window quarantines is re-verified once on
   the ladder's next tier instead of serially on the tier that just
   failed.
 
@@ -267,8 +267,8 @@ class ResilientVerifier(Verifier):
     def resilience_stats(self) -> dict:
         """The round-9 gauge bundle (verify_retries / verify_fallback_tier
         / verify_quarantined / sidecar_health) aggregated across tiers —
-        surfaced into pipeline stats, the bench's verifier_breakdown and
-        the per-process metrics snapshot."""
+        surfaced into pipeline stats and the per-process metrics
+        snapshot."""
         retries = self.retries_total
         quarantined = 0
         poisoned = 0
@@ -277,7 +277,7 @@ class ResilientVerifier(Verifier):
         sidecar_health = None
         health = self.tier_health()
         for i, tier in enumerate(self.tiers):
-            # a pipeline tier already folds its wrapped verifier in
+            # only a tier with a window (VerifierPipeline) contains faults
             sub = getattr(tier, "resilience_stats", None)
             if callable(sub):
                 s = sub()
@@ -287,9 +287,6 @@ class ResilientVerifier(Verifier):
                 rejected += s.get("quarantine_rejected", 0)
             else:
                 retries += getattr(tier, "retries_total", 0)
-                quarantined += getattr(tier, "quarantined_chunks", 0)
-                poisoned += getattr(tier, "poisoned_windows", 0)
-                rejected += getattr(tier, "quarantine_rejected", 0)
             rpc = getattr(tier, "rpc_failures", None)
             if rpc is not None:
                 rpc_failures += rpc

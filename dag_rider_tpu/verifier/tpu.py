@@ -46,6 +46,9 @@ from dag_rider_tpu.verifier.base import (
 from dag_rider_tpu.verifier.prep import PrepEngine
 
 _MIN_BUCKET = 16
+#: columns of the u8 transfer: 64 + 64 nibble digits of s and k, then
+#: R's sign bit, prevalid, a_valid (see _device_verify_comb)
+_U8_COLS = 131
 
 
 def _native_enabled() -> bool:
@@ -167,39 +170,6 @@ def _device_verify_comb(
     )
 
 
-@functools.partial(jax.jit, static_argnames=("impl",))
-def _device_verify_comb8(
-    u8: jax.Array,
-    i32: jax.Array,
-    key_tables: jax.Array,
-    b_table: jax.Array,
-    impl: str = "jnp",
-) -> jax.Array:
-    """8-bit-window twin of :func:`_device_verify_comb` — u8 carries raw
-    scalar BYTES (32+32) instead of nibble digits."""
-    from dag_rider_tpu.ops import comb
-
-    s_bytes = u8[:, :32].astype(jnp.int32)
-    k_bytes = u8[:, 32:64].astype(jnp.int32)
-    r_sign = u8[:, 64].astype(jnp.int32)
-    prevalid = u8[:, 65].astype(bool)
-    a_valid = u8[:, 66].astype(bool)
-    key_idx = i32[:, 0]
-    r_y = i32[:, 1:]
-    return comb.comb_verify_core8(
-        s_bytes,
-        k_bytes,
-        key_idx,
-        key_tables,
-        b_table,
-        a_valid,
-        r_y,
-        r_sign,
-        prevalid,
-        impl=impl,
-    )
-
-
 _B_TABLE_CACHED: Optional[np.ndarray] = None
 
 
@@ -212,36 +182,11 @@ def _b_table_cached() -> np.ndarray:
     return _B_TABLE_CACHED
 
 
-# Keyed by the default backend's platform name: ~34 MB of device memory
-# per entry, so a backend switch (cpu tests after a tpu run, or vice
-# versa) must not serve arrays resident on the wrong device (ADVICE r3).
-_B_TABLE8_DEV: dict = {}
-
-
-def _b_table8_dev():
-    """8-bit base-point table (registry-independent, device-resident) —
-    built once per process *per backend* through the same device builder
-    on a one-key "registry" holding B itself."""
-    backend = jax.default_backend()
-    if backend not in _B_TABLE8_DEV:
-        from dag_rider_tpu.crypto import ed25519
-        from dag_rider_tpu.ops import comb, field
-
-        bx, by, _, bt = ed25519.B
-        built = comb.build_key_tables8(
-            jnp.asarray(field.to_limbs(bx)[None]),
-            jnp.asarray(field.to_limbs(by)[None]),
-            jnp.asarray(field.to_limbs(bt)[None]),
-        )[0]
-        _B_TABLE8_DEV[backend] = jax.jit(comb.pad_rows)(built)
-    return _B_TABLE8_DEV[backend]
-
-
 def _comb_impl(size: int) -> str:
     """Pallas kernels on the TPU backend for lane-aligned batches;
     portable jnp everywhere else. Both are bit-identical — this is purely
-    a speed selection (PROFILE.md round 3: the jnp tree is memory-bound
-    on HLO temps; the kernels do one HBM pass per operand)."""
+    a speed selection (the jnp tree is memory-bound on HLO temps; the
+    kernels do one HBM pass per operand)."""
     if not config.env_flag("DAGRIDER_PALLAS_GROUP"):
         return "jnp"
     if size >= 128 and jax.default_backend() == "tpu":
@@ -272,24 +217,24 @@ class TPUVerifier(Verifier):
 
     Two ways to run it. Raw, ``fixed_bucket`` unset: every batch pads to
     its own power-of-two bucket and each new shape compiles on first
-    use — the bench's merged dispatches and the tests. Serving:
-    :meth:`warmup` fixes one bucket and compiles its program, and from
-    then on every batch is padded or chunked to that shape, so nothing
-    compiles after start-up. Every stack that contains faults
-    (VerifierPipeline, the sidecar, a node through either) is of the
-    second kind: a program the chip refuses fails construction, never a
-    window that would turn it into a rejected batch.
+    use — the tests. Serving: :meth:`warmup` fixes one bucket and
+    compiles its program, and from then on every batch is padded or
+    chunked to that shape, so nothing compiles after start-up.
+
+    One program a bucket — prep, dispatch, resolve — and no window: a
+    batch larger than the bucket is cut at it and each chunk dispatched
+    and resolved in turn, and a fault raises. The in-flight window with
+    its containment is :class:`~dag_rider_tpu.verifier.pipeline.
+    VerifierPipeline`, which every stack that contains faults holds over
+    this class (a node, the simulator); the sidecar turns a raise into a
+    failed RPC, which its client answers fail-closed.
     """
 
-    def __init__(self, registry: KeyRegistry, comb: Optional[bool] = None):
-        """``comb=True`` (the default, DAGRIDER_COMB=0 to flip) uses the
-        fixed-key comb path (ops/comb.py): per-key tables built on device
-        once, ~2.5x fewer field muls per signature, identical accept
-        masks. ``comb=False`` is the original windowed path — kept as the
-        differential oracle and for registries too large for table HBM
-        (~360 KB/key)."""
-        if comb is None:
-            comb = config.env_flag("DAGRIDER_COMB")
+    def __init__(self, registry: KeyRegistry, comb: bool = True):
+        """``comb=True`` uses the fixed-key comb path (ops/comb.py):
+        per-key tables built on device once, ~2.5x fewer field muls per
+        signature, identical accept masks. ``comb=False`` is the
+        original windowed path — the tests' differential oracle."""
         self._comb = comb
         if _native_enabled():
             # build/load now: a broken toolchain fails construction,
@@ -300,16 +245,8 @@ class TPUVerifier(Verifier):
         #: platform / device_kind of the backend every dispatch lands on
         self.platform = device_platform()
         self.device_kind = jax.devices()[0].device_kind
-        # Window width. 8-bit tables halve the gather rows and tree
-        # levels but cost 16x the HBM (1.07 GB padded at n=256) and
-        # measured NO faster on the chip in round 3 (56.6k vs 62.0k
-        # sigs/s at 16k merged — the bigger table's gather locality eats
-        # the row-count saving), so 4-bit is the default and 8-bit
-        # stays as a correct, tested variant (DAGRIDER_COMB_BITS=8).
-        bits_env = config.env_choice("DAGRIDER_COMB_BITS")
-        self._comb_bits = int(bits_env) if bits_env else 4
         self._key_tables = None  # device tables, built lazily
-        # compiled executables keyed (size, impl, bits) — see _program()
+        # compiled executables keyed (size, impl) — see _program()
         self._aot: dict = {}
         #: lower+compile seconds of each program in _aot, same keys
         self.compile_s: dict = {}
@@ -321,15 +258,14 @@ class TPUVerifier(Verifier):
         # reusable host staging rings per padded size — see _stage()
         self._staging: dict = {}
         self._staging_idx: dict = {}
+        # two dispatches in flight (a direct user of dispatch_batch /
+        # resolve_batch) and two preps ahead; a window holder asks for
+        # more through cover_in_flight()
+        self._ring_slots = 4
         # parallel host-prep engine (verifier/prep.py), built lazily by
         # _prep() so a prep_workers override set after construction
         # still takes effect on first use
         self._prep_engine: Optional[PrepEngine] = None
-        from dag_rider_tpu.verifier.pipeline import default_depth
-
-        #: in-flight window depth for the chunk-streaming verify_rounds
-        #: path (and the default for wrapping VerifierPipelines)
-        self.pipeline_depth = default_depth()
         #: cumulative seconds spent in warmup()'s AOT lower+compile
         self.warmup_compile_s = 0.0
         self.registry = registry
@@ -436,20 +372,11 @@ class TPUVerifier(Verifier):
             u8, i32 = dest
             u8 = u8[lo:hi]
             i32 = i32[lo:hi]
-            if self._comb_bits == 8:
-                u8[:, :32] = np.where(prevalid[:, None], s_raw, 0)
-                u8[:, 32:64] = k_raw
-                u8[:, 64] = r_sign
-                u8[:, 65] = prevalid
-                u8[:, 66] = self._a_valid[src] & prevalid
-            else:
-                u8[:, :64] = nibbles_batch(
-                    np.where(prevalid[:, None], s_raw, 0)
-                )
-                u8[:, 64:128] = nibbles_batch(k_raw)
-                u8[:, 128] = r_sign
-                u8[:, 129] = prevalid
-                u8[:, 130] = self._a_valid[src] & prevalid
+            u8[:, :64] = nibbles_batch(np.where(prevalid[:, None], s_raw, 0))
+            u8[:, 64:128] = nibbles_batch(k_raw)
+            u8[:, 128] = r_sign
+            u8[:, 129] = prevalid
+            u8[:, 130] = self._a_valid[src] & prevalid
             i32[:, 0] = src
             i32[:, 1:] = r_y_limbs
             return
@@ -475,10 +402,9 @@ class TPUVerifier(Verifier):
 
         comb mode packs two transfers instead of seven: per-transfer
         latency was a large share of the fixed dispatch cost when last
-        measured (round 3). u8 carries digits + flag bits; i32 carries
-        key index + R.y limbs. 8-bit windows ship the raw scalar bytes;
-        4-bit ships nibble digits. Every row and column of the output is
-        fully overwritten, so the caller may hand in a reused staging
+        measured (round 3). u8 carries nibble digits + flag bits; i32
+        carries key index + R.y limbs. Every row and column of the output
+        is fully overwritten, so the caller may hand in a reused staging
         pair (out=) — see _stage() for the aliasing discipline.
 
         The row fill itself runs through the prep engine
@@ -491,9 +417,8 @@ class TPUVerifier(Verifier):
             if out is not None:
                 dest: Tuple[np.ndarray, ...] = out
             else:
-                cols = 67 if self._comb_bits == 8 else 131
                 dest = (
-                    np.empty((size, cols), dtype=np.uint8),
+                    np.empty((size, _U8_COLS), dtype=np.uint8),
                     np.empty((size, 23), dtype=np.int32),
                 )
         else:
@@ -522,49 +447,43 @@ class TPUVerifier(Verifier):
             from dag_rider_tpu.ops import comb
 
             t0 = time.perf_counter()
-            if self._comb_bits == 8:
-                built = comb.build_key_tables8(
-                    jnp.asarray(self._a_x),
-                    jnp.asarray(self._a_y),
-                    jnp.asarray(self._a_t),
-                )
-                self._b_table_dev = _b_table8_dev()
-            else:
-                built = comb.build_key_tables(
-                    jnp.asarray(self._a_x),
-                    jnp.asarray(self._a_y),
-                    jnp.asarray(self._a_t),
-                )
-                self._b_table_dev = jax.jit(comb.pad_rows)(
-                    jnp.asarray(_b_table_cached())
-                )
+            built = comb.build_key_tables(
+                jnp.asarray(self._a_x),
+                jnp.asarray(self._a_y),
+                jnp.asarray(self._a_t),
+            )
+            self._b_table_dev = jax.jit(comb.pad_rows)(
+                jnp.asarray(_b_table_cached())
+            )
             self._key_tables = jax.jit(comb.pad_rows)(built)
             jax.block_until_ready((self._key_tables, self._b_table_dev))
             self.table_build_s = time.perf_counter() - t0
         return self._key_tables, self._b_table_dev
 
-    def _stage(self, size: int, cols: int) -> Tuple[np.ndarray, np.ndarray]:
+    def cover_in_flight(self, depth: int) -> None:
+        """A window holder (VerifierPipeline) says how many dispatches it
+        keeps in flight; the staging ring grows to cover them and the
+        two preps that run ahead of the window (see _stage)."""
+        self._ring_slots = max(self._ring_slots, int(depth) + 2)
+
+    def _stage(self, size: int) -> Tuple[np.ndarray, np.ndarray]:
         """Reusable (u8, i32) host staging pair for one dispatch.
 
         A small ring instead of a fresh np.empty per dispatch: the CPU
         PJRT client may alias a host array zero-copy into the program, so
         a slot must not be rewritten while a dispatch that shipped it can
-        still be executing. The ring holds pipeline_depth + 2 slots and
-        every supported window keeps at most pipeline_depth dispatches in
-        flight, so a slot's previous dispatch has always resolved before
-        the slot comes around again."""
+        still be executing. The ring holds the window's depth + 2 slots
+        (cover_in_flight) and the window keeps at most depth dispatches
+        in flight, so a slot's previous dispatch has always resolved
+        before the slot comes around again."""
         ring = self._staging.get(size)
-        if (
-            ring is None
-            or ring[0][0].shape[1] != cols
-            or len(ring) < self.pipeline_depth + 2
-        ):
+        if ring is None or len(ring) < self._ring_slots:
             ring = [
                 (
-                    np.empty((size, cols), dtype=np.uint8),
+                    np.empty((size, _U8_COLS), dtype=np.uint8),
                     np.empty((size, 23), dtype=np.int32),
                 )
-                for _ in range(self.pipeline_depth + 2)
+                for _ in range(self._ring_slots)
             ]
             self._staging[size] = ring
             self._staging_idx[size] = 0
@@ -603,7 +522,7 @@ class TPUVerifier(Verifier):
 
     def _aot_key(self, size: int, impl: str) -> tuple:
         """Cache key for the AOT-compiled program at this shape."""
-        return (size, impl, self._comb_bits)
+        return (size, impl)
 
     def _put(self, arr: np.ndarray) -> jax.Array:
         """Host staging array -> committed device input."""
@@ -622,12 +541,8 @@ class TPUVerifier(Verifier):
         (The inputs are not donated: the only output is the bool mask,
         which can alias neither — on the chip XLA answered a donation
         with "Some donated buffers were not usable", PR 21.)"""
-        if self._comb_bits == 8:
-            cols, fn = 67, _device_verify_comb8
-        else:
-            cols, fn = 131, _device_verify_comb
-        return fn.lower(
-            jax.ShapeDtypeStruct((size, cols), jnp.uint8),
+        return _device_verify_comb.lower(
+            jax.ShapeDtypeStruct((size, _U8_COLS), jnp.uint8),
             jax.ShapeDtypeStruct((size, 23), jnp.int32),
             tables,
             b_tab,
@@ -669,11 +584,11 @@ class TPUVerifier(Verifier):
         (VerifierPipeline at construction and again before each window
         opens, VerifierSidecarServer before its port opens, a node
         through either). From here on every batch is padded, or chunked
-        (verify_batch, verify_rounds), to this one shape, so the first
-        consensus round never eats the XLA compile and nothing compiles
-        inside a fault-contained window: a program the chip refuses
-        raises here. With the persistent cache the lower+compile is a
-        disk hit after the first ever run. A call that compiled then
+        (verify_batch), to this one shape, so the first consensus round
+        never eats the XLA compile and nothing compiles inside a
+        fault-contained window: a program the chip refuses raises here.
+        With the persistent cache the lower+compile is a disk hit after
+        the first ever run. A call that compiled then
         collects the heap once and freezes it (``gc.freeze``), so the
         served path's full collections walk only what it makes itself;
         a call that found the program there freezes nothing. Returns
@@ -711,16 +626,15 @@ class TPUVerifier(Verifier):
         return self.compile_s[key]
 
     #: host-prep / device-dispatch seconds of the most recent
-    #: verify_batch call — the host/device split the bench reports.
+    #: verify_batch call.
     last_prepare_s: float = 0.0
     last_dispatch_s: float = 0.0
 
     #: Cumulative verifier-seam accounting across a whole run: how much
     #: wall time went to host prep vs device dispatch+sync, over how
-    #: many dispatches and signatures. The bench's sim rungs report
-    #: these so an in-loop sigs/s shortfall is ATTRIBUTABLE (fixed
-    #: per-dispatch cost vs host consensus work) instead of a bare
-    #: number.
+    #: many dispatches and signatures (stats()), so an in-loop
+    #: shortfall is attributable: fixed per-dispatch cost or host
+    #: consensus work.
     total_prepare_s: float = 0.0
     total_dispatch_s: float = 0.0
     total_dispatches: int = 0
@@ -733,30 +647,6 @@ class TPUVerifier(Verifier):
     #: wander.
     fixed_bucket: Optional[int] = None
 
-    #: Explicit A/B switch for the async seam. False forces every
-    #: consumer (Simulation.run, the chunk-streaming verify_rounds, a
-    #: wrapping VerifierPipeline) onto the synchronous depth-1
-    #: dispatch-then-resolve shape — the bench's pipeline-off rung.
-    #: Replaces the round-5 instance-attribute None shadow of
-    #: dispatch_batch/resolve_batch (and the _unshadowed MRO walk that
-    #: let verify_batch reach past it).
-    pipeline_enabled: bool = True
-
-    #: Next-tier verifier for chunks quarantined out of a poisoned
-    #: window. Wired by ResilientVerifier (verifier/resilient.py) so a
-    #: chunk whose dispatch/resolve failed is re-verified once on the
-    #: ladder's next tier; None = one serial retry on this verifier,
-    #: then fail closed for that chunk.
-    quarantine_verifier: Optional[Verifier] = None
-
-    #: Fault-containment gauges (round 9): windows poisoned by a
-    #: dispatch/resolve/prep exception, chunks re-verified in
-    #: quarantine, and quarantine retries that failed too (those chunks
-    #: read all-False — fail closed).
-    poisoned_windows: int = 0
-    quarantined_chunks: int = 0
-    quarantine_rejected: int = 0
-
     #: Requested worker count for the parallel host-prep engine
     #: (verifier/prep.py). None defers to DAGRIDER_PREP_WORKERS (default
     #: 1 = serial). Assigning a new value rebuilds the engine on the
@@ -766,9 +656,8 @@ class TPUVerifier(Verifier):
 
     def _prep(self) -> PrepEngine:
         """The verifier's prep engine, (re)built lazily so a
-        ``prep_workers`` override picked up between runs takes effect —
-        the bench's 1-vs-N A/B flips it on one verifier without losing
-        the compiled programs or comb tables."""
+        ``prep_workers`` override picked up between runs takes effect
+        without losing the compiled programs or comb tables."""
         want = (
             int(self.prep_workers) if self.prep_workers is not None else None
         )
@@ -781,10 +670,10 @@ class TPUVerifier(Verifier):
 
     def prep_stats(self) -> dict:
         """Gauges of the parallel host-prep engine — surfaced through
-        pipeline stats(), the bench's verifier_breakdown and the
-        per-process metrics snapshot. ``parallel_fraction`` is the
-        no-silent-fallback gauge: rows that actually took the row-block
-        parallel path over all rows prepped."""
+        pipeline stats() and the per-process metrics snapshot.
+        ``parallel_fraction`` is the no-silent-fallback gauge: rows that
+        actually took the row-block parallel path over all rows
+        prepped."""
         eng = self._prep()
         return {
             "workers": eng.workers,
@@ -797,8 +686,7 @@ class TPUVerifier(Verifier):
 
     def stats(self) -> dict:
         """Where the work ran and what it cost: the backend, the program
-        of the latest dispatch, the cumulative seam accounting and the
-        containment counters — always present, zero on a clean run."""
+        of the latest dispatch and the cumulative seam accounting."""
         return {
             "platform": self.platform,
             "device_kind": self.device_kind,
@@ -813,9 +701,6 @@ class TPUVerifier(Verifier):
                 "x".join(str(p) for p in k[:2]): round(v, 2)
                 for k, v in self.compile_s.items()
             },
-            "poisoned_windows": self.poisoned_windows,
-            "quarantined": self.quarantined_chunks,
-            "quarantine_rejected": self.quarantine_rejected,
         }
 
     def prep_batch(self, vertices: Sequence[Vertex]) -> "PreppedBatch":
@@ -835,11 +720,7 @@ class TPUVerifier(Verifier):
         else:
             size = self._round_bucket(_bucket(len(vertices)))
         with obs.span("verify_batch.prepare") as prepare:
-            out = (
-                self._stage(size, 67 if self._comb_bits == 8 else 131)
-                if self._comb
-                else None
-            )
+            out = self._stage(size) if self._comb else None
             args = self._prepare(vertices, size, comb=self._comb, out=out)
         return PreppedBatch(args, size, len(vertices), prepare.seconds)
 
@@ -849,8 +730,8 @@ class TPUVerifier(Verifier):
         callers use this to run chunk k+2's prep concurrently with chunk
         k+1's prep and chunk k's device execution. Callers keep at most
         2 preps outstanding and submit a new one only after the window
-        has drained below depth — with the staging ring's
-        pipeline_depth + 2 slots that guarantees a slot's previous
+        has drained below depth — with the staging ring's depth + 2
+        slots (cover_in_flight) that guarantees a slot's previous
         dispatch has resolved before the slot is claimed again."""
         return self._prep().submit(self.prep_batch, vertices)
 
@@ -877,62 +758,6 @@ class TPUVerifier(Verifier):
                 mask = self._windowed_dispatch(args)
         return mask, count
 
-    # -- fault containment (round 9) --------------------------------------
-
-    def _quarantine(self, vertices: Sequence[Vertex]) -> List[bool]:
-        """Re-verify a chunk out of a poisoned window exactly once: on
-        the ladder's next tier when one is wired (quarantine_verifier),
-        else a fresh serial dispatch on this verifier. A second failure
-        rejects the chunk — fail closed, never fail open."""
-        self.quarantined_chunks += 1
-        vs = list(vertices)
-        try:
-            if self.quarantine_verifier is not None:
-                return self.quarantine_verifier.verify_batch(vs)
-            return self._resolve_timed(self.dispatch_batch(vs))
-        except Exception:  # noqa: BLE001 — second failure fail-closes
-            self.quarantine_rejected += 1
-            return [False] * len(vs)
-
-    def _contain_stream(
-        self, inflight, chunk: Sequence[Vertex], failed_first: bool
-    ) -> List[bool]:
-        """Contain a fault in the chunk-streaming window: salvage every
-        in-flight entry (resolve it; a second fault quarantines that
-        chunk too), re-arm the staging ring, then quarantine the failing
-        chunk. Returns the masks in FIFO chunk order — ``failed_first``
-        is True for a resolve fault (the failed chunk was the oldest,
-        already popped) and False for a prep/dispatch fault (the failed
-        chunk never entered the window, so salvaged chunks come first).
-        """
-        self.poisoned_windows += 1
-        salvaged = []  # (mask-or-None, chunk) in FIFO order
-        while inflight:
-            h, ch = inflight.popleft()
-            try:
-                salvaged.append((self._resolve_timed(h), ch))
-            except Exception:  # noqa: BLE001 — quarantined after reset
-                salvaged.append((None, ch))
-        self.reset_staging()
-        out: List[bool] = []
-        if failed_first:
-            out.extend(self._quarantine(chunk))
-        for m, ch in salvaged:
-            out.extend(m if m is not None else self._quarantine(ch))
-        if not failed_first:
-            out.extend(self._quarantine(chunk))
-        return out
-
-    def _resolve_stream(self, inflight) -> List[bool]:
-        """Resolve the oldest in-flight chunk, containing a resolve
-        fault (the rest of the window is salvaged, the ring re-armed,
-        and the failing chunk quarantined)."""
-        h, ch = inflight.popleft()
-        try:
-            return self._resolve_timed(h)
-        except Exception:  # noqa: BLE001 — contained, not propagated
-            return self._contain_stream(inflight, ch, failed_first=True)
-
     def dispatch_batch(self, vertices: Sequence[Vertex]):
         """Asynchronous half of verify: host prep + device dispatch, NO
         sync. Returns an opaque (device_mask, count) pending handle for
@@ -946,107 +771,15 @@ class TPUVerifier(Verifier):
     def verify_rounds(
         self, rounds: Sequence[Sequence[Vertex]]
     ) -> List[List[bool]]:
-        """Verify several DAG rounds in ONE device dispatch.
-
-        The per-dispatch cost had a large fixed component when last
-        measured (round 3, PROFILE.md), amortized by merging consecutive rounds' batches into a single
-        padded dispatch and splitting the mask after. Used by the bench's
-        merged steady-state phase and available to catch-up sync / burst
-        consumers.
-
-        Merges larger than the fixed bucket STREAM their chunks through
-        the async seam with a depth-K in-flight window (K =
-        pipeline_depth; 1 when pipeline_enabled is off): chunk k+1's
-        host prep overlaps chunk k's device execution instead of the old
-        serial dispatch-then-resolve loop. With the window open, chunk
-        prep additionally runs ahead on the prep engine's seam thread
-        (prep_batch_async) — chunk k+2's prep overlaps chunk k+1's prep
-        and chunk k's execution. Chunk boundaries and FIFO resolve order
-        are unchanged, so the mask stays byte-identical.
-
-        A prep/dispatch/resolve exception is CONTAINED, not propagated
-        (round 9): the window is salvaged, the staging ring re-armed,
-        and the failing chunk quarantined (_contain_stream) — the merge
-        always returns a full mask, wedging nothing upstream. The
-        bucket's program is compiled before the window opens, so a
-        compile failure is not among the faults it can see.
-        """
-        lens = [len(r) for r in rounds]
-        flat = [v for r in rounds for v in r]
-        if not flat:
-            return [[] for _ in rounds]
-        cap = self.fixed_bucket
-        if cap and len(flat) > cap:
-            from collections import deque
-
-            self.warmup()  # every chunk below runs this one program
-            depth = self.pipeline_depth if self.pipeline_enabled else 1
-            chunks = [flat[i : i + cap] for i in range(0, len(flat), cap)]
-            inflight: deque = deque()  # (pending handle, chunk) FIFO
-            mask = []
-            if depth > 1 and len(chunks) > 1:
-                # Prep-ahead ordering discipline: at most 2 prep futures
-                # outstanding, and a new prep is queued only AFTER the
-                # window has been drained below depth and the current
-                # chunk dispatched — so when prep(j) claims ring slot
-                # (j mod (depth+2)), the slot's previous claimant
-                # (chunk j-depth-2) has already resolved. See _stage().
-                preps: deque = deque()
-                nxt = 0
-                while nxt < len(chunks) and len(preps) < 2:
-                    preps.append(
-                        (self.prep_batch_async(chunks[nxt]), chunks[nxt])
-                    )
-                    nxt += 1
-                while preps:
-                    fut, chunk = preps.popleft()
-                    try:
-                        prepped = fut.result()
-                    except Exception:  # noqa: BLE001 — prep fault
-                        mask.extend(
-                            self._contain_stream(
-                                inflight, chunk, failed_first=False
-                            )
-                        )
-                        prepped = None
-                    if prepped is not None:
-                        while len(inflight) >= depth:
-                            mask.extend(self._resolve_stream(inflight))
-                        try:
-                            inflight.append(
-                                (self.dispatch_prepped(prepped), chunk)
-                            )
-                        except Exception:  # noqa: BLE001 — dispatch fault
-                            mask.extend(
-                                self._contain_stream(
-                                    inflight, chunk, failed_first=False
-                                )
-                            )
-                    if nxt < len(chunks):
-                        preps.append(
-                            (self.prep_batch_async(chunks[nxt]), chunks[nxt])
-                        )
-                        nxt += 1
-            else:
-                for chunk in chunks:
-                    while len(inflight) >= depth:
-                        mask.extend(self._resolve_stream(inflight))
-                    try:
-                        inflight.append((self.dispatch_batch(chunk), chunk))
-                    except Exception:  # noqa: BLE001 — prep/dispatch fault
-                        mask.extend(
-                            self._contain_stream(
-                                inflight, chunk, failed_first=False
-                            )
-                        )
-            while inflight:
-                mask.extend(self._resolve_stream(inflight))
-        else:
-            mask = self.verify_batch(flat)
+        """Verify several DAG rounds as ONE merged batch (one dispatch
+        where the merge fits the bucket, :meth:`verify_batch`'s chunks
+        where it does not) and split the mask after — for catch-up sync
+        and burst consumers. The mask is that of mapping verify_batch."""
+        mask = self.verify_batch([v for r in rounds for v in r])
         out, pos = [], 0
-        for ln in lens:
-            out.append(mask[pos : pos + ln])
-            pos += ln
+        for r in rounds:
+            out.append(mask[pos : pos + len(r)])
+            pos += len(r)
         return out
 
     @staticmethod
@@ -1057,8 +790,7 @@ class TPUVerifier(Verifier):
 
     def _resolve_timed(self, pending) -> List[bool]:
         """resolve_batch plus the device-seconds accounting the seam
-        breakdown expects (verify_batch and the chunk-streaming
-        verify_rounds both resolve through here)."""
+        breakdown expects."""
         with obs.span("verify_batch.resolve") as resolve:
             out = self.resolve_batch(pending)
         self.last_dispatch_s = resolve.seconds
@@ -1066,9 +798,18 @@ class TPUVerifier(Verifier):
         return out
 
     def verify_batch(self, vertices: Sequence[Vertex]) -> List[bool]:
+        """One dispatch, resolved at once; more than the fixed bucket
+        holds is cut at the bucket and each chunk dispatched and resolved
+        in turn. A fault raises: the window that contains faults is
+        VerifierPipeline's."""
         if not vertices:
             return []
-        if self.fixed_bucket and len(vertices) > self.fixed_bucket:
-            # more than the one program holds: stream it in chunks
-            return self.verify_rounds([vertices])[0]
-        return self._resolve_timed(self.dispatch_batch(vertices))
+        cap = self.fixed_bucket
+        if not cap or len(vertices) <= cap:
+            return self._resolve_timed(self.dispatch_batch(vertices))
+        mask: List[bool] = []
+        for i in range(0, len(vertices), cap):
+            mask.extend(
+                self._resolve_timed(self.dispatch_batch(vertices[i : i + cap]))
+            )
+        return mask

@@ -18,7 +18,7 @@ Fault plans are JSON lists of {"t": seconds-from-load-start, "action":
 "kill" | "restart" | "term", "node": i}. --kill auto generates a seeded
 kill-and-rejoin plan (one victim, never node 0). Env defaults:
 DAGRIDER_CLUSTER_TRANSPORT, DAGRIDER_CLUSTER_BOOT_S,
-DAGRIDER_CLUSTER_KEEP, DAGRIDER_CLUSTER_OUT (see README knob table).
+DAGRIDER_CLUSTER_KEEP (see README knob table).
 """
 
 from __future__ import annotations

@@ -122,10 +122,6 @@ _SLOW = {
     # sharded MSM compiles) — run them before spending chip time
     "test_chip_smoke.py::test_phase_a_rehearsal",
     "test_chip_smoke.py::test_phase_d_rehearsal_on_the_virtual_mesh",
-    # bench-rung mechanics: real consensus runs w/ device verifier
-    "test_bench_rungs.py::test_sim_rung_reports_breakdown_and_progress",
-    "test_bench_rungs.py::test_sim_rung_extends_past_box_until_target_met",
-    "test_bench_rungs.py::test_sim_rung_pipeline_off_runs_and_restores_seam",
 }
 
 

@@ -75,29 +75,75 @@ def test_knobs_flags_subscript_and_getenv_spellings():
     assert sum("DAGRIDER_B" in m for m in _msgs(got)) == 1
 
 
-def test_knobs_allows_config_and_bench_namespace():
-    got = knobs.run(
-        [
-            F(
-                "dag_rider_tpu/config.py",
-                "import os\nx = os.environ.get('DAGRIDER_PUMP')\n",
-            ),
-            F(
-                "bench.py",
-                "import os\nx = os.environ.get('DAGRIDER_BENCH_FOO')\n",
-            ),
-        ],
-        REPO,
-    )
-    assert got == []
+def test_knobs_allows_direct_reads_in_config_only():
+    """config.py is the one reader; no file or namespace is carved out
+    beside it (a root script reading a name of its own is flagged)."""
+    src = "import os\nx = os.environ.get('DAGRIDER_PUMP')\n"
+    assert knobs.run([F("dag_rider_tpu/config.py", src)], REPO) == []
+    for name in ("DAGRIDER_PUMP", "DAGRIDER_SMOKE_OWN"):
+        got = knobs.run(
+            [F("chip_smoke.py", src.replace("DAGRIDER_PUMP", name))], REPO
+        )
+        assert any(name in m for m in _msgs(got)), name
 
 
-def test_knobs_bench_cannot_read_package_namespace():
-    got = knobs.run(
-        [F("bench.py", "import os\nx = os.environ.get('DAGRIDER_PUMP')\n")],
-        REPO,
+def _sources(*extra):
+    """The files driderlint walks (the package, chip_smoke.py) plus
+    the graft entry and ``extra``."""
+    from dag_rider_tpu.analysis.core import discover
+
+    files = discover(REPO)
+    for rel in ("__graft_entry__.py", *extra):
+        with open(os.path.join(REPO, rel), encoding="utf-8") as fh:
+            files.append(F(rel, fh.read()))
+    return files
+
+
+def test_no_file_outside_config_reads_a_dagrider_variable():
+    got = [
+        f
+        for f in knobs.run(_sources(), REPO)
+        if "direct environment read" in f.message
+    ]
+    assert got == [], _msgs(got)
+
+
+def test_registry_holds_exactly_the_knobs_that_are_read():
+    """Every registered knob has a reader (an ``env_*`` accessor call
+    naming it: in the package, a root script, ``scripts/`` or the
+    suite's conftest), every name read is registered, and the README's
+    table documents the registered names and no other."""
+    import re
+
+    from dag_rider_tpu import config
+
+    scripts = sorted(
+        os.path.join("scripts", f)
+        for f in os.listdir(os.path.join(REPO, "scripts"))
+        if f.endswith(".py")
     )
-    assert any("DAGRIDER_PUMP" in m for m in _msgs(got))
+    read = set()
+    for _rel, tree, _src in _sources(*scripts, "tests/conftest.py"):
+        for node in ast.walk(tree):
+            if not (isinstance(node, ast.Call) and node.args):
+                continue
+            f = node.func
+            name = getattr(f, "attr", None) or getattr(f, "id", "")
+            arg = node.args[0]
+            if name.lstrip("_") in knobs._ACCESSORS and isinstance(
+                arg, ast.Constant
+            ):
+                read.add(arg.value)
+    assert read == set(config.KNOBS)
+    assert len(config.KNOBS) == 40
+    with open(os.path.join(REPO, "README.md"), encoding="utf-8") as fh:
+        rows = [ln for ln in fh if ln.startswith("|")]
+    documented = {
+        name
+        for ln in rows
+        for name in re.findall(r"DAGRIDER_[A-Z0-9_]+", ln.split("|")[1])
+    }
+    assert documented == set(config.KNOBS)
 
 
 def test_knobs_flags_unregistered_accessor_name():

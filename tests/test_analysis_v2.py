@@ -8,10 +8,10 @@ cross-validation ties the two lock views together — every site the
 dynamic race harness registers must be known to the static graph (the
 reverse gap is coverage intel, printed, not a failure).
 
-The release-checker fixtures reproduce the ADVICE `bench.py:734`
-defect class verbatim: the pre-round-8 sim256 rung shape (fixed_bucket
-set, restore at the bottom, nothing covering the middle) is kept here
-as the permanent regression fixture.
+The release-checker fixtures reproduce a defect class a measurement
+script once had (the file went in PR 30): fixed_bucket set, restore at
+the bottom, nothing covering the middle — kept here as the permanent
+regression fixture.
 """
 
 import ast
@@ -176,8 +176,8 @@ def test_static_lock_graph_covers_tree_sites(tree_files):
 
 # -- release: exception-safe borrow/restore --------------------------------
 
-#: the pre-round-8 bench.py sim256 shape — ADVICE bench.py:734, kept
-#: verbatim as the checker's permanent regression fixture
+#: the shape that once leaked a sim-sized bucket into the next phase,
+#: kept verbatim as the checker's permanent regression fixture
 _SIM256_LEAK_SRC = """
 def sim256_rung(verifier, batches, bucket):
     prev = verifier.fixed_bucket

@@ -1,8 +1,7 @@
 """Verifier chaos suite (round-9 tentpole acceptance).
 
-The containment machinery (VerifierPipeline._contain, the chunk-streaming
-TPUVerifier.verify_rounds loop, the PrepEngine block-pool boundary) is
-tested against the faults it claims to absorb, injected by
+The containment machinery (VerifierPipeline._contain — the one window —
+and the PrepEngine block-pool boundary) is tested against the faults it claims to absorb, injected by
 verifier/faults.py at every seam the round-7 placement hooks expose:
 
 - faults OFF (an armed injector whose plan never fires) must be
@@ -65,24 +64,23 @@ def test_faults_off_is_byte_identical(keys):
     assert any(not all(m) for m in want if m), "no corruption landed"
 
     v = TPUVerifier(reg)
-    v.fixed_bucket = 16
-    v.pipeline_depth = 2
+    pipe = VerifierPipeline(v, depth=2, fixed_bucket=16, warmup=False)
     inj = VerifierFaultInjector(VerifierFaultPlan())  # every p = 0.0
     inj.arm(v)
     try:
-        assert v.verify_rounds(rounds) == want
+        assert pipe.verify_rounds(rounds) == want
     finally:
         inj.disarm()
     assert inj.faults_injected == 0
     assert all(c == 0 for c in inj.stats.values())
-    assert v.poisoned_windows == 0
-    assert v.quarantined_chunks == 0
-    assert v.quarantine_rejected == 0
+    rs = pipe.resilience_stats()
+    assert rs["poisoned_windows"] == rs["quarantined"] == 0
+    assert rs["quarantine_rejected"] == 0
     # disarm really popped the instance shadows — class path is back
     assert "_prep_block" not in v.__dict__
     assert "dispatch_prepped" not in v.__dict__
     assert "resolve_batch" not in v.__dict__
-    assert v.verify_rounds(rounds) == want
+    assert pipe.verify_rounds(rounds) == want
 
 
 # -- bounded faults: contained, then byte-identical --------------------
@@ -125,10 +123,9 @@ def test_pipeline_contains_fault_and_recovers(keys, kind):
 
 @pytest.mark.parametrize("sharded", [False, True])
 def test_streamed_rounds_contain_faults(keys, sharded):
-    """The chunk-streaming verify_rounds window (no VerifierPipeline in
-    the path) contains a resolve fault the same way, on the single-chip
-    and the mesh-sharded verifier alike — containment lives above the
-    round-7 placement hooks."""
+    """VerifierPipeline.verify_rounds contains a resolve fault over the
+    single-chip and the mesh-sharded verifier alike — the one window
+    lives above the round-7 placement hooks."""
     reg, _ = keys
     cpu = CPUVerifier(reg)
     rng = random.Random(903 + sharded)
@@ -143,22 +140,82 @@ def test_streamed_rounds_contain_faults(keys, sharded):
         v = ShardedTPUVerifier(reg, make_mesh(8))
     else:
         v = TPUVerifier(reg)
-    v.fixed_bucket = 16
-    v.pipeline_depth = 2
+    pipe = VerifierPipeline(v, depth=2, fixed_bucket=16, warmup=False)
     inj = VerifierFaultInjector(
         VerifierFaultPlan(resolve_raise=1.0, max_faults=2, seed=903)
     )
     inj.arm(v)
     try:
-        assert v.verify_rounds(rounds) == want
+        assert pipe.verify_rounds(rounds) == want
     finally:
         inj.disarm()
     assert inj.faults_injected == 2
-    assert v.poisoned_windows >= 1
-    assert v.quarantined_chunks >= 1
-    assert v.quarantine_rejected == 0
+    rs = pipe.resilience_stats()
+    assert rs["poisoned_windows"] >= 1
+    assert rs["quarantined"] >= 1
+    assert rs["quarantine_rejected"] == 0
     # clean pass after disarm: ring re-armed, no residue
-    assert v.verify_rounds(rounds) == want
+    assert pipe.verify_rounds(rounds) == want
+
+
+# -- a bare verifier raises; the stacks over it answer in full ----------
+
+
+@pytest.mark.parametrize("stack", ["pipeline", "ladder"])
+def test_second_chunk_fault_raises_bare_and_is_answered_by_a_stack(keys, stack):
+    """A dispatch fault on the second of three chunks: a bare
+    TPUVerifier has no window to contain it and raises out of
+    verify_batch; under VerifierPipeline (containment) and under a
+    ResilientVerifier over the bare verifier with a CPU floor (the
+    ladder) the caller gets a full-length mask equal to the oracle's."""
+    from dag_rider_tpu.verifier.faults import VerifierFault
+    from dag_rider_tpu.verifier.resilient import ResilientVerifier
+
+    reg, _ = keys
+    pool = _signed_pool(keys, 48, seed=907)
+    want = CPUVerifier(reg).verify_batch(pool)
+    assert any(not ok for ok in want), "no corruption landed"
+
+    def arm(v):
+        """Shadow dispatch_prepped, as the injector does, to fail on
+        its second call only."""
+        calls = []
+        orig = v.dispatch_prepped
+
+        def dispatch_prepped(prepped):
+            calls.append(prepped.count)
+            if len(calls) == 2:
+                raise VerifierFault("injected dispatch fault, chunk 2")
+            return orig(prepped)
+
+        v.dispatch_prepped = dispatch_prepped
+        return calls
+
+    bare = TPUVerifier(reg)
+    bare.fixed_bucket = 16
+    calls = arm(bare)
+    with pytest.raises(VerifierFault):
+        bare.verify_batch(pool)
+    assert calls == [16, 16], "the fault was not on the second chunk"
+    assert "poisoned_windows" not in bare.stats()
+
+    v = TPUVerifier(reg)
+    v.fixed_bucket = 16
+    arm(v)
+    if stack == "pipeline":
+        top = VerifierPipeline(v, depth=2, warmup=False)
+    else:
+        top = ResilientVerifier(
+            [v, CPUVerifier(reg)], retries=0, probe_interval_s=0.01
+        )
+    assert top.verify_batch(pool) == want
+    rs = top.resilience_stats()
+    if stack == "pipeline":
+        assert rs["poisoned_windows"] == 1 and rs["quarantined"] >= 1
+        assert rs["quarantine_rejected"] == 0
+    else:
+        assert rs["fallbacks"] == 1 and rs["fallback_tier"] == 1
+        assert rs["poisoned_windows"] == rs["quarantined"] == 0
 
 
 # -- unbounded faults: drain, never wedge ------------------------------
@@ -251,7 +308,6 @@ def test_sim_commit_order_under_chaos_matches_fault_free(keys, kind):
 
     shared = TPUVerifier(reg)
     shared.fixed_bucket = 16
-    shared.pipeline_depth = 2
     # one fault, then clean: quarantine re-verifies on the (now clean)
     # same verifier, so the masks — and the order — cannot move
     inj = VerifierFaultInjector(

@@ -52,16 +52,13 @@ def _adversarial(vs):
     ]
 
 
-def test_comb_mask_matches_windowed_and_cpu(setup, monkeypatch):
+def test_comb_mask_matches_windowed_and_cpu(setup):
     reg, vs = setup
     batch = vs + _adversarial(vs)
     cpu = CPUVerifier(reg).verify_batch(batch)
     windowed = TPUVerifier(reg, comb=False).verify_batch(batch)
-    monkeypatch.setenv("DAGRIDER_COMB_BITS", "4")
-    comb4 = TPUVerifier(reg, comb=True).verify_batch(batch)
-    monkeypatch.setenv("DAGRIDER_COMB_BITS", "8")
-    comb8 = TPUVerifier(reg, comb=True).verify_batch(batch)
-    assert cpu == windowed == comb4 == comb8
+    comb = TPUVerifier(reg).verify_batch(batch)
+    assert cpu == windowed == comb
     assert cpu[: len(vs)] == [True] * len(vs)
     assert not any(cpu[len(vs) :])
 
@@ -107,13 +104,6 @@ def test_comb_fuzz_masks_match_cpu_oracle(setup):
     assert tv.verify_batch(batch) == cpu.verify_batch(batch)
 
 
-def test_invalid_comb_bits_env_rejected(setup, monkeypatch):
-    reg, _ = setup
-    monkeypatch.setenv("DAGRIDER_COMB_BITS", "16")
-    with pytest.raises(ValueError, match="DAGRIDER_COMB_BITS"):
-        TPUVerifier(reg, comb=True)
-
-
 def test_verify_rounds_merged_matches_per_round(setup):
     reg, vs = setup
     v = TPUVerifier(reg, comb=True)
@@ -142,16 +132,14 @@ def _host_affine(pt):
     return X * zi % F.P_INT, Y * zi % F.P_INT
 
 
-def test_comb_key_table_entries_match_host(setup, monkeypatch):
-    """Spot-check device-built comb tables: TABLE[key, w, d] == d*base^w*A
-    for both the 4-bit and 8-bit window builders."""
+def test_comb_key_table_entries_match_host(setup):
+    """Spot-check device-built comb tables: TABLE[key, w, d] == d*16^w*A."""
     import numpy as np
 
     from dag_rider_tpu.crypto import ed25519 as host
     from dag_rider_tpu.ops import field as F
 
     reg, _ = setup
-    monkeypatch.setenv("DAGRIDER_COMB_BITS", "4")
     tv = TPUVerifier(reg, comb=True)
     tables, _ = tv._comb_tables()  # padded [rows, 128] gather layout
     tab = np.asarray(tables)[:, : 4 * F.LIMBS].reshape(
@@ -161,16 +149,3 @@ def test_comb_key_table_entries_match_host(setup, monkeypatch):
         a_pt = host.point_decompress(reg.public_keys[key])
         want = _host_affine(host.scalar_mult(d * (16**w), a_pt))
         assert _affine(tab[key, w, d]) == want, (key, w, d)
-
-    monkeypatch.setenv("DAGRIDER_COMB_BITS", "8")
-    tv8 = TPUVerifier(reg, comb=True)
-    tables8, _ = tv8._comb_tables()
-    tab8 = np.asarray(tables8)[:, : 4 * F.LIMBS].reshape(
-        reg.n, 32, 256, 4, F.LIMBS
-    )
-    from dag_rider_tpu.ops.comb import DIGIT_POS8
-
-    for key, w, d in [(0, 0, 1), (1, 0, 255), (3, 2, 17), (5, 31, 128)]:
-        a_pt = host.point_decompress(reg.public_keys[key])
-        want = _host_affine(host.scalar_mult(d * (256**w), a_pt))
-        assert _affine(tab8[key, w, DIGIT_POS8[d]]) == want, (key, w, d)

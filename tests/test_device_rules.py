@@ -197,12 +197,11 @@ def test_compile_error_fails_construction_not_a_window(keys, monkeypatch):
         pipe.verify_batch(vs)
     assert pipe.stats()["poisoned_windows"] == 0
     assert pipe.stats()["quarantined"] == 0
-    # and so does the verifier's own chunk-streaming window
+    # and a bare verifier's chunk-by-chunk pass raises it as it stands
     v = TPUVerifier(reg)
     v.fixed_bucket = 4
     with pytest.raises(VerifierCompileError):
         v.verify_rounds([vs])
-    assert v.poisoned_windows == 0
 
 
 def test_node_refuses_to_start_on_a_compile_error(keys_path, monkeypatch):
@@ -237,10 +236,10 @@ def test_served_stacks_run_one_program_whatever_the_batch(keys, keys_path):
     try:
         pipe = nd.process.verifier
         base = pipe.verifier
-        assert pipe.fixed_bucket == 16 and list(base._aot) == [(16, "jnp", 4)]
+        assert pipe.fixed_bucket == 16 and list(base._aot) == [(16, "jnp")]
         for lo, hi in ((0, 1), (1, 17), (0, 40)):
             assert pipe.verify_batch(pool[lo:hi]) == want[lo:hi]
-        assert list(base._aot) == [(16, "jnp", 4)]
+        assert list(base._aot) == [(16, "jnp")]
     finally:
         nd.net.close()
 
@@ -270,7 +269,7 @@ def test_simulation_fixes_the_committees_bucket():
     v = sim.processes[0].verifier
     assert v.fixed_bucket is None and not v._aot
     sim.run(max_messages=64)
-    assert v.fixed_bucket == 16 and list(v._aot) == [(16, "jnp", 4)]
+    assert v.fixed_bucket == 16 and list(v._aot) == [(16, "jnp")]
     assert v.stats()["dispatches"] > 0 and v.stats()["bucket"] == 16
 
 
@@ -299,24 +298,19 @@ def test_warmup_fixes_and_compiles_the_committees_shape(monkeypatch):
 
 # -- the verifier says where it ran ------------------------------------
 
-_ALWAYS = (
-    "platform",
-    "device_kind",
-    "impl",
-    "bucket",
-    "poisoned_windows",
-    "quarantined",
-    "quarantine_rejected",
-)
+_WHERE = ("platform", "device_kind", "impl", "bucket")
+#: the window's containment counters: VerifierPipeline's alone
+_CONTAINED = ("poisoned_windows", "quarantined", "quarantine_rejected")
 
 
 def test_stats_carry_platform_and_counters_unconditionally(keys):
     reg, _ = keys
     v = TPUVerifier(reg)
     pipe = VerifierPipeline(v, warmup=False)
-    for stats in (v.stats(), pipe.stats()):
-        for k in _ALWAYS:
-            assert k in stats, k
+    for k in _WHERE:
+        assert k in v.stats() and k in pipe.stats(), k
+    for k in _CONTAINED:
+        assert k in pipe.stats() and k not in v.stats(), k
     assert pipe.stats()["retries"] == pipe.stats()["fallbacks"] == 0
     assert pipe.verify_batch(_signed(keys)) == [True] * 8
     after = pipe.stats()
@@ -337,7 +331,7 @@ def test_node_started_event_names_the_verifier(keys_path):
         nd.stop()
     started = [e for e in events if e.get("event") == "started"]
     assert len(started) == 1
-    for k in _ALWAYS + ("retries", "fallbacks"):
+    for k in _WHERE + _CONTAINED + ("retries", "fallbacks"):
         assert k in started[0], k
     assert started[0]["verifier"] == "VerifierPipeline"
     assert started[0]["platform"] == "cpu"

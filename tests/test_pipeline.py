@@ -2,8 +2,8 @@
 
 The pipeline changes WHEN the host blocks, never WHAT the device
 computes: masks from the depth-K window (verifier/pipeline.py), the
-chunk-streaming ``verify_rounds``, and the CPU oracle must be
-byte-identical across randomized burst shapes, window depths
+bare verifier's chunk-by-chunk ``verify_rounds``, and the CPU oracle
+must be byte-identical across randomized burst shapes, window depths
 (K in {1, 2, 4}), and ``fixed_bucket`` settings — including empty rounds
 and merges larger than the bucket (the over-cap chunking edge). The
 commit order downstream of those masks is checked end-to-end through the
@@ -77,8 +77,8 @@ def _random_rounds(pool, rng):
 @pytest.mark.parametrize("depth", [1, 2, 4])
 @pytest.mark.parametrize("bucket", [None, 16, 32])
 def test_pipeline_masks_byte_identical(keys, depth, bucket):
-    """Property: depth-K pipeline == chunk-streaming verify_rounds ==
-    CPU oracle, for every (depth, bucket) combination. A 48-vertex pool
+    """Property: depth-K pipeline == the bare verifier's chunked
+    verify_rounds == CPU oracle, for every (depth, bucket) combination. A 48-vertex pool
     against bucket 16/32 forces over-cap chunking; bucket None exercises
     the power-of-two ladder."""
     reg, _ = keys
@@ -89,10 +89,9 @@ def test_pipeline_masks_byte_identical(keys, depth, bucket):
     want = [cpu.verify_batch(r) for r in rounds]
     assert any(not all(m) for m in want if m), "no corruption landed"
 
-    streamed = TPUVerifier(reg)
-    streamed.fixed_bucket = bucket
-    streamed.pipeline_depth = depth
-    assert streamed.verify_rounds(rounds) == want
+    bare = TPUVerifier(reg)
+    bare.fixed_bucket = bucket
+    assert bare.verify_rounds(rounds) == want
 
     pipe = VerifierPipeline(
         TPUVerifier(reg), depth=depth, fixed_bucket=bucket, warmup=False
@@ -108,7 +107,7 @@ def test_pipeline_masks_byte_identical(keys, depth, bucket):
 def test_sharded_pipeline_masks_byte_identical(keys, depth, bucket):
     """Round-7 tentpole: the MESH-sharded verifier through the depth-K
     window must produce the same bytes as the CPU oracle and the
-    single-chip streamed path at every depth — chunk boundaries are set
+    single-chip path at every depth — chunk boundaries are set
     by the caller's bucket exactly as on one chip; only the padded
     dispatch size rounds up to the mesh multiple (invisible after the
     ``[:count]`` slice)."""
@@ -128,12 +127,10 @@ def test_sharded_pipeline_masks_byte_identical(keys, depth, bucket):
 
     single = TPUVerifier(reg)
     single.fixed_bucket = bucket
-    single.pipeline_depth = depth
     assert single.verify_rounds(rounds) == want
 
     sharded = ShardedTPUVerifier(reg, make_mesh(8))
     sharded.fixed_bucket = bucket
-    sharded.pipeline_depth = depth
     assert sharded.verify_rounds(rounds) == want
 
     pipe = VerifierPipeline(
@@ -192,23 +189,6 @@ def test_window_gauges_and_serial_degeneration(keys):
     assert serial.depth_hwm == 1
 
 
-def test_pipeline_enabled_off_caps_window_at_one(keys):
-    """The bench's A/B flag: pipeline_enabled=False on the wrapped
-    verifier forces the window to depth 1 — same mask, no overlap."""
-    reg, _ = keys
-    pool = _signed_pool(keys, 40, seed=5)
-    base = TPUVerifier(reg)
-    pipe = VerifierPipeline(base, depth=4, fixed_bucket=16, warmup=False)
-    on = pipe.verify_batch(pool)
-    assert pipe.last_max_depth >= 2
-    base.pipeline_enabled = False
-    try:
-        assert pipe.verify_batch(pool) == on
-        assert pipe.last_max_depth == 1
-    finally:
-        base.pipeline_enabled = True
-
-
 def test_sim_commit_order_matches_cpu_at_every_depth(keys):
     """Acceptance: CPU-vs-device commit order stays byte-identical with
     the pipeline enabled at every tested depth, with per-cycle bursts
@@ -220,7 +200,7 @@ def test_sim_commit_order_matches_cpu_at_every_depth(keys):
     reg, seeds = keys
     signers = [VertexSigner(s) for s in seeds]
 
-    def run(factory, dedup=True):
+    def run(factory, dedup=True, window=None):
         cfg = Config(n=N, coin="round_robin", propose_empty=True)
         sim = Simulation(
             cfg,
@@ -228,6 +208,9 @@ def test_sim_commit_order_matches_cpu_at_every_depth(keys):
             signer_factory=lambda i: signers[i],
         )
         sim.dedup = dedup
+        # the window run() would build over the shared verifier, at the
+        # depth under test
+        sim._verify_pipe = window
         sim.submit_blocks(per_process=2)
         for _ in range(10):
             sim.run(max_messages=N * (N - 1))
@@ -242,12 +225,12 @@ def test_sim_commit_order_matches_cpu_at_every_depth(keys):
     assert len(cpu_log) > 10, "CPU reference run delivered too little"
     for depth in (1, 2, 4):
         shared = TPUVerifier(reg)
-        shared.fixed_bucket = 16
-        shared.pipeline_depth = depth
+        window = VerifierPipeline(shared, depth=depth, fixed_bucket=16)
         # dedup off: the merged burst keeps all n*(n-1) copies, so a
         # cycle's dispatch genuinely exceeds the bucket and chunks
         # (deliveries are dedup-invariant — see the dedup tests)
-        dev_log, sim = run(lambda i: shared, dedup=False)
+        dev_log, sim = run(lambda i: shared, dedup=False, window=window)
+        assert sim._verify_pipe is window and window.dispatches
         k = min(len(cpu_log), len(dev_log))
         assert k > 10 and cpu_log[:k] == dev_log[:k], f"depth {depth}"
         depths = [

@@ -180,11 +180,10 @@ def test_prep_masks_byte_identical(keys, workers, depth):
         want = [cpu.verify_batch(r) for r in rounds]
         assert any(not all(m) for m in want if m), "no corruption landed"
 
-        streamed = TPUVerifier(reg)
-        streamed.fixed_bucket = bucket
-        streamed.pipeline_depth = depth
-        streamed.prep_workers = workers
-        assert streamed.verify_rounds(rounds) == want
+        bare = TPUVerifier(reg)
+        bare.fixed_bucket = bucket
+        bare.prep_workers = workers
+        assert bare.verify_rounds(rounds) == want
 
         pipe = VerifierPipeline(
             TPUVerifier(reg), depth=depth, fixed_bucket=bucket, warmup=False
@@ -220,7 +219,6 @@ def test_sharded_prep_masks_byte_identical(keys, depth):
 
     serial = ShardedTPUVerifier(reg, make_mesh(8))
     serial.fixed_bucket = 64
-    serial.pipeline_depth = depth
     serial.prep_workers = 1
     assert serial.verify_rounds(rounds) == want
 
@@ -262,19 +260,21 @@ def test_prep_engine_active_through_async_seam(keys):
     assert s["prep_parallel_fraction"] > 0.0
 
 
-def test_streamed_verify_rounds_uses_prep_ahead(keys):
-    """TPUVerifier's own over-cap streaming (no pipeline wrapper) also
-    runs prep-ahead: same mask, seam thread engaged."""
+def test_bare_verifier_chunks_over_cap_without_a_window(keys):
+    """A TPUVerifier with no pipeline over it takes an over-cap merge
+    chunk by chunk: same mask, row-block prep still parallel, and no
+    prep-ahead thread — the window is VerifierPipeline's alone."""
     reg, _ = keys
     cpu = CPUVerifier(reg)
     pool = _signed_pool(keys, 160, seed=43)
     want = cpu.verify_batch(pool)
     v = TPUVerifier(reg)
     v.fixed_bucket = 64
-    v.pipeline_depth = 2
     v.prep_workers = 4
     assert v.verify_rounds([pool]) == [want]
-    assert v._prep()._seam is not None, "streaming path skipped prep-ahead"
+    assert v.total_dispatches == 3  # ceil(160 / 64)
+    assert v._prep().dispatches_parallel > 0
+    assert v._prep()._seam is None, "a bare verifier opened a window"
 
 
 class _RingWatchVerifier(TPUVerifier):

@@ -151,26 +151,38 @@ def test_commit_order_byte_identical_cpu_vs_tpu():
     assert logs["cpu"] == logs["tpu"]
 
 
-def test_verify_batch_survives_pipeline_off_flag(keys, signed_vertices):
-    """bench.py's sim256_sync rung flips pipeline_enabled False to force
-    the synchronous depth-1 path (this flag replaced the round-5
-    instance-attribute None shadow, whose failure mode was verify_batch
-    calling None mid-ladder); verify_batch and the chunked verify_rounds
-    must keep working — and produce identical masks — in both states."""
+# -- a bare verifier: one program a bucket, no window -------------------
+
+
+@pytest.mark.parametrize("count,dispatches", [(40, 3), (16, 1), (17, 2)])
+def test_bare_verifier_cuts_an_oversize_batch_at_the_bucket(
+    keys, signed_vertices, count, dispatches
+):
+    """No pipeline over it: a batch larger than the fixed bucket is cut
+    at the bucket and each chunk dispatched and resolved in turn — the
+    CPU oracle's mask, ceil(count / 16) dispatches."""
     reg, _ = keys
+    pool = (signed_vertices + corruptions(signed_vertices)) * 2
+    batch = pool[:count]
+    want = CPUVerifier(reg).verify_batch(batch)
+    assert True in want and False in want
     v = TPUVerifier(reg)
     v.fixed_bucket = 16
-    baseline = v.verify_batch(signed_vertices)
-    rounds_base = v.verify_rounds([signed_vertices, signed_vertices])
-    v.pipeline_enabled = False
-    try:
-        assert v.verify_batch(signed_vertices) == baseline
-        assert v.verify_rounds([signed_vertices, signed_vertices]) == (
-            rounds_base
-        )
-        assert all(baseline)
-    finally:
-        v.pipeline_enabled = True
-    # flag restored: the async seam is usable again
-    pending = v.dispatch_batch(signed_vertices)
-    assert v.resolve_batch(pending) == baseline
+    assert v.verify_batch(batch) == want
+    assert v.total_dispatches == dispatches
+    assert v.total_sigs_dispatched == count
+    assert v.stats()["bucket"] == 16
+
+
+def test_one_compiled_program_after_warmup_and_an_oversize_batch(
+    keys, signed_vertices
+):
+    """``stats()["compile_s"]`` holds one entry a compiled shape: one
+    after warmup(), and still one after a batch of three chunks."""
+    reg, _ = keys
+    v = TPUVerifier(reg)
+    v.warmup()
+    assert list(v.stats()["compile_s"]) == ["16xjnp"]
+    assert v.verify_batch(signed_vertices * 5) == [True] * 40
+    assert v.total_dispatches == 3
+    assert list(v.stats()["compile_s"]) == ["16xjnp"]

@@ -124,6 +124,7 @@ def test_one_thread_releases_every_held_message_in_the_order_they_fall_due():
     assert released == [k for _, k in in_order]
     assert delay_threads() == before + 1
     queue.close()
+    assert delay_threads() == before  # close() waits for its thread
     queue.push(0.0, "after close")
     assert len(queue) == 0 and len(batches) == 2
 
@@ -151,18 +152,23 @@ def _echo(k: int):
 
 
 def test_a_delayed_send_waits_in_the_transports_one_queue():
+    def delay_threads():
+        return sum(1 for t in threading.enumerate() if t.name == "net-delay")
+
+    before = delay_threads()
     a, b = _pair(WanFault(seed=1, delay_ms=(5_000.0, 5_000.0)))
     try:
         for k in range(1, 51):
             a.broadcast(_echo(k))
         assert len(a._held) == 50
-        assert sum(1 for t in threading.enumerate() if t.name == "net-delay") == 1
+        assert delay_threads() == before + 1
         assert a.metrics.snapshot()["net_wan_delays"] == 50
         assert a.metrics.snapshot().get("net_sends", 0) == 0  # none leaves early
     finally:
         a.close()
         b.close()
     assert len(a._held) == 0
+    assert delay_threads() == before  # no thread outlives its transport
 
 
 def test_frames_for_a_peer_that_fall_due_together_share_one_rpc_and_all_arrive():
