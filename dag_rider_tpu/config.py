@@ -560,6 +560,12 @@ class Config:
 class MempoolConfig:
     """Knobs for the ingestion edge (``dag_rider_tpu/mempool/``).
 
+    The data path is *pool -> the proposer cuts its block* on a node
+    (``Process.block_source``: one block of whatever is pending when a
+    vertex is made, nothing staged) and *pool -> ``build_blocks`` ->
+    ``Process.submit``* in the lockstep drivers, which cut blocks ahead
+    of their vertices once a cycle.
+
     Dataclass defaults < env < explicit :meth:`from_dict` values — so a
     deployed fleet is retunable via environment without editing every
     node's JSON config, and a config file still wins when it speaks up.
@@ -576,7 +582,10 @@ class MempoolConfig:
         batch_bytes: the batcher packs blocks up to this many payload
             bytes (a single oversized transaction still ships alone).
         batch_deadline_ms: a non-empty pool older than this flushes a
-            partial block — bounds client latency at low load.
+            partial block — bounds client latency at low load. On a
+            node: how long a proposer with ``propose_empty`` off holds
+            a partial block before it spends a round on it (a vertex
+            that goes out anyway takes what is pending, however young).
         admit_low / admit_high: pool-fill watermarks. Below low every
             source is accepted (subject to ``source_rate``); between them
             each source is throttled to ``throttle_rate`` tx/s; at or
@@ -590,7 +599,9 @@ class MempoolConfig:
         source_burst: token-bucket burst depth for both rate caps.
         max_batch_txs: hard cap on transactions per built block (guards
             the wire codec against pathological many-tiny-tx blocks).
-        max_staged_blocks: stop pulling built blocks into
+        max_staged_blocks: read only by callers that push
+            (``build_blocks(staged=...)``; a node stages nothing): stop
+            pulling built blocks into
             ``Process.blocks_to_propose`` while it already holds this
             many — DAG-Rider proposes ONE block per round, so under
             sustained overload the proposal queue is the next unbounded

@@ -113,6 +113,12 @@ class Process:
         #: ``blocks_to_propose`` for a vertex (a mempool closes its
         #: submit -> vertex wait there)
         self.on_propose: Optional[Callable[[Block], None]] = None
+        #: where a vertex's block comes from once ``blocks_to_propose``
+        #: is empty: an object with ``next_block()`` (a Block, or None)
+        #: and ``block_ready()`` — a node's mempool, which cuts the
+        #: block when the vertex is made instead of staging blocks
+        #: ahead of it. None: only what ``submit`` queued is proposed.
+        self.block_source = None
         # Structured event log (SURVEY §5 L5; the reference has 3 zap
         # Debug sites — here every state transition emits a typed event).
         # NOOP by default: one attribute test per call site.
@@ -1631,7 +1637,7 @@ class Process:
                 # crosses; every correct process converges at round 4B.
                 self.metrics.inc("epoch_barrier_holds")
                 break
-            if not self.blocks_to_propose and not self.cfg.propose_empty:
+            if not self.cfg.propose_empty and not self._block_available():
                 break  # paper: wait until a block is available
             self.round += 1
             self.metrics.inc("rounds_advanced")
@@ -1682,14 +1688,26 @@ class Process:
             )
         )
 
+    def _block_available(self) -> bool:
+        """Something to propose: a queued block, or a source whose
+        size-or-deadline trigger has fired."""
+        return bool(self.blocks_to_propose) or (
+            self.block_source is not None
+            and self.block_source.block_ready()
+        )
+
     def _create_vertex(self, rnd: int) -> Vertex:
         """Vertex factory (Alg. 2 lines 17-21 + 29-31, quoted at
         ``process.go:271-275`` and ``process.go:300-302``)."""
-        block = (
-            self.blocks_to_propose.popleft()
-            if self.blocks_to_propose
-            else Block()
-        )
+        source = self.block_source
+        if self.blocks_to_propose:
+            block = self.blocks_to_propose.popleft()
+        else:
+            # cut now, from what is pending now; nothing pending (or no
+            # source) is an empty block
+            block = source.next_block() if source is not None else None
+            if block is None:
+                block = Block()
         if self.lanes is not None:
             # a LanePending handle becomes its certified carrier block
             # (or the payload itself on degrade); plain blocks pass
@@ -1811,7 +1829,7 @@ class Process:
 
     def _maybe_request_sync(self, made_progress: bool = False) -> None:
         # Stuck = no progress while there is something to wait for: a
-        # non-empty buffer (missing predecessors), or queued client blocks
+        # non-empty buffer (missing predecessors), or a block to propose
         # with an incomplete current round (our — or our peers' — round-r
         # broadcasts were lost, so everyone's buffers can be EMPTY while
         # the cluster deadlocks; a quiescent cluster with no pending
@@ -1827,7 +1845,7 @@ class Process:
             )
             or bool(self._cert_pool)  # rounds parked awaiting a cert
             or (
-                bool(self.blocks_to_propose)
+                self._block_available()
                 and self.round >= 1
                 and self.dag.round_size(self.round) < self.cfg.quorum
             )
@@ -1917,7 +1935,7 @@ class Process:
             # but self.round itself may not be (lost broadcasts).
             lo = min(lo, max(1, self.round))
         elif (
-            self.blocks_to_propose
+            self._block_available()
             and self.round >= 1
             and self.dag.round_size(self.round) < self.cfg.quorum
         ):

@@ -5,8 +5,18 @@ bytes ride in a block is decided here, Narwhal-style (data path
 separate from the ordering path):
 
     client tx --> admission (accept/throttle/shed) --> pool (bounded,
-    dedup, per-client FIFO lanes, TTL) --> batcher (size-or-deadline
-    Block packing) --> Process.submit --> ... a_deliver
+    dedup, per-client FIFO lanes, TTL) --> batcher (Block packing)
+    --> a vertex --> ... a_deliver
+
+From the pool to a vertex there is one cutting rule
+(``BlockBatcher.build``) and two callers. On a node it is *pool -> the
+proposer cuts its block*: ``Process._create_vertex`` asks
+:meth:`Mempool.next_block` (its ``block_source``) for one block of
+whatever is pending when it makes the vertex, so nothing is staged in
+front of consensus. The lockstep drivers (``mempool.loadgen``, the
+benchmark's committee cell) push: *pool -> ``build_blocks`` ->
+``Process.submit``*, once a cycle, on the size-or-deadline triggers and
+under the ``max_staged_blocks`` bound.
 
 :class:`Mempool` is the facade gluing the three stages under one lock
 (``Node.submit`` runs on client threads, the pump thread drains), plus
@@ -197,8 +207,10 @@ class Mempool:
         force: bool = False,
         staged: int = 0,
     ) -> List[Block]:
-        """TTL-evict, then drain triggered batches. The pump calls this
-        each cycle and feeds the blocks to ``Process.submit``.
+        """TTL-evict, then drain triggered batches. A lockstep driver
+        calls this once a cycle and feeds the blocks to
+        ``Process.submit`` (a node's proposer asks :meth:`next_block`
+        instead and stages nothing).
 
         ``staged`` is the consumer's current backlog (depth of
         ``Process.blocks_to_propose``); builds stop once backlog plus
@@ -207,39 +219,82 @@ class Mempool:
         unbounded proposal queue. ``force`` (shutdown/checkpoint flush)
         ignores the bound."""
         with self._lock:
-            t = self.clock() if now is None else now
-            if self.cfg.adaptive_deadline:
-                self._adapt_deadline()
-            for tx in self.pool.expire(t):
-                self._inflight.pop(tx, None)
-            control: List[Block] = []
-            if self._control:
-                # control lane flush: one dedicated block, ahead of any
-                # payload batch and exempt from the staging bound — a
-                # reconfiguration op must reach its boundary even when
-                # the payload path is backlogged
-                control.append(Block(tuple(self._control)))
-                self._control = []
+            t = self._begin_cut(now)
+            control = self._take_control()
             limit: Optional[int] = None
             if not force:
                 limit = max(0, self.cfg.max_staged_blocks - staged)
-                if limit == 0:
-                    return control
-            blocks = control + self.batcher.drain(
-                t, force=force, limit=limit
-            )
-            if blocks and self.log.enabled:
-                for b in blocks:
-                    keys = [
-                        tx_key(tx)
-                        for tx in b.transactions
-                        if sample_tx(tx, self.trace_sample)
-                    ]
-                    if keys:
-                        bk = block_key(b.encode())
-                        for k in keys:
-                            self.log.event("tx_batch", tx=k, block=bk)
+            # the control block is exempt from the staging bound — a
+            # reconfiguration op must reach its boundary even when the
+            # payload path is backlogged
+            blocks = [] if control is None else [control]
+            if limit != 0:
+                blocks += self.batcher.drain(t, force=force, limit=limit)
+            if blocks:
+                spans.count("mempool.cut_ahead", len(blocks))
+                self._trace_batch(blocks)
             return blocks
+
+    def next_block(self, now: Optional[float] = None) -> Optional[Block]:
+        """The block of a vertex that is being made now
+        (``Process.block_source``): a pending control block first and
+        alone, else one payload block of whatever is pending — however
+        young: the vertex goes out anyway, and what it leaves behind
+        waits a whole round — up to ``batch_bytes`` / ``max_batch_txs``.
+        None when nothing is pending."""
+        with self._lock:
+            t = self._begin_cut(now)
+            block = self._take_control()
+            if block is None:
+                block = self.batcher.build(t, force=True)
+                if block is None:
+                    return None
+            spans.count("mempool.cut_at_propose")
+            self._trace_batch([block])
+            return block
+
+    def block_ready(self, now: Optional[float] = None) -> bool:
+        """Whether a proposer that does not propose empty blocks should
+        spend a round now: a control op is pending, or the size or
+        deadline trigger has fired — how long a quiet validator holds a
+        partial block."""
+        with self._lock:
+            t = self._begin_cut(now)
+            return bool(self._control) or self.batcher.ready(t)
+
+    def _begin_cut(self, now: Optional[float]) -> float:
+        """What precedes every look at the pool from the proposing side:
+        the effective deadline retuned, the expired evicted. Returns the
+        time used. Caller holds the lock."""
+        t = self.clock() if now is None else now
+        if self.cfg.adaptive_deadline:
+            self._adapt_deadline()
+        for tx in self.pool.expire(t):
+            self._inflight.pop(tx, None)
+        return t
+
+    def _take_control(self) -> Optional[Block]:
+        """The control lane's flush: one dedicated block ahead of any
+        payload batch. Caller holds the lock."""
+        if not self._control:
+            return None
+        block = Block(tuple(self._control))
+        self._control = []
+        return block
+
+    def _trace_batch(self, blocks: List[Block]) -> None:
+        if not self.log.enabled:
+            return
+        for b in blocks:
+            keys = [
+                tx_key(tx)
+                for tx in b.transactions
+                if sample_tx(tx, self.trace_sample)
+            ]
+            if keys:
+                bk = block_key(b.encode())
+                for k in keys:
+                    self.log.event("tx_batch", tx=k, block=bk)
 
     def _adapt_deadline(self) -> None:
         """Retune the batcher's effective deadline from the live

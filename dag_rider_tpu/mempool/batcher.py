@@ -1,7 +1,11 @@
 """Block builder: packs pooled transactions into ``Block`` payloads.
 
 DAG-Rider a_bcasts one block per vertex, so the bytes a vertex carries
-are decided here. Two triggers, whichever fires first:
+are decided here. A proposer that is making a vertex takes one block of
+whatever is pending (``build(now, force=True)``). A caller that cuts
+blocks ahead of their vertices (``drain``), and a proposer that does not
+propose empty blocks and asks whether to spend a round (``ready``), go
+by two triggers, whichever fires first:
 
 - **size** — the pool holds at least ``batch_bytes`` of payload: ship a
   full block (throughput mode; fill fraction ~1.0);

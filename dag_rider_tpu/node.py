@@ -525,6 +525,7 @@ class Node:
                     log=self.process.log,
                 )
                 self.process.on_propose = self.mempool.observe_proposed
+                self.process.block_source = self.mempool
             self.net.attach_metrics(self.process.metrics)
             if self.tracing is not None:
                 self.tracing.flight.add_metrics_source(
@@ -534,8 +535,9 @@ class Node:
         self.process = _build_process()
         # Round-10 ingestion edge: "mempool": true (env-tuned) or a dict
         # of MempoolConfig overrides attaches the admission + batching
-        # front door; submit() then routes through it and the pump pulls
-        # built blocks. Absent/false keeps the legacy direct-block path.
+        # front door; submit() then routes through it and the proposer
+        # cuts each vertex's block from the pool when it makes the
+        # vertex. Absent/false keeps the legacy direct-block path.
         _attach()
         self.ckpt_dir = cfg.get("checkpoint_dir")
         self.ckpt_every = float(cfg.get("checkpoint_every_s", 30))
@@ -739,15 +741,6 @@ class Node:
 
     def _tick(self) -> int:
         self._drain_submissions()
-        if self.mempool is not None:
-            # the pump pulls BUILT blocks (size-or-deadline batches), not
-            # raw submissions — the round-10 front-door contract; staged=
-            # current proposal backlog so overload stays in the pool
-            # (bounded, sheddable) instead of blocks_to_propose (neither)
-            for block in self.mempool.build_blocks(
-                staged=len(self.process.blocks_to_propose)
-            ):
-                self.process.submit(block)
         if self.process.state_transfer_needed:
             self._state_transfer()
         moved = self.net.pump(256)

@@ -13,7 +13,9 @@ chain decomposes a transaction's submit→deliver latency into three
 stages that sum EXACTLY (every stamp shares one EventLog clock):
 
     mempool_queue  = batch.ts   - submit.ts    (admission + batcher hold)
-    propose_stage  = propose.ts - batch.ts     (blocks_to_propose wait)
+    propose_stage  = propose.ts - batch.ts     (blocks_to_propose wait;
+                                                ~0 where the proposer
+                                                cuts its own block)
     wave_commit    = deliver.ts - propose.ts   (RBC + DAG + wave lag)
 
 The wave_commit window is then *attributed* across the host phase

@@ -118,6 +118,11 @@ KNOWN_COUNTS = frozenset(
         # lists someone read, and so became tuples of ids
         "sidecar.vertices_decoded",
         "codec.edges_unpacked",
+        # mempool/ — blocks cut for a vertex that was being made (a
+        # proposer's ``block_source``), and blocks cut ahead of one
+        # (``build_blocks``, for a caller that stages them)
+        "mempool.cut_at_propose",
+        "mempool.cut_ahead",
     }
 )
 

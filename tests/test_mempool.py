@@ -304,6 +304,141 @@ def test_mempool_stats_and_checkpoint_roundtrip():
     assert fresh.submit((b"aaaa",), now=5.0).deduped == 1
 
 
+# -- the proposer's side: one block, cut when the vertex is made -------------
+
+
+def _counts():
+    from dag_rider_tpu.obs import spans
+
+    c = spans.snapshot()["counts"]
+    return c.get("mempool.cut_at_propose", 0), c.get("mempool.cut_ahead", 0)
+
+
+def test_next_block_is_none_on_an_empty_pool_and_counts_nothing():
+    mp = Mempool(MempoolConfig(cap=64))
+    before = _counts()
+    assert mp.next_block(now=0.0) is None
+    assert not mp.block_ready(now=99.0)
+    assert _counts() == before
+
+
+@pytest.mark.parametrize("age_s", [0.0, 0.01, 0.2])
+def test_next_block_takes_everything_pending_however_young(age_s):
+    mp = Mempool(MempoolConfig(cap=64, batch_bytes=8192, batch_deadline_ms=50.0))
+    txs = [f"young-{i}".encode() for i in range(5)]
+    mp.submit(txs[:3], client="a", now=0.0)
+    mp.submit(txs[3:], client="b", now=0.0)
+    before = _counts()
+    # the trigger says how long a QUIET proposer holds a partial block;
+    # a vertex that is going out anyway takes what is there
+    assert mp.block_ready(now=age_s) == (age_s >= 0.05)
+    block = mp.next_block(now=age_s)
+    # round-robin over the two client lanes, as build_blocks packs
+    assert list(block.transactions) == [txs[0], txs[3], txs[1], txs[4], txs[2]]
+    assert len(mp.pool) == 0 and mp.next_block(now=age_s) is None
+    assert _counts() == (before[0] + 1, before[1])
+    stats = mp.stats()
+    assert stats["blocks_built"] == 1 and stats["txs_packed"] == 5
+    assert 0.0 < stats["batch_fill"] < 0.01
+    # still in flight until delivered: a resubmission is a duplicate
+    assert mp.submit((txs[0],), now=age_s).deduped == 1
+
+
+def test_next_block_stops_at_batch_bytes_and_max_batch_txs():
+    mp = Mempool(MempoolConfig(cap=64, batch_bytes=64, max_batch_txs=1024))
+    mp.submit([bytes([i]) * 32 for i in range(5)], now=0.0)
+    assert mp.block_ready(now=0.0)  # the size trigger
+    assert [len(mp.next_block(now=0.0).transactions) for _ in range(3)] == [2, 2, 1]
+    capped = Mempool(MempoolConfig(cap=64, batch_bytes=8192, max_batch_txs=3))
+    capped.submit([bytes([i]) * 8 for i in range(5)], now=0.0)
+    assert [len(capped.next_block(now=0.0).transactions) for _ in range(2)] == [3, 2]
+
+
+def test_next_block_gives_a_control_block_first_and_alone():
+    from dag_rider_tpu.core.codec import EPOCH_MAGIC
+
+    mp = Mempool(MempoolConfig(cap=64, batch_deadline_ms=50.0))
+    op = EPOCH_MAGIC + b"reconfigure"
+    mp.submit((b"payload-0",), now=0.0)
+    mp.submit((op,), now=0.0)
+    mp.submit((b"payload-1",), now=0.0)
+    assert mp.block_ready(now=0.0)  # a control op waits for no deadline
+    before = _counts()
+    assert mp.next_block(now=0.0).transactions == (op,)
+    assert not mp.block_ready(now=0.0)
+    assert mp.next_block(now=0.0).transactions == (b"payload-0", b"payload-1")
+    assert mp.next_block(now=0.0) is None
+    assert _counts() == (before[0] + 2, before[1])
+
+
+def test_next_block_and_block_ready_evict_what_outlived_its_ttl():
+    mp = Mempool(MempoolConfig(cap=64, ttl_s=1.0))
+    mp.submit((b"stale",), now=0.0)
+    mp.submit((b"fresh",), now=0.8)
+    assert mp.next_block(now=1.5).transactions == (b"fresh",)
+    assert mp.stats()["expired"] == 1
+    # the evicted payload left the dedup horizon with the pool
+    assert mp.submit((b"stale",), now=1.5).accepted == 1
+    # a pool that holds only the expired is not worth a round
+    only_stale = Mempool(MempoolConfig(cap=64, ttl_s=1.0))
+    only_stale.submit((b"stale",), now=0.0)
+    assert not only_stale.block_ready(now=2.0)
+    assert only_stale.next_block(now=2.0) is None
+
+
+def test_build_blocks_counts_what_it_cut_ahead_and_traces_like_next_block():
+    events = []
+    from dag_rider_tpu.utils.slog import EventLog
+
+    def traced():
+        return Mempool(
+            MempoolConfig(cap=64, batch_bytes=16, batch_deadline_ms=0.0),
+            log=EventLog(events.append),
+            trace_sample=1.0,
+        )
+
+    txs = [bytes([65 + i]) * 8 for i in range(5)]
+    pushed, pulled = traced(), traced()
+    pushed.submit(txs, now=0.0)
+    before = _counts()
+    assert len(pushed.build_blocks(now=0.0)) == 3
+    assert pushed.build_blocks(now=0.0) == []
+    assert _counts() == (before[0], before[1] + 3)
+    by_push = [(e["tx"], e["block"]) for e in events if e["event"] == "tx_batch"]
+    del events[:]
+    pulled.submit(txs, now=0.0)
+    while pulled.next_block(now=0.0) is not None:
+        pass
+    assert _counts() == (before[0] + 3, before[1] + 3)
+    by_pull = [(e["tx"], e["block"]) for e in events if e["event"] == "tx_batch"]
+    assert by_pull == by_push and len(by_pull) == 5
+
+
+def test_build_blocks_after_next_block_is_byte_identical():
+    """One cutting rule, two callers: a pool whose first block a
+    proposer pulled cuts the same blocks afterwards as one that was only
+    ever drained, lane rotation and fill books included."""
+    cfg = MempoolConfig(cap=256, batch_bytes=64, batch_deadline_ms=20.0)
+    pulled, drained = Mempool(cfg), Mempool(cfg)
+    first = [(f"c{i % 3}", f"first-{i:02d}".encode()) for i in range(7)]
+    later = [(f"c{i % 4}", f"later-{i:02d}".encode() * 2) for i in range(23)]
+    for mp in (pulled, drained):
+        for client, tx in first:
+            mp.submit((tx,), client=client, now=0.0)
+    a = pulled.next_block(now=0.001)
+    (b,) = drained.build_blocks(now=0.001, force=True)
+    assert a.encode() == b.encode()
+    for mp in (pulled, drained):
+        for client, tx in later:
+            mp.submit((tx,), client=client, now=0.002)
+    for now in (0.01, 0.03):  # the size trigger, then the deadline's
+        assert [x.encode() for x in pulled.build_blocks(now=now)] == [
+            x.encode() for x in drained.build_blocks(now=now)
+        ]
+    assert len(pulled.pool) == len(drained.pool) == 0
+    assert pulled.stats()["batch_fill"] == drained.stats()["batch_fill"]
+
+
 # -- load generator ---------------------------------------------------------
 
 
