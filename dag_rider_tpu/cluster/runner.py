@@ -340,6 +340,9 @@ class NodeRunner:
             "retained": sorted(tx.hex() for tx in retained),
             "metrics": self.node.process.metrics.snapshot(),
         }
+        open_slots = getattr(self.node.process.transport, "open_slots", None)
+        if open_slots is not None:  # a reliable-broadcast stage
+            final["rbc_open_slots"] = open_slots()
         for path, blob in (
             (self.files["final_report"], final),
             (self.files["span_book"], obs.spans.snapshot()),

@@ -171,6 +171,8 @@ class Process:
         self.lanes = None
         self.decided_wave = 0
         self._pending_waves: Set[int] = set()
+        for name in ("pump.wave_commit", "pump.wave_skip", "pump.sync_request"):
+            obs.count(name, 0)  # a book that shows the name reads 0, not nothing
         self.delivered_log: List[VertexID] = []
         #: deliveries dropped from delivered_log by GC pruning (the log
         #: keeps only the live window when cfg.gc_depth is set)
@@ -1951,6 +1953,7 @@ class Process:
         hi = lo + self.cfg.sync_window - 1
         self._sync_last_lo = lo
         self.metrics.inc("sync_requested")
+        obs.count("pump.sync_request")
         self.log.event("sync_request", lo=lo, hi=hi)
         req = BroadcastMessage(
             vertex=None,
@@ -2233,6 +2236,7 @@ class Process:
         if leader is None:
             if not quiet:
                 self.metrics.inc("waves_skipped")
+                obs.count("pump.wave_skip")
                 self.log.event("wave_skip", wave=wave, reason="no_leader")
             return
         r4, r1 = self.cfg.wave_round(wave, self.cfg.wave_length), self.cfg.wave_round(wave, 1)
@@ -2240,6 +2244,7 @@ class Process:
         if votes < self.cfg.quorum:
             if not quiet:
                 self.metrics.inc("waves_skipped")
+                obs.count("pump.wave_skip")
                 self.log.event(
                     "wave_skip", wave=wave, reason="quorum", votes=votes
                 )
@@ -2281,6 +2286,10 @@ class Process:
                 ):
                     leaders.push(prior)
                     cur = prior
+            # the waves this commit closes: itself and every undecided
+            # wave the chain walked back over (a length, booked for its max)
+            obs.spans.record("pump.chain_waves", wave - self.decided_wave)
+            obs.count("pump.wave_commit")
             self.decided_wave = wave
             self.metrics.inc("waves_decided")
             # interval stamp at DECIDE time — a deferred flush that runs two

@@ -55,6 +55,10 @@ KNOWN_SPANS = frozenset(
         "pump.propose",
         "pump.wave",
         "pump.chain",
+        # how many waves one commit closed (itself and the undecided
+        # waves its leader chain walked back over): a length booked
+        # through record() for its count, sum and max — not a time
+        "pump.chain_waves",
         "pump.order",
         "pump.prune",
         "pump.sync",
@@ -123,6 +127,19 @@ KNOWN_COUNTS = frozenset(
         # (``build_blocks``, for a caller that stages them)
         "mempool.cut_at_propose",
         "mempool.cut_ahead",
+        # consensus/process.py — a wave tried at its last round that
+        # committed its leader, and one that did not (no leader vertex,
+        # or no quorum of votes); a catch-up request sent
+        "pump.wave_commit",
+        "pump.wave_skip",
+        "pump.sync_request",
+        # transport/net.py — a failed attempt put back for another try;
+        # the failure detector reporting a peer down; a frame handed to
+        # a sender for a peer it holds down (a probe, since the rest of
+        # that peer's frames are dropped before they cost anything)
+        "net.retry",
+        "net.peer_down",
+        "net.to_down_peer",
     }
 )
 

@@ -46,6 +46,8 @@ _identity = lambda b: b  # noqa: E731 — bytes in, bytes out
 
 #: sender threads a transport (each holds one peer's RPC at a time)
 _SENDERS = 8
+#: a peer the failure detector holds down is sent one frame this often
+_PROBE_EVERY_S = 1.0
 
 
 _SNAP_DOMAIN = b"dagrider-snapshot-req-v2"  # v2: timestamped request body
@@ -584,6 +586,11 @@ class GrpcTransport(Transport):
         # tolerates the faults; operators get the signal.
         self.down_after = 3
         self._consec_fail: Dict[int, int] = {}
+        #: when the next probe of each peer held down is due: until then
+        #: its frames are dropped where they are made (see _shielded)
+        self._probe_at: Dict[int, float] = {}
+        for name in ("net.retry", "net.peer_down", "net.to_down_peer"):
+            obs.count(name, 0)  # a book that shows the name reads 0, not nothing
         from concurrent import futures
 
         #: the senders: threads start as work comes, none before
@@ -763,7 +770,7 @@ class GrpcTransport(Transport):
         """One attempt through the WAN policy: dropped, or sent at once
         (None either way), or to be held — then ``(delay_s, item)`` for
         the delay queue, which the caller hands over."""
-        if self._closed:
+        if self._closed or self._shielded(peer):
             return None
         if self._send_fault is not None:
             verdict = self._send_fault(peer)
@@ -778,6 +785,28 @@ class GrpcTransport(Transport):
                 return verdict, (peer, payload, attempt, True)
         self._send_now(peer, [(payload, attempt)])
         return None
+
+    def _held_down(self, peer: int) -> bool:
+        return self._consec_fail.get(peer, 0) >= self.down_after
+
+    def _shielded(self, peer: int) -> bool:
+        """True where the failure detector holds ``peer`` down and its
+        next probe is not due: the frame is dropped here — as its retry
+        chain would drop it three failed attempts later — before it
+        costs the WAN's heap, a sender's turn and that chain. One frame
+        every ``_PROBE_EVERY_S`` goes through as the probe: the first
+        that succeeds reports the peer recovered and lifts the shield.
+        Consensus covers the frames dropped meanwhile as it covers any
+        drop: a returning peer catches up by sync."""
+        if not self._held_down(peer):
+            return False
+        now = time.monotonic()
+        with self._lock:
+            if now < self._probe_at.get(peer, 0.0):
+                self.metrics.inc("net_down_peer_drops")
+                return True
+            self._probe_at[peer] = now + _PROBE_EVERY_S
+        return False
 
     def _send(self, peer: int, payload: bytes, attempt: int) -> None:
         held = self._route(peer, payload, attempt)
@@ -812,6 +841,8 @@ class GrpcTransport(Transport):
         if self._closed:
             return
         obs.count("net.messages", len(frames))
+        if self._held_down(peer):
+            obs.count("net.to_down_peer", len(frames))
         with self._lock:
             self.metrics.inc("net_sends", len(frames))
             self._outbox.setdefault(peer, []).extend(frames)
@@ -881,10 +912,13 @@ class GrpcTransport(Transport):
     def _on_failure(self, peer: int, payload: bytes, attempt: int) -> None:
         if self._closed:
             return
-        if attempt >= self._retries:
+        if attempt >= self._retries or self._held_down(peer):
             # The failure detector counts *logical messages* whose whole
             # retry chain was exhausted — a single message's transient
             # retry burst must not trip the down threshold by itself.
+            # A frame for a peer already held down is a probe (or was
+            # under way when the peer tripped): it gets no chain, the
+            # next probe is the retry.
             with self._lock:
                 self.metrics.inc("net_send_errors")
                 self.metrics.inc("net_drops")
@@ -911,6 +945,7 @@ class GrpcTransport(Transport):
             if chan is not None:
                 chan.close()
             if just_down:
+                obs.count("net.peer_down")
                 self._inc("net_peer_down")
                 self.log.event(
                     "net_peer_down",
@@ -921,6 +956,7 @@ class GrpcTransport(Transport):
         with self._lock:
             self.metrics.inc("net_send_errors")
             self.metrics.inc("net_retries")
+            obs.count("net.retry")
             # +/-25% seeded jitter: a restarted peer must not absorb
             # every sender's backed-off retries in one synchronized
             # thundering burst.

@@ -123,6 +123,12 @@ class RbcTransport(Transport):
                 removed += 1
         return removed
 
+    def open_slots(self) -> int:
+        """Instances this process has voted in and not delivered: what is
+        in flight, and what a sender that died left unfinished — the
+        latter stays until the prune floor passes its round."""
+        return len(self._echoed - self._delivered)
+
     # -- Transport interface ------------------------------------------------
 
     def subscribe(self, index: int, handler: Handler) -> None:

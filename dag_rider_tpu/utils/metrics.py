@@ -97,6 +97,7 @@ KNOWN_COUNTERS = frozenset(
         "net_auth_rejects",
         "net_peer_down",
         "net_peer_recovered",
+        "net_down_peer_drops",
         "net_snapshot_rejects",
         "net_snapshot_stale_refusals",
         "net_snapshot_replays",
