@@ -58,7 +58,7 @@ def _register(
     KNOBS[name] = Knob(name, kind, default, doc, choices, minimum)
 
 
-_register("DAGRIDER_PUMP", "choice", "scalar",
+_register("DAGRIDER_PUMP", "choice", "vector",
           "host consensus pump path", choices=("scalar", "vector"))
 _register("DAGRIDER_CERT", "choice", "off",
           "aggregated round certificates", choices=("off", "agg"))
@@ -336,11 +336,12 @@ class Config:
     # each process retires DAG state below its decided frontier minus
     # gc_depth (DagState.prune_below), bounding memory for long runs.
     gc_depth: Optional[int] = None
-    # Host consensus pump path: "scalar" is the reference per-message /
-    # per-vertex semantics; "vector" is the round-batched refinement
-    # (byte-identical commit order — tests/test_pump_vector.py is the
-    # gate). None resolves from DAGRIDER_PUMP, defaulting to "scalar";
-    # an explicit value beats the environment.
+    # Host consensus pump path: "vector" is the round-batched pump a
+    # Process runs; "scalar" is the reference per-message / per-vertex
+    # semantics, kept as its oracle (byte-identical commit order —
+    # tests/test_pump_vector.py is the gate). None resolves from
+    # DAGRIDER_PUMP, defaulting to "vector"; an explicit value beats
+    # the environment.
     pump: Optional[str] = None
     # Aggregated round certificates (ISSUE 9): "off" keeps the per-vertex
     # verify path as the reference oracle; "agg" BLS-signs vertex digests

@@ -1330,7 +1330,7 @@ class Process:
         """
         if self._vector:
             return self._drain_buffer_vector()
-        admitted_any = False
+        admitted = 0
         changed = True
         log_admit = self.log.wants("admit")
         present = self.dag.present
@@ -1457,9 +1457,11 @@ class Process:
                             "admit", round=v.round, source=v.source
                         )
                     changed = True
-                    admitted_any = True
+                    admitted += 1
             self._buffer = keep
-        return admitted_any
+        if admitted:
+            obs.count("pump.admit_scalar", admitted)
+        return admitted > 0
 
     def _drain_buffer_vector(self) -> bool:
         """Round-batched buffer drain (the vector pump).
@@ -1478,7 +1480,7 @@ class Process:
         groups = self._buffer_rounds
         if not groups:
             return False
-        admitted_any = False
+        admitted = 0
         dag = self.dag
         n = self.cfg.n
         vertices = dag.vertices
@@ -1540,7 +1542,7 @@ class Process:
                                 self.log.event(
                                     "admit", round=v.round, source=v.source
                                 )
-                        admitted_any = True
+                        admitted += len(admit)
                     continue
             live = [v for v in grp.values() if v.id not in vertices]
             dups = len(grp) - len(live)
@@ -1598,10 +1600,12 @@ class Process:
                         self.log.event(
                             "admit", round=v.round, source=v.source
                         )
-                admitted_any = True
+                admitted += len(admit)
             if keep:
                 groups[r] = {v.id.source: v for v in keep}
-        return admitted_any
+        if admitted:
+            obs.count("pump.admit_batched", admitted)
+        return admitted > 0
 
     def _try_advance(self) -> bool:
         """Round advancement (Alg. 2 lines 11-15, quoted at
@@ -2578,16 +2582,26 @@ class Process:
         src = self.coin.choose_leader(wave)
         return self.dag.get(VertexID(self.cfg.wave_round(wave, 1), src))
 
+    @staticmethod
+    def _reach_from(strong_stack, src: int) -> np.ndarray:
+        """bool[n]: the round-r_lo vertices that (r_hi, src) reaches by
+        strong edges, ``strong_stack`` being bool[k, n, n] with the top
+        round first. Seeded, so the descent is vector @ matrix per round
+        (O(k·n²)) instead of the full n x n chain product. The host twin
+        of :func:`ops.dag_kernels.leader_reach` (tests pin the two
+        together), kept here because importing anything from ``ops``
+        imports jax, which a validator's own process never needs."""
+        vec = np.asarray(strong_stack[0][src], dtype=bool)
+        for s in strong_stack[1:]:
+            vec = vec @ s
+        return np.asarray(vec, dtype=bool)
+
     def _leader_path(self, hi: VertexID, lo: VertexID) -> bool:
         """Strong-path query for the retroactive leader chain (vector
-        pump): seeded vector @ matrix descent over the dense mirrors
-        (:func:`ops.dag_kernels.leader_reach_np`) — O(k·n²) bit ops for
-        a k-round gap instead of the scalar closure walk's per-round
-        Python bookkeeping. Same boolean-semiring reachability as
-        ``dag.path(strong_only=True)``; tests pin the twin against the
-        jitted kernel."""
-        from dag_rider_tpu.ops.dag_kernels import leader_reach_np
-
+        pump): :meth:`_reach_from` over the dense mirrors — O(k·n²) bit
+        ops for a k-round gap instead of the scalar closure walk's
+        per-round Python bookkeeping. Same boolean-semiring reachability
+        as ``dag.path(strong_only=True)``."""
         dag = self.dag
         if not dag.present(hi) or not dag.present(lo):
             return False
@@ -2595,7 +2609,7 @@ class Process:
             return True
         if lo.round >= hi.round:
             return False
-        vec = leader_reach_np(
+        vec = self._reach_from(
             dag.strong_stack(hi.round, lo.round), hi.source
         )
         return bool(vec[lo.source])

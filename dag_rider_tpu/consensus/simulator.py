@@ -357,11 +357,16 @@ class Simulation:
         # echoes at the broker layer, so cross-destination grouping
         # would reorder the queue tail).
         grouped = getattr(self.transport, "pump_grouped", None)
+        # views on the round-batched pump: a delivered VAL only lands in
+        # their inbox while steps are deferred
+        vector_views = [
+            p for p in self.processes if getattr(p, "_vector", False)
+        ]
         if (
             callable(grouped)
             and not self._rbc
             and self.processes
-            and all(getattr(p, "_vector", False) for p in self.processes)
+            and len(vector_views) == len(self.processes)
         ):
             pump = grouped
             # Compress fan-out to one queue entry per broadcast; the
@@ -417,6 +422,22 @@ class Simulation:
                         got = pump(max_messages - delivered)
                     cycle_host = deliver.seconds
                     pump_wall += cycle_host
+                    if vector_views:
+                        # The deferred admission checks, BEFORE the
+                        # collect below: they are what moves a delivered
+                        # vertex into _pending_verify, so run from
+                        # step() they would leave the merged dispatch
+                        # empty and every view verifying its own batch.
+                        # Here they sit where the scalar pump's
+                        # on_message runs them (after delivery, before
+                        # the overlapped flush can prune), and step()
+                        # finds an empty inbox.
+                        with obs.span("pump.inbox") as inbox:
+                            for p in vector_views:
+                                if p._inbox:
+                                    p._process_inbox()
+                        cycle_host += inbox.seconds
+                        pump_wall += inbox.seconds
                     if coalesce:
                         with obs.span("pump.collect"):
                             batches = [p.take_verify_batch() for p in self.processes]

@@ -206,7 +206,7 @@ class Node:
             # bounded DAG memory for long-running nodes (None = grow
             # forever, reference-compatible)
             gc_depth=int(gc_depth) if gc_depth is not None else None,
-            # hot-path pump flavor; None defers to DAGRIDER_PUMP / scalar
+            # hot-path pump flavor; None defers to DAGRIDER_PUMP / vector
             pump=cfg.get("pump"),
             # aggregated round certificates; None defers to DAGRIDER_CERT
             cert=cfg.get("cert"),

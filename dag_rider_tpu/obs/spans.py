@@ -133,6 +133,11 @@ KNOWN_COUNTS = frozenset(
         "pump.wave_commit",
         "pump.wave_skip",
         "pump.sync_request",
+        # consensus/process.py — vertices the buffer drain admitted into
+        # the DAG: by the round-batched drain (whole round groups, one
+        # insert_many), and by the scalar walk that is its oracle
+        "pump.admit_batched",
+        "pump.admit_scalar",
         # transport/net.py — a failed attempt put back for another try;
         # the failure detector reporting a peer down; a frame handed to
         # a sender for a peer it holds down (a probe, since the rest of

@@ -258,13 +258,14 @@ def closure_from_full(
 # Host twins (numpy)
 # ---------------------------------------------------------------------------
 #
-# The vectorized host pump (consensus/process.py, DAGRIDER_PUMP=vector)
-# needs these same predicates per round, but a jitted dispatch costs
-# ~50-100 us on CPU — more than the whole batched numpy op at n=256. So
-# the hot path calls these numpy twins; tests/test_pump_vector.py pins
-# each twin equal to its jitted sibling on random DAGs so they cannot
-# drift apart. Bool @ bool numpy matmul is the established idiom here
-# (consensus/process.py _weak_edges_for).
+# The same predicates in numpy, for a host that has a round in hand: a
+# jitted dispatch costs ~50-100 us on CPU — more than the whole batched
+# numpy op at n=256. tests/test_pump_vector.py pins each twin equal to
+# its jitted sibling on random DAGs so they cannot drift apart. Bool @
+# bool numpy matmul is the established idiom here (consensus/process.py
+# _weak_edges_for). The twin the round-batched pump calls in every
+# validator's process, :func:`leader_reach`'s, lives with its caller
+# (``Process._reach_from``): importing this module imports jax.
 
 
 def reach_chain_np(strong_stack) -> "np.ndarray":
@@ -291,16 +292,6 @@ def admission_mask_np(strong_pred, exists_prev, weak_pred, exists):
 def strong_edge_quorum_np(strong_pred, *, quorum: int):
     """Numpy twin of :func:`strong_edge_quorum`: bool[B]."""
     return np.count_nonzero(strong_pred, axis=-1) >= quorum
-
-
-def leader_reach_np(strong_stack, hi_leader: int) -> "np.ndarray":
-    """Numpy twin of :func:`leader_reach` — but seeded, so the descent is
-    vector @ matrix per round (O(k n^2)) instead of materializing the full
-    n x n chain product (O(k n^3))."""
-    vec = np.asarray(strong_stack[0][hi_leader], dtype=bool)
-    for s in strong_stack[1:]:
-        vec = vec @ s
-    return np.asarray(vec, dtype=bool)
 
 
 @jax.jit

@@ -58,6 +58,24 @@ def _unfreeze_heap_after_each_test():
     gc.unfreeze()
 
 
+@pytest.fixture(autouse=True, scope="module")
+def _benchmark_files_open_an_empty_span_book(request):
+    """The span book (``obs/spans.py``) is the process's and is never
+    reset, and the files under ``tests/benchmark`` read metrics off it as
+    a benchmark run does: from a process that has run one cell. A worker
+    that ran other files first (``--dist loadfile`` deals them out as
+    they come) still has their spans and counts, so a share of 0 read
+    whatever a ``Node`` test had counted before it. Each such file
+    starts from an empty book."""
+    if "benchmark" in request.node.path.parts:
+        from dag_rider_tpu.obs import spans
+
+        for share in list(spans._shares):
+            share.spans.clear()
+            share.counts.clear()
+    yield
+
+
 def pytest_sessionfinish(session, exitstatus):
     if _RACE:
         leftover = _races.drain_violations()

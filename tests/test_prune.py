@@ -171,7 +171,7 @@ def test_below_horizon_vertex_is_dropped_not_wedged():
         BroadcastMessage(vertex=ghost, round=ghost.round, sender=1)
     )
     p.step()
-    assert ghost.id not in p._buffered_ids
+    assert ghost.id not in {v.id for v in p.buffer}
     assert not p.dag.present(ghost.id)
 
 

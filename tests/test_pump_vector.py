@@ -36,19 +36,19 @@ from dag_rider_tpu.transport.faults import FaultPlan, FaultyTransport
 # ---------------------------------------------------------------------------
 
 
-def test_pump_defaults_to_scalar(monkeypatch):
+def test_pump_defaults_to_vector(monkeypatch):
     monkeypatch.delenv("DAGRIDER_PUMP", raising=False)
-    assert Config(n=4).pump == "scalar"
-
-
-def test_pump_env_resolution(monkeypatch):
-    monkeypatch.setenv("DAGRIDER_PUMP", "vector")
     assert Config(n=4).pump == "vector"
 
 
+def test_pump_env_resolution(monkeypatch):
+    monkeypatch.setenv("DAGRIDER_PUMP", "scalar")
+    assert Config(n=4).pump == "scalar"
+
+
 def test_pump_explicit_beats_env(monkeypatch):
-    monkeypatch.setenv("DAGRIDER_PUMP", "vector")
-    assert Config(n=4, pump="scalar").pump == "scalar"
+    monkeypatch.setenv("DAGRIDER_PUMP", "scalar")
+    assert Config(n=4, pump="vector").pump == "vector"
 
 
 def test_pump_validation():
@@ -134,7 +134,7 @@ def test_host_twins_match_jitted_kernels():
         for hi in range(n):
             np.testing.assert_array_equal(
                 np.asarray(dk.leader_reach(stack, hi)),
-                dk.leader_reach_np(stack, hi),
+                Process._reach_from(stack, hi),
             )
     for _ in range(8):
         row = rng.random(n) < 0.7
@@ -152,6 +152,34 @@ def test_host_twins_match_jitted_kernels():
             np.asarray(dk.admission_mask(sp, ex[2], wp, ex)),
             dk.admission_mask_np(sp, ex[2], wp, ex),
         )
+
+
+@pytest.mark.parametrize("pump", ["scalar", "vector"])
+def test_the_pump_runs_a_committee_without_importing_jax(pump):
+    """A validator's own process holds no jax (its chip is a sidecar's):
+    a committee's path through a few waves, the leader chain's
+    strong-path query included, must not import it."""
+    import subprocess
+    import sys
+
+    script = (
+        "import sys\n"
+        "from dag_rider_tpu.config import Config\n"
+        "from dag_rider_tpu.consensus import Simulation\n"
+        "from dag_rider_tpu.core.types import VertexID\n"
+        f"cfg = Config(n=4, coin='round_robin', propose_empty=True, pump={pump!r})\n"
+        "sim = Simulation(cfg)\n"
+        "while min(p.round for p in sim.processes) < 13:\n"
+        "    sim.run(max_messages=16)\n"
+        "p = sim.processes[0]\n"
+        "assert p.decided_wave >= 2 and sim.deliveries[0]\n"
+        "assert p._leader_path(VertexID(9, 0), VertexID(5, 1))\n"
+        "assert 'jax' not in sys.modules, 'the pump imported jax'\n"
+    )
+    done = subprocess.run(
+        [sys.executable, "-c", script], capture_output=True, text=True, timeout=120
+    )
+    assert done.returncode == 0, done.stderr
 
 
 # ---------------------------------------------------------------------------
@@ -386,3 +414,148 @@ def test_adversary_equivalence_under_rbc():
     )
     assert any(scalar)
     assert scalar == vector
+
+
+# ---------------------------------------------------------------------------
+# the lockstep driver's merged dispatch under the round-batched pump
+# ---------------------------------------------------------------------------
+
+
+class _CountingShared:
+    """One host verifier for every view that counts how it is called:
+    ``merged`` by the lockstep driver (one dedup'd batch a pump cycle),
+    ``per_view`` by a view's own ``_drain_verify``."""
+
+    def __init__(self, registry):
+        from dag_rider_tpu.verifier.cpu import CPUVerifier
+
+        self.inner = CPUVerifier(registry)
+        self.registry = registry
+        self.merged: list = []  # sizes
+        self.per_view = 0
+
+    def verify_batch(self, vertices):
+        self.per_view += 1
+        return self.inner.verify_batch(vertices)
+
+    def verify_rounds(self, rounds):
+        self.merged.extend(len(r) for r in rounds)
+        return [self.inner.verify_batch(r) for r in rounds]
+
+
+class _TwoCallShared(_CountingShared):
+    """The same with the device verifier's two calls, so that
+    ``Simulation.run`` takes its pipelined branch (a dispatch window,
+    ``defer_delivery``, the ordering walks overlapped with the tail)."""
+
+    def dispatch_batch(self, vertices):
+        self.merged.append(len(vertices))
+        return list(vertices)
+
+    def resolve_batch(self, handle):
+        return self.inner.verify_batch(handle)
+
+
+def _cell_shape(n: int, pump, shared_cls):
+    """``committee256``'s stack at size n: signed vertices, one shared
+    verifier with dedup, the threshold-BLS coin, ``propose_empty``,
+    ``gc_depth`` 24, a mempool in front of every view."""
+    from dag_rider_tpu.config import MempoolConfig
+    from dag_rider_tpu.consensus.scenarios import coin_factory
+    from dag_rider_tpu.verifier.base import KeyRegistry, VertexSigner
+
+    cfg = Config(
+        n=n, coin="threshold_bls", propose_empty=True, gc_depth=24,
+        wave_length=4, pump=pump,
+    )
+    registry, seeds = KeyRegistry.generate(n)
+    shared = shared_cls(registry)
+    signers = [VertexSigner(s) for s in seeds]
+    sim = Simulation(
+        cfg,
+        verifier_factory=lambda i: shared,
+        signer_factory=lambda i: signers[i],
+        coin_factory=coin_factory("threshold_bls", n, cfg.f),
+    )
+    ticks = iter(range(10**9))
+    mempools = sim.attach_mempools(
+        MempoolConfig(), clock=lambda: 0.001 * next(ticks)
+    )
+    return sim, shared, mempools
+
+
+def _drive_cell_shape(sim, mempools, rounds: int):
+    """The committee driver's cycle — submit, ``build_blocks``,
+    ``run(n * n)`` — until every view has passed ``rounds``; yields
+    after each cycle."""
+    n = sim.cfg.n
+    for cycle in range(40 * rounds):
+        for i, (p, mp) in enumerate(zip(sim.processes, mempools)):
+            mp.submit((f"c{cycle}-v{i}".encode().ljust(32, b"."),), client=f"c{i}")
+            for b in mp.build_blocks(force=True, staged=len(p.blocks_to_propose)):
+                p.submit(b)
+        sim.run(max_messages=n * n)
+        yield cycle
+        if min(p.round for p in sim.processes) >= rounds:
+            return
+    raise AssertionError("failed to reach the target round")
+
+
+@pytest.fixture
+def registered_default(monkeypatch):
+    """``pump=None`` means the registry's default, whatever lane runs us."""
+    monkeypatch.delenv("DAGRIDER_PUMP", raising=False)
+
+
+@pytest.mark.parametrize("shared_cls", [_CountingShared, _TwoCallShared])
+def test_round_reaches_the_shared_verifier_as_one_merged_batch(
+    shared_cls, registered_default
+):
+    """Under the round-batched pump a delivered vertex waits in the
+    view's inbox; the driver has to run the inbox checks before it
+    gathers the cycle's batch, or the merged dispatch is empty and each
+    of the n views verifies its own batch from ``step()``."""
+    n = 8
+    sim, shared, mempools = _cell_shape(n, None, shared_cls)
+    assert all(p._vector for p in sim.processes)
+    seen = 0
+    for _ in _drive_cell_shape(sim, mempools, rounds=9):
+        calls = shared.merged[seen:]
+        seen = len(shared.merged)
+        # the round's n unique vertices and the few the spare messages
+        # carry, in one batch or two
+        assert 1 <= len(calls) <= 2 and sum(calls) <= 2 * n, calls
+        assert all(not p._inbox and not p._pending_verify for p in sim.processes)
+    assert shared.per_view == 0  # no view ever verified for itself
+    assert sum(shared.merged) >= 8 * n
+    sim.check_agreement()
+    assert min(len(d) for d in sim.deliveries) > n  # a wave committed
+
+
+@pytest.mark.parametrize(
+    "n, shared_cls, pump",
+    [
+        (8, _CountingShared, "vector"),
+        (8, _TwoCallShared, "vector"),
+        (16, _TwoCallShared, None),  # the default, at the larger size
+    ],
+)
+def test_cell_shape_delivers_the_scalar_pumps_log(
+    n, shared_cls, pump, registered_default
+):
+    """The committee cell's shape — mempools, the threshold-BLS coin and
+    one shared verifier, which the fuzz above does not pair — delivers
+    the same log at every view whichever pump runs it."""
+    logs = {}
+    for side in ("scalar", pump):
+        sim, shared, mempools = _cell_shape(n, side, shared_cls)
+        assert all(p._vector == (side != "scalar") for p in sim.processes)
+        for _ in _drive_cell_shape(sim, mempools, rounds=9):
+            pass
+        assert shared.per_view == 0
+        logs[side] = (_delivery_logs(sim, range(n)), list(shared.merged))
+    assert any(logs["scalar"][0])
+    assert min(len(log) for log in logs["scalar"][0]) > n
+    assert logs[pump][0] == logs["scalar"][0]
+    # and asked the shared verifier the same questions, call for call
+    assert logs[pump][1] == logs["scalar"][1]

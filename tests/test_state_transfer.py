@@ -351,7 +351,7 @@ def test_attested_peer_floor_unwedges_blocked_buffer():
         p.on_message(BroadcastMessage(vertex=v, round=v.round, sender=3))
     p._started = True
     p.step()
-    assert {v_low.id, v_strong.id, v_weak.id} <= p._buffered_ids
+    assert {v_low.id, v_strong.id, v_weak.id} <= {v.id for v in p.buffer}
 
     # stuck -> sync request fires at lo = min blocker round (5).
     # Requests are unicast (pull gossip, round 11): capture both seams,
@@ -379,7 +379,7 @@ def test_attested_peer_floor_unwedges_blocked_buffer():
     assert not p.state_transfer_needed  # floors <= our round: no rewind
     # admission untouched: everything stays buffered (a lower-floor
     # peer may yet serve the predecessors), nothing was admitted
-    assert {v_low.id, v_strong.id, v_weak.id} <= p._buffered_ids
+    assert {v_low.id, v_strong.id, v_weak.id} <= {v.id for v in p.buffer}
     assert not p.dag.present(v_weak.id)
     # but the requester stops asking for the attested-pruned window —
     # the actual wedge: before the fix this re-requested lo=5 forever
