@@ -6,10 +6,7 @@ spare messages); n and more where every view verifies its own."""
 
 from benchmarks.harness import spanbook
 
-# single quotes: tests/benchmark/test_span_metrics.py looks for each
-# registered name in double quotes and holds this one on its list of
-# names no reader file has
-DISPATCH = 'verify_batch.dispatch'
+DISPATCH = "verify_batch.dispatch"
 
 
 def read(obs):

@@ -131,8 +131,12 @@ def test_the_traced_line_carries_every_new_metric_that_needs_no_device(good):
 def test_the_end_to_end_line_has_the_cells_two_metrics(good):
     cell, observed = good["cell"], good["observed"]
     line = base.bench.read_metrics(cell, "end_to_end", observed, setup_s=12.5)
-    assert set(line) == {"commit_p95_ms", "setup_s"}
-    assert line["commit_p95_ms"]["value"] > 3 * 216.975  # no commit under a wave
+    assert set(line) == {"commit_p50_ms.wan", "setup_s"}
+    assert line["commit_p50_ms.wan"]["value"] > 3 * 216.975  # no commit under a wave
+    # the tail of the same books stays in the traced line, ungated
+    traced = good["line"]["metrics"]
+    assert traced["commit_p95_ms.wan"]["value"] >= line["commit_p50_ms.wan"]["value"]
+    assert "commit_p50_ms.wan" not in traced and "commit_p95_ms" not in traced
 
 
 @pytest.mark.parametrize("control", (controls.LaxVerifier, controls.AcceptAll))
@@ -358,7 +362,8 @@ BOOK0 = {
         "wal.append": stat(50, 5),
         "remote.verify": stat(25, 125),
     },
-    "counts": {"pump.round_advance": 10},
+    "counts": {"pump.round_advance": 10, "pump.wave_commit": 11, "pump.wave_skip": 1,
+               "mempool.cut_at_propose": 45, "mempool.cut_ahead": 15},
 }
 CLUSTER_BOOK = {"spans": {"net.send": stat(960, 700)},
                 "counts": {"pump.round_advance": 40, "net.messages": 3_840}}
@@ -377,7 +382,10 @@ EXPECTED = {
     "remote_rpcs_per_round": 25 / 10,
     "round_ms.wan": 51_000 / 120,
     "wan_floor_ms_per_round": 3 * 40.0,
-    "commit_p50_ms.wan": 2_000.0,
+    "commit_p95_ms.wan": 3_000.0,
+    "mempool_cut_at_propose_pct.wan": 75.0,
+    # one of validator 0's twelve waves went without a commit
+    "waves_without_commit_pct.wan": 100.0 / 12,
     "device_idle_pct.wan": 75.0,
     "comb_program_us.wan": 2_500.0,
 }
@@ -400,9 +408,11 @@ def obs_with(book0=BOOK0, cluster=CLUSTER_BOOK) -> dict:
 
 
 def test_the_manifest_has_the_new_cells_metrics_each_with_a_reader():
-    assert sorted(m["name"] for m in WAN_METRICS) == sorted(EXPECTED)
+    """Every expected name is there; a later PR adds a metric for this
+    cell as a reader file and a manifest entry, so the list is a floor."""
+    assert set(EXPECTED) <= {m["name"] for m in WAN_METRICS}
     for m in WAN_METRICS:
-        assert m["moves"] == "commit_p95_ms" and m["source"] != "program_span"
+        assert m["moves"] == "commit_p50_ms.wan" and m["source"] != "program_span"
         assert os.path.exists(cells.reader_path(ROOT, m["name"]))
     assert "comb_roofline" not in {m["name"] for m in cells.load_cell(ROOT, WAN)["per_layer"]}
 
@@ -417,12 +427,13 @@ def test_reader_works_its_number_out_of_a_hand_filled_book(name):
 
 @pytest.mark.parametrize(
     "name", sorted(n for n in EXPECTED if n.split(".")[0] not in
-                   ("round_ms", "wan_floor_ms_per_round", "commit_p50_ms",
-                    "device_idle_pct", "comb_program_us"))
+                   ("round_ms", "wan_floor_ms_per_round", "commit_p95_ms",
+                    "device_idle_pct", "comb_program_us", "mempool_cut_at_propose_pct"))
 )
 def test_reader_returns_nothing_where_the_run_left_no_book(name):
     """The parent's validators write no span book; a book with other
-    names in it reads as nothing too."""
+    names in it reads as nothing too. (The mempool's share then reads
+    this process's book: ``test_mempool_cut_metric.py``.)"""
     assert READERS[name](obs_with(book0=None)) is None
     empty = {"spans": {}, "counts": {}}
     assert READERS[name](obs_with(book0=empty, cluster=empty)) is None

@@ -1,8 +1,11 @@
-"""``mempool_cut_at_propose_pct``: the stated arithmetic on a hand-filled
-book, nothing where the program counts neither kind of cut or the run
-left no book, and after a window at n=4 of each cell that lists it: 100
-where the validators are ``Node``s (their proposers cut their own
-blocks), 0 where the driver feeds ``Process.submit`` itself.
+"""``mempool_cut_at_propose_pct`` (the committee's) and
+``mempool_cut_at_propose_pct.wan`` (the WAN cell's: a name for each,
+since the two cells report different end-to-end metrics; one reader
+file): the stated arithmetic on a hand-filled book, nothing where the
+program counts neither kind of cut or the run left no book, and after a
+window at n=4 of each cell: 100 where the validators are ``Node``s
+(their proposers cut their own blocks), 0 where the driver feeds
+``Process.submit`` itself.
 """
 
 import importlib.util
@@ -32,8 +35,11 @@ NAME = "mempool_cut_at_propose_pct"
 WAN = "narwhal20-wan.poisson512"
 COMMITTEE = "committee256.poisson1k"
 MANIFEST = cells.load_manifest(ROOT)
+WAN_NAME = NAME + ".wan"
 ENTRY = next(m for m in MANIFEST["per_layer"] if m["name"] == NAME)
-READ = cells.load_readers(ROOT, [ENTRY])[NAME]
+WAN_ENTRY = next(m for m in MANIFEST["per_layer"] if m["name"] == WAN_NAME)
+READERS = cells.load_readers(ROOT, [ENTRY, WAN_ENTRY])
+READ = READERS[NAME]
 TRACED = {"programs": {}, "busy_s": 0.1, "window_s": 4.0}
 
 
@@ -62,16 +68,21 @@ def own_book(monkeypatch):
     return fill
 
 
-def test_the_manifest_lists_the_metric_for_the_two_cells_with_a_mempool():
-    assert ENTRY == {
-        "name": NAME, "unit": "%", "better": "higher", "source": "program_counter",
-        "layer": "mempool", "moves": "commit_p95_ms", "workloads": [WAN, COMMITTEE],
+@pytest.mark.parametrize(
+    "entry, cell, moves",
+    [(ENTRY, COMMITTEE, "commit_p95_ms"), (WAN_ENTRY, WAN, "commit_p50_ms.wan")],
+)
+def test_the_manifest_lists_the_metric_once_for_each_cell_with_a_mempool(entry, cell, moves):
+    """Split by what the two cells report end to end, read by one file."""
+    assert entry == {
+        "name": entry["name"], "unit": "%", "better": "higher", "source": "program_counter",
+        "layer": "mempool", "moves": moves, "workloads": [cell],
     }
-    assert cells.reader_path(ROOT, NAME).endswith(NAME + ".py")
-    for cell in (WAN, COMMITTEE):
-        assert NAME in {m["name"] for m in cells.load_cell(ROOT, cell)["per_layer"]}
-    for cell in ("sidecar256.colocated4", "sidecar256.colocated1"):
-        assert NAME not in {m["name"] for m in cells.load_cell(ROOT, cell)["per_layer"]}
+    assert cells.reader_path(ROOT, entry["name"]).endswith(os.sep + NAME + ".py")
+    assert moves in {m["name"] for m in cells.load_cell(ROOT, cell)["end_to_end"]}
+    for other in (w["name"] for w in MANIFEST["workloads"]):
+        listed = entry["name"] in {m["name"] for m in cells.load_cell(ROOT, other)["per_layer"]}
+        assert listed == (other == cell), other
 
 
 @pytest.mark.parametrize(
@@ -84,7 +95,8 @@ def test_the_manifest_lists_the_metric_for_the_two_cells_with_a_mempool():
 )
 def test_reader_works_the_share_out_of_validator_0s_book(counts, want, own_book):
     own_book(**{"mempool.cut_ahead": 1_000})  # not this book: validator 0's
-    assert READ(cluster_obs(book(**counts))) == pytest.approx(want)
+    assert READERS[WAN_NAME](cluster_obs(book(**counts))) == pytest.approx(want)
+    assert READ(cluster_obs(book(**counts))) == pytest.approx(want)  # one file for both
 
 
 @pytest.mark.parametrize(
@@ -135,7 +147,8 @@ def test_the_clusters_validators_cut_every_block_at_the_proposal_at_n4():
     seen = cluster_cell.window_over(base.host_backend, trace_on=1)
     line = seen["line"]
     assert line["correct"], line["compared"]
-    assert line["metrics"][NAME] == {"value": 100.0, "unit": "%"}
+    assert line["metrics"][WAN_NAME] == {"value": 100.0, "unit": "%"}
+    assert NAME not in line["metrics"]
     book0 = seen["observed"]["counters"]["validator0_book"]
     assert book0["counts"]["mempool.cut_at_propose"] == book0["spans"]["mempool.wait"]["count"]
     assert "mempool.cut_ahead" not in book0["counts"]

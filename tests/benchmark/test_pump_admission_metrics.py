@@ -112,7 +112,7 @@ def test_both_counters_are_registered_and_the_reader_names_them():
     for counter in ("pump.admit_batched", "pump.admit_scalar"):
         assert counter in spans.KNOWN_COUNTS and f'"{counter}"' in text
     assert "verify_batch.dispatch" in spans.KNOWN_SPANS
-    assert "'verify_batch.dispatch'" in open(cells.reader_path(ROOT, DISPATCHES)).read()
+    assert '"verify_batch.dispatch"' in open(cells.reader_path(ROOT, DISPATCHES)).read()
 
 
 def test_a_committee_window_at_n4_admits_every_vertex_in_batches(monkeypatch):
