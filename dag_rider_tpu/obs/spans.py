@@ -122,6 +122,12 @@ KNOWN_COUNTS = frozenset(
         # lists someone read, and so became tuples of ids
         "sidecar.vertices_decoded",
         "codec.edges_unpacked",
+        # verifier/sidecar.py — bytes of the requests the handler took
+        # (a whole round an RPC: ~5.6 KB a vertex at n=1,024)
+        "sidecar.request_bytes",
+        # verifier/tpu.py — bytes of the comb tables on the device (every
+        # key's and the base point's), once where they are built
+        "verifier.table_bytes",
         # mempool/ — blocks cut for a vertex that was being made (a
         # proposer's ``block_source``), and blocks cut ahead of one
         # (``build_blocks``, for a caller that stages them)

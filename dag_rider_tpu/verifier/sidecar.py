@@ -34,6 +34,20 @@ from dag_rider_tpu.verifier.base import Verifier, VerifierUnavailableError
 _METHOD = "/dagrider.Verifier/VerifyBatch"
 _identity = lambda b: b  # noqa: E731
 
+#: Largest request or reply either end accepts, in bytes. gRPC's own
+#: default (4 MiB) refuses a whole round of a 1,024-validator committee
+#: (5.7 MB: 683 strong edges of 8 bytes a vertex). A round is one RPC,
+#: so the ceiling is sized for the largest the repo names — n=1,024,
+#: each vertex with 683 strong and up to 341 weak edges (8 KiB) and a
+#: block of the mempool's default 8 KiB, ~17 MB in all — with ~4x room.
+#: A request over it is a transport fault: the client's send or the
+#: server's receive fails, and the batch reads all-invalid (fail-closed).
+MAX_MESSAGE_BYTES = 64 * 1024 * 1024
+_MESSAGE_OPTIONS = (
+    ("grpc.max_receive_message_length", MAX_MESSAGE_BYTES),
+    ("grpc.max_send_message_length", MAX_MESSAGE_BYTES),
+)
+
 
 def _encode_batch(vertices: Sequence[Vertex]) -> bytes:
     return b"".join(codec.frame(codec.encode_vertex(v)) for v in vertices)
@@ -81,6 +95,7 @@ class _VerifyHandler(grpc.GenericRpcHandler):
                     "sidecar.between_rpcs",
                     obs.spans.clock_ns() - self._left_ns,
                 )
+            obs.count("sidecar.request_bytes", len(request))
             try:
                 with obs.span("sidecar.rpc"):
                     return serve(request, context)
@@ -127,7 +142,9 @@ class VerifierSidecarServer:
         obs.spans.watch_gc()
         # one worker: device dispatches serialize anyway, and a single
         # thread keeps per-backend batching deterministic.
-        self._server = grpc.server(futures.ThreadPoolExecutor(max_workers=1))
+        self._server = grpc.server(
+            futures.ThreadPoolExecutor(max_workers=1), options=_MESSAGE_OPTIONS
+        )
         self._server.add_generic_rpc_handlers((_VerifyHandler(backend),))
         self.bound_port = self._server.add_insecure_port(listen_addr)
         self._server.start()
@@ -195,7 +212,9 @@ class RemoteVerifier(Verifier):
         self._connect()
 
     def _connect(self) -> None:
-        self._channel = grpc.insecure_channel(self._address)
+        self._channel = grpc.insecure_channel(
+            self._address, options=_MESSAGE_OPTIONS
+        )
         self._call = self._channel.unary_unary(
             _METHOD,
             request_serializer=_identity,

@@ -458,6 +458,10 @@ class TPUVerifier(Verifier):
             self._key_tables = jax.jit(comb.pad_rows)(built)
             jax.block_until_ready((self._key_tables, self._b_table_dev))
             self.table_build_s = time.perf_counter() - t0
+            obs.count(
+                "verifier.table_bytes",
+                self._key_tables.nbytes + self._b_table_dev.nbytes,
+            )
         return self._key_tables, self._b_table_dev
 
     def cover_in_flight(self, depth: int) -> None:

@@ -186,3 +186,21 @@ def test_one_compiled_program_after_warmup_and_an_oversize_batch(
     assert v.verify_batch(signed_vertices * 5) == [True] * 40
     assert v.total_dispatches == 3
     assert list(v.stats()["compile_s"]) == ["16xjnp"]
+
+
+def test_the_comb_tables_are_counted_once_in_bytes():
+    """``verifier.table_bytes``: every key's comb table and the base
+    point's in the [rows, 128] int32 gather layout — (n + 1) keys x 64
+    windows x 16 entries x 128 lanes x 4 bytes — counted where they are
+    built, and not again where they are looked up."""
+    from dag_rider_tpu.obs import spans
+
+    def counted() -> int:
+        return spans.snapshot()["counts"].get("verifier.table_bytes", 0)
+
+    v = TPUVerifier(KeyRegistry.generate(16)[0])
+    before = counted()
+    tables, b_tab = v._comb_tables()
+    v._comb_tables()
+    assert counted() - before == (16 + 1) * 64 * 16 * 128 * 4
+    assert tables.nbytes + b_tab.nbytes == counted() - before
