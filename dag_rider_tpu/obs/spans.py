@@ -128,6 +128,11 @@ KNOWN_COUNTS = frozenset(
         # verifier/tpu.py — bytes of the comb tables on the device (every
         # key's and the base point's), once where they are built
         "verifier.table_bytes",
+        # verifier/tpu.py — a comb program compiled from the lowered
+        # program stored beside the compile cache, and one traced and
+        # lowered because none was stored there (or it would not load)
+        "verifier.program_loaded",
+        "verifier.program_lowered",
         # mempool/ — blocks cut for a vertex that was being made (a
         # proposer's ``block_source``), and blocks cut ahead of one
         # (``build_blocks``, for a caller that stages them)

@@ -26,18 +26,25 @@ from __future__ import annotations
 import functools
 import gc
 import hashlib
+import json
+import os
 import time
 from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+import jaxlib
 
 from dag_rider_tpu import config, obs
 from dag_rider_tpu.core.types import Vertex
 from dag_rider_tpu.crypto import ed25519
 from dag_rider_tpu.ops import curve, field
-from dag_rider_tpu.utils.jaxcache import device_platform
+from dag_rider_tpu.utils.jaxcache import (
+    device_platform,
+    read_program,
+    write_program,
+)
 from dag_rider_tpu.verifier.base import (
     KeyRegistry,
     Verifier,
@@ -168,6 +175,51 @@ def _device_verify_comb(
         prevalid,
         impl=impl,
     )
+
+
+#: the environment knobs that code under ops/ reads (``config.env_*``).
+#: The comb program's trace reads none of them, but a stored program is
+#: keyed by their values all the same, so that a trace which starts to
+#: read one cannot be loaded under another setting of it.
+_OPS_KNOBS = ("DAGRIDER_MSM_PALLAS",)
+
+
+@functools.lru_cache(maxsize=None)
+def _source_digest() -> str:
+    """sha256 over the source of every module the comb program is traced
+    from: each module under ops/ and this one."""
+    here = os.path.abspath(__file__)
+    ops_dir = os.path.join(os.path.dirname(os.path.dirname(here)), "ops")
+    paths = [
+        os.path.join(ops_dir, f)
+        for f in sorted(os.listdir(ops_dir))
+        if f.endswith(".py")
+    ]
+    digest = hashlib.sha256()
+    for path in paths + [here]:
+        with open(path, "rb") as fh:
+            src = fh.read()
+        digest.update(f"{os.path.basename(path)}\0{len(src)}\0".encode())
+        digest.update(src)
+    return digest.hexdigest()
+
+
+@functools.lru_cache(maxsize=32)
+def _stored_program(blob: bytes):
+    """The jitted comb program of a serialized export, built without
+    tracing any of its kernels: one per distinct program in a process,
+    so a second verifier of a shape compiles from jax's in-process
+    caches as it would from a traced program. The function carries the
+    traced one's name, so the module is ``jit__device_verify_comb``
+    whichever way the program was built, and the device trace finds it
+    under that name."""
+    exported = jax.export.deserialize(bytearray(blob))
+
+    def call(u8, i32, key_tables, b_table):
+        return exported.call(u8, i32, key_tables, b_table)
+
+    call.__name__ = "_device_verify_comb"
+    return jax.jit(call)
 
 
 _B_TABLE_CACHED: Optional[np.ndarray] = None
@@ -540,18 +592,71 @@ class TPUVerifier(Verifier):
         """The comb=False oracle path's device call."""
         return _device_verify(*(jnp.asarray(a) for a in args))
 
+    def _program_name(self, size: int, impl: str, args: tuple) -> str:
+        """Name of the stored comb program for these arguments: a digest
+        of everything its trace reads besides their values — the sources
+        it is traced from, jax's and jaxlib's versions, the backend and
+        its build, the device kind, the padded size, the tree engine, the
+        arguments' shapes and dtypes, 64-bit mode and the knobs of ops/."""
+        parts = {
+            "sources": _source_digest(),
+            "jax": jax.__version__,
+            "jaxlib": jaxlib.__version__,
+            "platform": self.platform,
+            "platform_version": jax.devices()[0].client.platform_version,
+            "device_kind": self.device_kind,
+            "size": size,
+            "impl": impl,
+            "args": [[list(a.shape), str(a.dtype)] for a in args],
+            "x64": bool(jax.config.jax_enable_x64),
+            "knobs": {k: config.env_str(k) for k in _OPS_KNOBS},
+        }
+        blob = json.dumps(parts, sort_keys=True).encode()
+        return "comb-" + hashlib.sha256(blob).hexdigest()
+
     def _aot_lower(self, size: int, impl: str, tables, b_tab):
         """lower+compile the comb program at the exact dispatch shape.
         (The inputs are not donated: the only output is the bool mask,
         which can alias neither — on the chip XLA answered a donation
-        with "Some donated buffers were not usable", PR 21.)"""
-        return _device_verify_comb.lower(
+        with "Some donated buffers were not usable".)
+
+        Tracing the kernels is most of a warm process's set-up (tens of
+        seconds of Python with the Pallas tree), and XLA's persistent
+        cache cannot spare it: its key is computed from the lowered
+        module. So the lowered program is kept beside that cache
+        (``jaxcache.write_program``) under :meth:`_program_name`, and a
+        process that finds it there compiles it without a trace
+        (``verifier.program_loaded``). A program missing, not whole, or
+        that does not deserialize or compile is lowered as it would be
+        without the store and written again (``verifier.program_lowered``);
+        an error raised here is the lowering's own, never the store's."""
+        args = (
             jax.ShapeDtypeStruct((size, _U8_COLS), jnp.uint8),
             jax.ShapeDtypeStruct((size, 23), jnp.int32),
-            tables,
-            b_tab,
-            impl=impl,
-        ).compile()
+            jax.ShapeDtypeStruct(tables.shape, tables.dtype),
+            jax.ShapeDtypeStruct(b_tab.shape, b_tab.dtype),
+        )
+        name = self._program_name(size, impl, args)
+        blob = read_program(name)
+        if blob is not None:
+            try:
+                exe = _stored_program(blob).lower(*args).compile()
+            except Exception:  # a stored program that will not load is a miss
+                pass
+            else:
+                obs.count("verifier.program_loaded")
+                return exe
+        obs.count("verifier.program_lowered")
+        try:
+            exported = jax.export.export(_device_verify_comb)(*args, impl=impl)
+            blob = bytes(exported.serialize())
+            exe = _stored_program(blob).lower(*args).compile()
+        except Exception:  # lowered as without the store: its error is the one raised
+            return _device_verify_comb.lower(
+                args[0], args[1], tables, b_tab, impl=impl
+            ).compile()
+        write_program(name, blob)
+        return exe
 
     def _note_dispatch(self, size: int, count: int) -> None:
         """Per-dispatch gauge hook (mesh subclasses book shard balance)."""
