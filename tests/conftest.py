@@ -17,7 +17,10 @@ if "xla_force_host_platform_device_count" not in _flags:
         _flags + " --xla_force_host_platform_device_count=8"
     ).strip()
 
+import contextlib  # noqa: E402
+import faulthandler  # noqa: E402
 import gc  # noqa: E402
+import signal  # noqa: E402
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
@@ -74,6 +77,60 @@ def _benchmark_files_open_an_empty_span_book(request):
             share.spans.clear()
             share.counts.clear()
     yield
+
+
+# Every phase of a test (its fixtures' setup, its body, their teardown)
+# runs under one wall-clock limit, so a hang (a loop that never ends, a
+# join or a wait that nothing wakes) fails that one test with the line it
+# hung on, and the run goes on. Three times the slowest phase of a cold
+# run of the suite (no .jax_cache, six workers), rounded up to a minute:
+# test_prep.py::test_prep_masks_byte_identical[1-1], 111.3 s.
+LIMIT_S = 360
+
+
+@contextlib.contextmanager
+def _time_limit():
+    """SIGALRM after ``LIMIT_S`` seconds: dump the main thread's stack
+    and fail the test. ``Failed`` is a ``BaseException``, so a test's own
+    ``except Exception`` cannot swallow it; and Python retries an
+    interrupted wait only when the handler returns (PEP 475), so a join,
+    an ``Event.wait`` or a lock acquire ends here too."""
+    if not hasattr(signal, "setitimer"):
+        yield
+        return
+
+    def over_limit(signum, frame):
+        __tracebackhide__ = True
+        faulthandler.dump_traceback(sys.__stderr__, all_threads=False)
+        pytest.fail(f"over its {LIMIT_S} s limit")
+
+    previous = signal.signal(signal.SIGALRM, over_limit)
+    signal.setitimer(signal.ITIMER_REAL, LIMIT_S)
+    try:
+        yield
+    finally:
+        try:
+            signal.setitimer(signal.ITIMER_REAL, 0)
+        finally:
+            signal.signal(signal.SIGALRM, previous)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_setup(item):
+    with _time_limit():
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_call(item):
+    with _time_limit():
+        return (yield)
+
+
+@pytest.hookimpl(wrapper=True)
+def pytest_runtest_teardown(item, nextitem):
+    with _time_limit():
+        return (yield)
 
 
 def pytest_sessionfinish(session, exitstatus):
