@@ -79,7 +79,8 @@ _register("DAGRIDER_VERIFY_FALLBACK", "str", "",
 _register("DAGRIDER_PREP_WORKERS", "int", 1,
           "parallel host-prep worker count", minimum=1)
 _register("DAGRIDER_NATIVE", "flag", True,
-          "native challenge hashing (hashlib fallback when off)")
+          "native challenge hashing and vertex signing (hashlib and "
+          "pure-Python signing when off)")
 _register("DAGRIDER_PALLAS_GROUP", "flag", True,
           "Pallas group-op kernels on real TPU backends")
 _register("DAGRIDER_MSM_PALLAS", "flag", True,

@@ -1,14 +1,17 @@
-// Batched Ed25519 challenge-scalar computation — native host component.
+// Native host crypto: batched Ed25519 challenge scalars, and signing.
 //
 // The verify host path computes k = SHA-512(R || A || M) mod L per vertex
 // (RFC 8032 §5.1.7 step 2); at the 50k sigs/s north star this per-row work
 // is the last Python loop in TPUVerifier._prepare. This library does the
 // whole batch in one C call: a self-contained FIPS 180-4 SHA-512 (spec
 // constants, no OpenSSL dependency) and a byte-Horner mod-L reduction.
+// A process signs its own vertices (VertexSigner) through libcrypto's
+// Ed25519 (RFC 8032 §5.1.6, deterministic: the same key and message give
+// the same 64 bytes as the pure-Python signer).
 //
 // Exposed via ctypes (dag_rider_tpu/utils/native.py); built on demand with
-// `g++ -O2 -shared -fPIC`. Pure-Python hashlib remains the fallback and
-// the differential-testing oracle (tests/test_native.py).
+// `g++ -O2 -shared -fPIC`. Pure Python (hashlib, crypto/ed25519.py) remains
+// the fallback and the differential-testing oracle (tests/test_native.py).
 
 #include <cstdint>
 #include <cstring>
@@ -19,25 +22,68 @@
 
 namespace {
 
-// OpenSSL's one-shot SHA512 (stable libcrypto ABI), resolved at runtime —
-// the image ships libcrypto.so.3 but no dev headers/symlink. When absent
-// the self-contained FIPS 180-4 implementation below is used instead;
-// both produce identical digests (differentially tested against hashlib).
+// libcrypto, resolved at runtime — the image ships libcrypto.so.3 but no
+// dev headers/symlink. nullptr when absent. Each resolver below runs once,
+// at its first call (a function-local static: thread-safe in C++11).
+void* libcrypto() {
+  // RTLD_LOCAL: we only dlsym from our own handle; exporting OpenSSL
+  // symbols globally could interpose on a different libcrypto already
+  // loaded by Python's _ssl/cryptography modules.
+  static void* const handle = [] {
+    void* h = dlopen("libcrypto.so.3", RTLD_NOW | RTLD_LOCAL);
+    return h ? h : dlopen("libcrypto.so.1.1", RTLD_NOW | RTLD_LOCAL);
+  }();
+  return handle;
+}
+
+// OpenSSL's one-shot SHA512 (stable libcrypto ABI). When absent the
+// self-contained FIPS 180-4 implementation below is used instead; both
+// produce identical digests (differentially tested against hashlib).
 typedef unsigned char* (*sha512_fn)(const unsigned char*, size_t,
                                     unsigned char*);
 
 sha512_fn resolve_openssl_sha512() {
-  static sha512_fn cached = nullptr;
-  static bool tried = false;
-  if (!tried) {
-    tried = true;
-    // RTLD_LOCAL: we only dlsym from our own handle; exporting OpenSSL
-    // symbols globally could interpose on a different libcrypto already
-    // loaded by Python's _ssl/cryptography modules.
-    void* h = dlopen("libcrypto.so.3", RTLD_NOW | RTLD_LOCAL);
-    if (!h) h = dlopen("libcrypto.so.1.1", RTLD_NOW | RTLD_LOCAL);
-    if (h) cached = (sha512_fn)dlsym(h, "SHA512");
-  }
+  static const sha512_fn cached = [] {
+    void* h = libcrypto();
+    return h ? (sha512_fn)dlsym(h, "SHA512") : nullptr;
+  }();
+  return cached;
+}
+
+// libcrypto's EVP signing API, as far as Ed25519 needs it (opaque
+// pointers; stable ABI across 1.1.1 and 3.x).
+const int kNidEd25519 = 1087;  // EVP_PKEY_ED25519
+
+struct Evp {
+  void* (*pkey_new_raw_private_key)(int, void*, const unsigned char*, size_t);
+  void (*pkey_free)(void*);
+  void* (*md_ctx_new)();
+  void (*md_ctx_free)(void*);
+  int (*digest_sign_init)(void*, void**, const void*, void*, void*);
+  int (*digest_sign)(void*, unsigned char*, size_t*, const unsigned char*,
+                     size_t);
+};
+
+// nullptr unless every function resolved.
+const Evp* resolve_evp() {
+  static Evp evp;
+  static const Evp* const cached = []() -> const Evp* {
+    void* h = libcrypto();
+    if (!h) return nullptr;
+    evp.pkey_new_raw_private_key =
+        (decltype(evp.pkey_new_raw_private_key))dlsym(
+            h, "EVP_PKEY_new_raw_private_key");
+    evp.pkey_free = (decltype(evp.pkey_free))dlsym(h, "EVP_PKEY_free");
+    evp.md_ctx_new = (decltype(evp.md_ctx_new))dlsym(h, "EVP_MD_CTX_new");
+    evp.md_ctx_free = (decltype(evp.md_ctx_free))dlsym(h, "EVP_MD_CTX_free");
+    evp.digest_sign_init =
+        (decltype(evp.digest_sign_init))dlsym(h, "EVP_DigestSignInit");
+    evp.digest_sign = (decltype(evp.digest_sign))dlsym(h, "EVP_DigestSign");
+    bool all = evp.pkey_new_raw_private_key && evp.pkey_free &&
+               evp.md_ctx_new && evp.md_ctx_free && evp.digest_sign_init &&
+               evp.digest_sign;
+    return all ? &evp : nullptr;
+  }();
   return cached;
 }
 
@@ -279,6 +325,37 @@ void dagrider_challenge_batch(const uint8_t* rs, const uint8_t* pks,
     sha.final(digest);
     reduce_digest_mod_l(digest, out + 32 * i);
   }
+}
+
+// Ed25519 signing key from a 32-byte RFC 8032 seed (libcrypto derives the
+// public key here, once); nullptr where libcrypto or its Ed25519 cannot be
+// resolved. Free with dagrider_ed25519_key_free.
+void* dagrider_ed25519_key_new(const uint8_t* seed) {
+  const Evp* evp = resolve_evp();
+  if (!evp) return nullptr;
+  return evp->pkey_new_raw_private_key(kNidEd25519, nullptr, seed, 32);
+}
+
+// out: the 64-byte signature of msg[0:len]. 0 on success. A context a
+// call, so calls on one key from several threads are safe.
+int dagrider_ed25519_sign(void* key, const uint8_t* msg, size_t len,
+                          uint8_t* out) {
+  const Evp* evp = resolve_evp();
+  if (!evp || !key) return 1;
+  void* ctx = evp->md_ctx_new();
+  if (!ctx) return 2;
+  size_t siglen = 64;
+  int rc = 3;
+  if (evp->digest_sign_init(ctx, nullptr, nullptr, nullptr, key) == 1 &&
+      evp->digest_sign(ctx, out, &siglen, msg, len) == 1 && siglen == 64)
+    rc = 0;
+  evp->md_ctx_free(ctx);
+  return rc;
+}
+
+void dagrider_ed25519_key_free(void* key) {
+  const Evp* evp = resolve_evp();
+  if (evp && key) evp->pkey_free(key);
 }
 
 }  // extern "C"

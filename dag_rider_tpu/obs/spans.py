@@ -156,6 +156,10 @@ KNOWN_COUNTS = frozenset(
         "net.retry",
         "net.peer_down",
         "net.to_down_peer",
+        # verifier/base.py — a vertex VertexSigner signed through
+        # libcrypto's Ed25519, and one it signed in pure Python
+        "sign.native",
+        "sign.python",
     }
 )
 

@@ -1,15 +1,17 @@
 """ctypes seam to the native host library (native/challenge.cpp).
 
 The build's native surface (SURVEY §2a: host-side native code in C++):
-batched Ed25519 challenge-scalar computation for the verify host path.
+batched Ed25519 challenge-scalar computation for the verify host path,
+and libcrypto's Ed25519 signing for a process's own vertices.
 The library is compiled on demand with ``g++ -O2 -shared -fPIC`` into the
 package's ``native/`` directory and loaded with ctypes — no pybind11 /
 build-system dependency. The object's name carries a hash of the source
 it was built from, so an object left over from an older challenge.cpp
 (or copied around with a fresh mtime) is never loaded. A build or load
 failure raises: running without the library is a choice
-(``DAGRIDER_NATIVE=0``, the hashlib path — which stays the
-differential-testing oracle, tests/test_native.py), not a fallback.
+(``DAGRIDER_NATIVE=0``, the hashlib and pure-Python signing paths —
+which stay the differential-testing oracles, tests/test_native.py), not
+a fallback.
 """
 
 from __future__ import annotations
@@ -20,6 +22,7 @@ import hashlib
 import os
 import subprocess
 import threading
+import weakref
 from typing import Optional, Sequence
 
 import numpy as np
@@ -82,6 +85,14 @@ def load() -> ctypes.CDLL:
             u8p, u8p, u8p, u64p, ctypes.c_uint64, u8p,
         ]
         lib.dagrider_challenge_batch.restype = None
+        lib.dagrider_ed25519_key_new.argtypes = [ctypes.c_char_p]
+        lib.dagrider_ed25519_key_new.restype = ctypes.c_void_p
+        lib.dagrider_ed25519_sign.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_size_t, ctypes.c_char_p,
+        ]
+        lib.dagrider_ed25519_sign.restype = ctypes.c_int
+        lib.dagrider_ed25519_key_free.argtypes = [ctypes.c_void_p]
+        lib.dagrider_ed25519_key_free.restype = None
         _lib = lib
         return _lib
 
@@ -126,3 +137,32 @@ def challenge_batch(
         _u8(out),
     )
     return out
+
+
+class Ed25519Key:
+    """libcrypto's Ed25519 signing key for one RFC 8032 seed, made once:
+    making it derives the public key with a scalar multiply. Signatures
+    are byte-identical to ``crypto.ed25519.sign`` (RFC 8032 signing is
+    deterministic). The key is freed with this object."""
+
+    def __init__(self, lib: ctypes.CDLL, handle: int):
+        self._sign = lib.dagrider_ed25519_sign
+        self._handle = handle
+        weakref.finalize(self, lib.dagrider_ed25519_key_free, handle)
+
+    @classmethod
+    def make(cls, seed: bytes) -> Optional["Ed25519Key"]:
+        """The key for a 32-byte seed, or None where libcrypto or its
+        Ed25519 cannot be resolved. Builds the library on first use."""
+        if len(seed) != 32:
+            raise ValueError("seed must be 32 bytes")
+        lib = load()
+        handle = lib.dagrider_ed25519_key_new(seed)
+        return cls(lib, handle) if handle else None
+
+    def sign(self, message: bytes) -> bytes:
+        out = ctypes.create_string_buffer(64)
+        rc = self._sign(self._handle, message, len(message), out)
+        if rc != 0:
+            raise RuntimeError(f"libcrypto's Ed25519 signing failed ({rc})")
+        return out.raw
