@@ -183,10 +183,14 @@ def _own_deliveries(i: int, prev, sink: list, clock):
 
 def _forge(stack: Stack, rnd: int):
     """One wrong vertex for round ``rnd`` and the message that carries
-    it; the kind and the source come from the seed."""
+    it; the kind and the source come from the seed. Where every source
+    of ``rnd`` is already forged (a small committee whose view 0 is
+    slow to advance), under the first round after it that has one free."""
     from dag_rider_tpu.core.types import BroadcastMessage
 
     rng, n = stack.rng, stack.n
+    while all((rnd, s) in stack.forged_ids for s in range(n)):
+        rnd += 1
     q = roundpool.quorum(n)
     strong = tuple((rnd - 1, s) for s in range(q))
     kind = roundpool.KINDS[(stack.first_kind + len(stack.forged_ids)) % len(roundpool.KINDS)]

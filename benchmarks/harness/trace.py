@@ -11,6 +11,7 @@ everything else.
 from __future__ import annotations
 
 import glob
+import heapq
 import os
 import shutil
 import time
@@ -18,9 +19,21 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 Interval = Tuple[float, float]
 
-#: host spans the gaps are named by: the program's two annotations and
-#: the ones the benchmark's drivers put around their own calls
-HOST_PREFIXES = ("bench.", "verify_batch.")
+
+def _program_prefixes() -> Tuple[str, ...]:
+    """The layers of every span the program may open (``<layer>.``, from
+    ``obs.spans.KNOWN_SPANS``); none from a program without them."""
+    try:
+        from dag_rider_tpu.obs.spans import KNOWN_SPANS
+    except ImportError:
+        return ()
+    return tuple(sorted({name.split(".", 1)[0] + "." for name in KNOWN_SPANS}))
+
+
+#: host spans the gaps are named by: the ones the benchmark's drivers put
+#: around their own calls, the verify seam's, and every span the program
+#: opens (``pump.``, ``coin.``, ``sign.``, ``sidecar.``, ...)
+HOST_PREFIXES = tuple(dict.fromkeys(("bench.", "verify_batch.") + _program_prefixes()))
 BETWEEN_OPS = "device:between_ops_of_a_program"
 UNANNOTATED = "host:unannotated"
 
@@ -89,16 +102,36 @@ def complement(covered: Sequence[Interval], lo: float, hi: float) -> List[Interv
     return [(a, b) for a, b in out if b > a]
 
 
-def _name_gap(gap: Interval, spans: Sequence[list], into: Dict[str, float]) -> None:
-    """Share one idle gap out among the host spans that cover it, each
-    stretch going to the innermost (shortest) span over it."""
-    a, b = gap
-    over = [s for s in spans if s[1] < b and s[1] + s[2] > a]
-    cuts = sorted({a, b, *(max(a, s[1]) for s in over), *(min(b, s[1] + s[2]) for s in over)})
-    for lo, hi in zip(cuts, cuts[1:]):
-        inner = [s for s in over if s[1] <= lo and s[1] + s[2] >= hi]
-        name = min(inner, key=lambda s: s[2])[0] if inner else UNANNOTATED
-        into[name] = into.get(name, 0.0) + (hi - lo)
+def _name_gaps(gaps: Sequence[Interval], spans: Sequence[list], into: Dict[str, float]) -> None:
+    """Share sorted, disjoint idle gaps out among the host spans that
+    cover them, each stretch going to the innermost (shortest) span over
+    it, the earlier-listed of two as long; one sweep over the spans by
+    their start, so that a trace with tens of thousands of the program's
+    spans reduces in one pass."""
+    order = sorted(range(len(spans)), key=lambda i: spans[i][1])
+    nxt = 0
+    #: (duration, index, end) of the spans started so far; one that has
+    #: ended leaves only when it comes to the top
+    open_: List[tuple] = []
+    for a, b in gaps:
+        at = a
+        while at < b:
+            while nxt < len(order) and spans[order[nxt]][1] <= at:
+                i = order[nxt]
+                heapq.heappush(open_, (spans[i][2], i, spans[i][1] + spans[i][2]))
+                nxt += 1
+            while open_ and open_[0][2] <= at:
+                heapq.heappop(open_)
+            until = b
+            if nxt < len(order):
+                until = min(until, spans[order[nxt]][1])
+            if open_:
+                until = min(until, open_[0][2])
+                name = spans[open_[0][1]][0]
+            else:
+                name = UNANNOTATED
+            into[name] = into.get(name, 0.0) + (until - at)
+            at = until
 
 
 def _top(book: Dict[str, float], k: int = 10) -> List[list]:
@@ -134,8 +167,7 @@ def reduce(events: dict, window_s: float) -> Optional[dict]:
     between = total(complement(busy, lo, hi)) - total(complement(inside, lo, hi))
     if between > 0:
         gap_book[BETWEEN_OPS] = between
-    for gap in complement(inside, lo, hi):
-        _name_gap(gap, host, gap_book)
+    _name_gaps(complement(inside, lo, hi), host, gap_book)
     return {
         "busy_s": busy_ns * 1e-9 / len(chips),
         "window_s": window_s,

@@ -41,7 +41,13 @@ _spec = importlib.util.spec_from_file_location(
 bench = importlib.util.module_from_spec(_spec)
 _spec.loader.exec_module(bench)
 
-MANIFEST = cells.load_manifest(ROOT)
+_spec = importlib.util.spec_from_file_location(
+    "benchmark_manifest_rule", os.path.join(os.path.dirname(__file__), "manifest_rule.py")
+)
+rule = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(rule)
+
+MANIFEST = rule.MANIFEST
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 LINE_KEYS = ["correct", "attempted", "failed", "metrics", "device"]
@@ -186,8 +192,13 @@ def test_a_cell_a_config_and_a_metric_are_added_without_editing_a_file(tmp_path)
         json.dump(manifest, fh)
     cell = cells.load_cell(root, "committee64.uniform200")
     assert cell["config"]["n"] == 64 and cell["traffic"]["profile"] == "uniform"
-    assert [m["name"] for m in cell["end_to_end"]] == ["commit_p95_ms", "setup_s"]
-    assert [m["name"] for m in cell["per_layer"]] == ["cycles_in_window"]
+    rule.check_cell(
+        "committee64.uniform200", manifest, per_layer=["cycles_in_window"],
+        end_to_end=["commit_p95_ms", "setup_s"],
+    )
+    assert {m["name"] for m in cell["per_layer"]} == rule.owned_names(
+        "committee64.uniform200", manifest=manifest
+    )
     readers = cells.load_readers(root, cell["per_layer"])
     assert readers["cycles_in_window"]({"counters": {"cycles": 7}}) == 7
     assert cells.load_driver(root, cell["config"]["driver"]).run_window

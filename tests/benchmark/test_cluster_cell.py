@@ -27,16 +27,23 @@ from benchmarks.harness import (  # noqa: E402
     validatorbook,
 )
 
-_spec = importlib.util.spec_from_file_location(
-    "benchmark_test_cells", os.path.join(os.path.dirname(__file__), "test_cells.py")
-)
-base = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(base)
+
+def _sibling(stem):
+    spec = importlib.util.spec_from_file_location(
+        f"benchmark_{stem}", os.path.join(os.path.dirname(__file__), f"{stem}.py")
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+base = _sibling("test_cells")
+rule = _sibling("manifest_rule")
 
 WAN = "narwhal20-wan.poisson512"
 CO1 = "sidecar256.colocated1"
-MANIFEST = cells.load_manifest(ROOT)
-WAN_METRICS = [m for m in MANIFEST["per_layer"] if m.get("workloads") == [WAN]]
+MANIFEST = rule.MANIFEST
+WAN_METRICS = rule.owned(WAN)
 READERS = cells.load_readers(ROOT, WAN_METRICS)
 SECONDS = 3.0
 MS = 1_000_000  # ns
@@ -116,7 +123,8 @@ def test_the_window_is_correct_with_every_compared_number_zero(good):
 
 def test_the_traced_line_carries_every_new_metric_that_needs_no_device(good):
     metrics = good["line"]["metrics"]
-    want = {m["name"] for m in WAN_METRICS if m["source"] != "device_trace"}
+    # the cell's own (program_loaded_pct needs the device verifier's program)
+    want = {n for n in EXPECTED if rule.entry(n)["source"] != "device_trace"}
     assert want <= set(metrics), want - set(metrics)
     assert metrics["wan_floor_ms_per_round"]["value"] == pytest.approx(216.975)
     assert metrics["round_ms.wan"]["value"] > metrics["wan_floor_ms_per_round"]["value"]
@@ -131,7 +139,8 @@ def test_the_traced_line_carries_every_new_metric_that_needs_no_device(good):
 def test_the_end_to_end_line_has_the_cells_two_metrics(good):
     cell, observed = good["cell"], good["observed"]
     line = base.bench.read_metrics(cell, "end_to_end", observed, setup_s=12.5)
-    assert set(line) == {"commit_p50_ms.wan", "setup_s"}
+    assert set(line) == rule.owned_names(WAN, "end_to_end")
+    rule.assert_floor({"commit_p50_ms.wan", "setup_s"}, line)
     assert line["commit_p50_ms.wan"]["value"] > 3 * 216.975  # no commit under a wave
     # the tail of the same books stays in the traced line, ungated
     traced = good["line"]["metrics"]
@@ -410,9 +419,11 @@ def obs_with(book0=BOOK0, cluster=CLUSTER_BOOK) -> dict:
 def test_the_manifest_has_the_new_cells_metrics_each_with_a_reader():
     """Every expected name is there; a later PR adds a metric for this
     cell as a reader file and a manifest entry, so the list is a floor."""
-    assert set(EXPECTED) <= {m["name"] for m in WAN_METRICS}
-    for m in WAN_METRICS:
+    rule.check_cell(WAN, per_layer=EXPECTED, end_to_end=["commit_p50_ms.wan"])
+    for name in EXPECTED:
+        m = rule.entry(name)
         assert m["moves"] == "commit_p50_ms.wan" and m["source"] != "program_span"
+    for m in WAN_METRICS:
         assert os.path.exists(cells.reader_path(ROOT, m["name"]))
     assert "comb_roofline" not in {m["name"] for m in cells.load_cell(ROOT, WAN)["per_layer"]}
 
@@ -448,11 +459,14 @@ def test_colocated1_is_colocated4_with_one_client():
     assert one["config"] == four["config"]
     differ = {k for k in four["traffic"] if one["traffic"][k] != four["traffic"][k]}
     assert differ == {"clients", "why"} and one["traffic"]["clients"] == 1
-    assert [m["name"] for m in one["end_to_end"]] == [m["name"] for m in four["end_to_end"]]
-    mine = {m["name"] for m in one["per_layer"]}
-    assert mine == {"verify_rpc_p50_ms", "sidecar_gap_ms_per_rpc", "verify_batch_ms_per_rpc",
-                    "device_idle_pct.verify", "comb_program_us", "comb_roofline"}
-    assert not any(m["source"] == "program_span" for m in one["per_layer"])
+    # every end-to-end metric of colocated4's is colocated1's too
+    rule.check_cell(
+        CO1, end_to_end={m["name"] for m in four["end_to_end"]},
+        per_layer={"verify_rpc_p50_ms", "sidecar_gap_ms_per_rpc", "verify_batch_ms_per_rpc",
+                   "device_idle_pct.verify", "comb_program_us", "comb_roofline"},
+    )
+    # the harness loads what the manifest lists for the cell
+    assert {m["name"] for m in one["per_layer"]} == rule.owned_names(CO1)
 
 
 def test_colocated1_window_on_the_host_verifier():
@@ -464,4 +478,5 @@ def test_colocated1_window_on_the_host_verifier():
     )
     base.check_line(line, cell, 0)
     assert line["correct"], line["compared"]
-    assert set(line["metrics"]) == {"verify_rpc_p95_ms", "verified_sigs_per_s", "setup_s"}
+    rule.assert_floor({"verify_rpc_p95_ms", "verified_sigs_per_s", "setup_s"}, line["metrics"])
+    assert set(line["metrics"]) == rule.owned_names(CO1, "end_to_end")

@@ -24,16 +24,23 @@ from benchmarks.harness import (  # noqa: E402
     reference_crash,
 )
 
-_spec = importlib.util.spec_from_file_location(
-    "benchmark_test_cells", os.path.join(os.path.dirname(__file__), "test_cells.py")
-)
-base = importlib.util.module_from_spec(_spec)
-_spec.loader.exec_module(base)
+
+def _sibling(stem):
+    spec = importlib.util.spec_from_file_location(
+        f"benchmark_{stem}", os.path.join(os.path.dirname(__file__), f"{stem}.py")
+    )
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+base = _sibling("test_cells")
+rule = _sibling("manifest_rule")
 
 CRASH = "narwhal10-wan.poisson512-crash3"
 WAN = "narwhal20-wan.poisson512"
-MANIFEST = cells.load_manifest(ROOT)
-CRASH_METRICS = [m for m in MANIFEST["per_layer"] if m.get("workloads") == [CRASH]]
+MANIFEST = rule.MANIFEST
+CRASH_METRICS = rule.owned(CRASH)
 READERS = cells.load_readers(ROOT, CRASH_METRICS)
 SECONDS = 3.0
 MS = 1_000_000  # ns
@@ -130,7 +137,8 @@ def test_the_victim_died_by_round_before_the_window_and_nobody_stood_at_its_door
 
 def test_the_traced_line_carries_every_new_metric_that_needs_no_device(good):
     metrics = good["line"]["metrics"]
-    want = {m["name"] for m in CRASH_METRICS if m["source"] != "device_trace"}
+    # the cell's own (program_loaded_pct needs the device verifier's program)
+    want = {n for n in EXPECTED if rule.entry(n)["source"] != "device_trace"}
     assert want <= set(metrics), want - set(metrics)
     # stockholm gone: virginia, california and sydney wait for each other
     assert metrics["wan_floor_ms_per_round.crash"]["value"] == pytest.approx(300.0)
@@ -268,9 +276,7 @@ def test_the_configuration_and_the_traffic_state_the_fault():
     assert any("crashed validator's log is a prefix" in g for g in config["guarantees"])
     (entry,) = [w for w in MANIFEST["workloads"] if w["name"] == CRASH]
     assert entry["chips"] == 1
-    assert CRASH in next(
-        m for m in MANIFEST["end_to_end"] if m["name"] == "commit_p95_ms"
-    )["workloads"]
+    assert rule.owns(CRASH, rule.entry("commit_p95_ms", "end_to_end"))
 
 
 def test_the_driver_is_thin_and_owns_its_copy_of_the_clusters():
@@ -349,17 +355,25 @@ def obs_with(book0=BOOK0, cluster=CLUSTER_BOOK) -> dict:
 
 
 def test_the_manifest_has_the_cells_metrics_each_with_a_reader():
-    assert sorted(m["name"] for m in CRASH_METRICS) == sorted(EXPECTED)
-    for m in CRASH_METRICS:
+    """The cell's names are a floor: a later PR appends a metric, or this
+    cell to a metric's ``workloads`` (``program_loaded_pct``, which moves
+    ``setup_s``, is every cell's)."""
+    rule.check_cell(CRASH, per_layer=EXPECTED, end_to_end=["commit_p95_ms"])
+    for name in EXPECTED:
+        m = rule.entry(name)
         assert m["moves"] == "commit_p95_ms" and m["source"] != "program_span"
+    for m in CRASH_METRICS:
         assert os.path.exists(cells.reader_path(ROOT, m["name"]))
     mine = {m["name"] for m in cells.load_cell(ROOT, CRASH)["per_layer"]}
-    assert mine == set(EXPECTED)  # no roofline, nothing that scales by n
+    assert mine == rule.owned_names(CRASH)  # the harness loads what the manifest lists
+    # no roofline, nothing that scales by n
+    assert not {"comb_roofline", "net_messages_per_round", "net_delay_lag_ms_per_message"} & mine
     for name in OWN + ("wan_floor_ms_per_round.crash",):
         assert cells.reader_path(ROOT, name).endswith(name + ".py")
-    # the cluster cell's eighteen (sixteen until PR 35) share no name with this cell's
-    wan = [m for m in MANIFEST["per_layer"] if m.get("workloads") == [WAN]]
-    assert len(wan) == 18 and not {m["name"] for m in wan} & set(EXPECTED)
+    # the cluster cell's own metrics share no name with this cell's
+    wan = {m["name"] for m in rule.owned(WAN) if not rule.owns(CRASH, m)}
+    rule.assert_floor(_sibling("test_cluster_cell").EXPECTED, wan, WAN)
+    assert not wan & set(EXPECTED)
 
 
 @pytest.mark.parametrize("name", sorted(EXPECTED))

@@ -31,13 +31,13 @@ def _sibling(stem):
 
 
 base = _sibling("test_cells")
+rule = _sibling("manifest_rule")
 NAME = "mempool_cut_at_propose_pct"
 WAN = "narwhal20-wan.poisson512"
 COMMITTEE = "committee256.poisson1k"
-MANIFEST = cells.load_manifest(ROOT)
 WAN_NAME = NAME + ".wan"
-ENTRY = next(m for m in MANIFEST["per_layer"] if m["name"] == NAME)
-WAN_ENTRY = next(m for m in MANIFEST["per_layer"] if m["name"] == WAN_NAME)
+ENTRY = rule.entry(NAME)
+WAN_ENTRY = rule.entry(WAN_NAME)
 READERS = cells.load_readers(ROOT, [ENTRY, WAN_ENTRY])
 READ = READERS[NAME]
 TRACED = {"programs": {}, "busy_s": 0.1, "window_s": 4.0}
@@ -73,16 +73,20 @@ def own_book(monkeypatch):
     [(ENTRY, COMMITTEE, "commit_p95_ms"), (WAN_ENTRY, WAN, "commit_p50_ms.wan")],
 )
 def test_the_manifest_lists_the_metric_once_for_each_cell_with_a_mempool(entry, cell, moves):
-    """Split by what the two cells report end to end, read by one file."""
-    assert entry == {
-        "name": entry["name"], "unit": "%", "better": "higher", "source": "program_counter",
-        "layer": "mempool", "moves": moves, "workloads": [cell],
-    }
+    """Split by what the two cells report end to end, read by one file:
+    each name is listed for its own cell and not for the other's, and
+    every cell that lists it reports the end-to-end metric it moves."""
+    rule.assert_fields(
+        entry["name"], cells_=[cell], unit="%", better="higher", source="program_counter",
+        layer="mempool", moves=moves,
+    )
     assert cells.reader_path(ROOT, entry["name"]).endswith(os.sep + NAME + ".py")
-    assert moves in {m["name"] for m in cells.load_cell(ROOT, cell)["end_to_end"]}
-    for other in (w["name"] for w in MANIFEST["workloads"]):
+    rule.check_cell(cell, per_layer=[entry["name"]], end_to_end=[moves])
+    for other in rule.cell_names():
         listed = entry["name"] in {m["name"] for m in cells.load_cell(ROOT, other)["per_layer"]}
-        assert listed == (other == cell), other
+        assert listed == rule.owns(other, entry), other
+        assert not listed or moves in rule.owned_names(other, "end_to_end"), other
+    assert not rule.owns(WAN if cell == COMMITTEE else COMMITTEE, entry)
 
 
 @pytest.mark.parametrize(

@@ -1,12 +1,14 @@
 """The verify service of a 1,024-validator committee as a cell:
 ``sidecar1024.colocated4`` loads through the harness as data, reports the
 sidecar cells' six per-layer metrics that are not read from the span
-book, each worked out of a hand-filled sample set and trace (and nothing
-without one), the two counters this cell brings are read from a
-hand-filled book, and the ``sidecar`` driver's comparison counts a single
-wrong verdict of a 1,024-vertex mask.
+book and three that are (its server's decode, the gap between RPCs and
+the prep), the two counters this cell brings and the share of programs
+loaded, each worked out of a hand-filled sample set, book and trace (and
+nothing without one), and the ``sidecar`` driver's comparison counts a
+single wrong verdict of a 1,024-vertex mask.
 """
 
+import importlib.util
 import os
 import sys
 import types
@@ -19,6 +21,12 @@ if ROOT not in sys.path:
 
 from benchmarks.harness import bytecount, cells, reference, roundpool  # noqa: E402
 
+_spec = importlib.util.spec_from_file_location(
+    "benchmark_manifest_rule", os.path.join(os.path.dirname(__file__), "manifest_rule.py")
+)
+rule = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(rule)
+
 CELL = "sidecar1024.colocated4"
 MS = 1_000_000  # ns
 ROUND_BYTES = 5_734_375  # one round of the pool on the wire
@@ -30,12 +38,19 @@ def stat(count, total_ms, child_ms=0.0):
             "child_ns": int(child_ms * MS)}
 
 
-#: a sidecar that served 20 RPCs of a whole n=1,024 round each
+#: a sidecar that served 20 RPCs of a whole n=1,024 round each, from a
+#: program it loaded
 BOOK = {
-    "spans": {"sidecar.rpc": stat(20, 1_200, child_ms=500)},
+    "spans": {
+        "sidecar.rpc": stat(20, 1_200, child_ms=500),
+        "sidecar.decode": stat(20, 400, child_ms=20),
+        "sidecar.between_rpcs": stat(19, 418),
+        "verify_batch.prepare": stat(20, 300),
+    },
     "counts": {
         "sidecar.request_bytes": 20 * ROUND_BYTES,
         "verifier.table_bytes": TABLE_BYTES,
+        "verifier.program_loaded": 1,
     },
 }
 PROGRAM_S = [0.0022, 0.0023]
@@ -51,7 +66,9 @@ OBS = {
     "trace": {"programs": {"jit__device_verify_comb(3)": PROGRAM_S},
               "busy_s": 0.15, "window_s": 5.0},
 }
-#: the sidecar cells' metrics this cell reports, in the manifest's order
+#: the sidecar cells' metrics this cell reports, its name appended to
+#: their ``workloads`` (six not read from the book, three of the server's
+#: book), and the share of programs loaded, which every cell reports
 LISTED = {
     "verify_rpc_p50_ms": 260.0,
     "sidecar_gap_ms_per_rpc": 42.0,
@@ -59,10 +76,12 @@ LISTED = {
     "device_idle_pct.verify": 97.0,
     "comb_program_us": 2250.0,
     "comb_roofline": 100 * (46_366_720 / 819e9) / 0.00225,
+    "sidecar_decode_ms_per_rpc": (400 - 20) / 20,
+    "sidecar_between_rpcs_ms_per_rpc": 418 / 19,
+    "seam_prepare_ms_per_rpc": 300 / 20,
+    "program_loaded_pct": 100.0,
 }
-#: the readers of the two counters the cell brings, which the manifest
-#: does not list: ``test_pump_admission_metrics.py`` holds the pump's two
-#: entries last in ``per_layer``, and entries may only be appended
+#: the two counters the cell brings, listed for it alone
 COUNTED = {
     "sidecar_request_kib_per_rpc": ROUND_BYTES / 1024,
     "comb_tables_mib": 512.5,
@@ -88,12 +107,21 @@ def test_the_cell_is_data_over_the_sidecar_driver_and_colocated4(cell):
     assert roundpool.quorum(config["n"]) == 683
     assert config["driver"] == "sidecar" and cell["chips"] == 1
     assert cell["traffic"] == cells.load_cell(ROOT, "sidecar256.colocated4")["traffic"]
-    assert [m["name"] for m in cell["end_to_end"]] == [
-        "verify_rpc_p95_ms", "verified_sigs_per_s", "setup_s",
-    ]
-    assert [m["name"] for m in cell["per_layer"]] == list(LISTED)
-    sidecar256 = ["sidecar256.colocated4", "sidecar256.colocated1"]
-    assert all(m["workloads"] == sidecar256 + [CELL] for m in cell["per_layer"])
+    rule.check_cell(
+        CELL, per_layer=EXPECTED,
+        end_to_end=["verify_rpc_p95_ms", "verified_sigs_per_s", "setup_s"],
+    )
+    assert {m["name"] for m in cell["per_layer"]} == rule.owned_names(CELL)
+    # the sidecar cells' own metrics stay theirs: this cell is appended
+    for name in LISTED:
+        if name != "program_loaded_pct":
+            rule.assert_fields(name, cells_=["sidecar256.colocated4", CELL])
+    for name, unit, layer in (("sidecar_request_kib_per_rpc", "KiB", "sidecar server"),
+                              ("comb_tables_mib", "MiB", "kernels")):
+        rule.assert_fields(name, cells_=[CELL], unit=unit, better="lower",
+                           source="program_counter", layer=layer, moves="verified_sigs_per_s")
+        # the layer's name letter for letter, as the cell's other metrics have it
+        assert layer in {m["layer"] for m in rule.owned("sidecar256.colocated4")}
 
 
 @pytest.mark.parametrize("name", list(EXPECTED))

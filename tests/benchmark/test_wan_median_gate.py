@@ -9,6 +9,7 @@ sixteen chip runs gave (PERF.md section 2). That a per-layer metric's
 ``test_cells.py``'s ``test_every_layer_metric_moves_a_metric_its_cells_report``.
 """
 
+import importlib.util
 import os
 import random
 import sys
@@ -20,6 +21,12 @@ if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
 from benchmarks.harness import cells  # noqa: E402
+
+_spec = importlib.util.spec_from_file_location(
+    "benchmark_manifest_rule", os.path.join(os.path.dirname(__file__), "manifest_rule.py")
+)
+rule = importlib.util.module_from_spec(_spec)
+_spec.loader.exec_module(rule)
 
 WAN = "narwhal20-wan.poisson512"
 COMMITTEE = "committee256.poisson1k"
@@ -88,18 +95,23 @@ def test_median_and_tail_of_the_wan_cell_are_read_by_the_quantities_own_files():
 )
 def test_the_commit_cells_end_to_end_metrics(cell, gate):
     loaded = cells.load_cell(ROOT, cell)
-    assert sorted(m["name"] for m in loaded["end_to_end"]) == sorted([gate, "setup_s"])
-    entry = next(m for m in loaded["end_to_end"] if m["name"] == gate)
-    assert entry["unit"] == "ms" and entry["better"] == "lower" and entry["source"] == "host_clock"
-    assert cell in entry["workloads"]
+    rule.check_cell(cell, end_to_end=[gate, "setup_s"])
+    assert {m["name"] for m in loaded["end_to_end"]} == rule.owned_names(cell, "end_to_end")
+    # one gate a cell: the WAN cell's median, the others' tail
+    other = "commit_p95_ms" if gate == MEDIAN else MEDIAN
+    assert other not in rule.owned_names(cell, "end_to_end")
+    entry = rule.assert_fields(
+        gate, "end_to_end", cells_=[cell], unit="ms", better="lower", source="host_clock"
+    )
     # the tail stays where the runs hold it, under 0.05 since the driver's
     # check read 0.04 at its lower end; the WAN cell's median has a bound of
     # its own: three times the mean of the two spreads that check read,
     # 3 x 0.0466, after it refused 0.09 as too tight (PERF.md section 2)
     if gate == MEDIAN:
-        assert entry["workloads"] == [WAN] and entry["bound"] == 0.14
+        assert entry["bound"] == 0.14
+        assert not rule.owns(COMMITTEE, entry) and not rule.owns(CRASH, entry)
     else:
-        assert WAN not in entry["workloads"] and entry["bound"] == 0.05
+        assert not rule.owns(WAN, entry) and entry["bound"] == 0.05
 
 
 def test_the_wan_cells_traced_line_names_its_tail_and_its_skipped_waves():
