@@ -196,6 +196,34 @@ def decode_certificate(
     )
 
 
+def encode_certificates(certs: Sequence[RoundCertificate]) -> bytes:
+    """A batch of round certificates on the verify service's wire: each
+    :func:`encode_certificate` in a length-prefixed :func:`frame`."""
+    return b"".join(frame(encode_certificate(c)) for c in certs)
+
+
+def decode_certificates(payload: bytes) -> List[RoundCertificate]:
+    """Inverse of :func:`encode_certificates`. A frame that is cut short,
+    holds more or less than one whole certificate, or a batch with bytes
+    after its last frame is a ``ValueError``."""
+    out: List[RoundCertificate] = []
+    offset = 0
+    while offset < len(payload):
+        item = read_frame(payload, offset)
+        if item is None:
+            raise ValueError("truncated certificate frame")
+        blob, offset = item
+        try:
+            cert, end = decode_certificate(blob)
+        except struct.error as exc:
+            raise ValueError(f"malformed certificate: {exc}") from None
+        whole = sum(len(d) for d in cert.digests) + len(cert.agg_sig)
+        if end != len(blob) or whole + 12 + 8 * len(cert.signers) != end:
+            raise ValueError("certificate frame is not one whole certificate")
+        out.append(cert)
+    return out
+
+
 def encode_span_certificate(span: SpanCertificate) -> bytes:
     """Span layout: first round, round count, then each round's signer
     count + signer u32s + parallel digest blobs, then the combined

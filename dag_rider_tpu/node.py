@@ -36,7 +36,9 @@ Config (JSON):
   "cert": "agg",                   // aggregated round certificates (ISSUE 9):
                                    // one BLS aggregate check admits a whole
                                    // round; default "off" (per-vertex path);
-                                   // env default DAGRIDER_CERT
+                                   // env default DAGRIDER_CERT; with
+                                   // "verifier": "remote" the sidecar
+                                   // checks the certificates (VerifyCerts)
   "cert_msm": "host",              // | "device" | "sharded" — certificate
                                    // aggregation seam (DAGRIDER_CERT_MSM)
 
@@ -458,9 +460,22 @@ class Node:
             from dag_rider_tpu.verifier.cert import CertVerifier
 
             cert_signer = CertSigner(int(sk_hex, 16))
-            cert_verifier = CertVerifier(
-                reg, self.ccfg.quorum, msm=cfg.get("cert_msm")
-            )
+            if kind == "remote":
+                # the sidecar that checks this validator's vertices checks
+                # its round certificates too (VerifyCerts)
+                from dag_rider_tpu.verifier.sidecar import RemoteCertVerifier
+
+                cert_verifier = RemoteCertVerifier(
+                    cfg["verifier_address"],
+                    reg,
+                    self.ccfg.quorum,
+                    timeout=float(cfg.get("verifier_timeout_s", 30.0)),
+                    msm=cfg.get("cert_msm"),
+                )
+            else:
+                cert_verifier = CertVerifier(
+                    reg, self.ccfg.quorum, msm=cfg.get("cert_msm")
+                )
             if hasattr(verifier, "cert_verifier"):
                 # ladder deployments surface the certificate gauges in
                 # the same resilience bundle (verifier/resilient.py)

@@ -75,6 +75,17 @@ KNOWN_SPANS = frozenset(
         "sidecar.rpc",
         "sidecar.decode",
         "sidecar.between_rpcs",
+        # verifier/sidecar.py — one served VerifyCerts call, and the
+        # decoding of its certificates
+        "sidecar.cert_rpc",
+        "sidecar.cert_decode",
+        # verifier/cert.py CertBackend — one certificate's check: the
+        # digests' H(d) (host SHA, the device's square roots and
+        # cofactor ladder), the Miller program's dispatch to its product
+        # on the host, and the host's final exponentiation
+        "cert.hash",
+        "cert.miller",
+        "cert.final_exp",
         # mempool/ — submit -> vertex, one closed span per block
         "mempool.wait",
         # node.py — one pass of the validator's loop (its idle sleep
@@ -160,6 +171,13 @@ KNOWN_COUNTS = frozenset(
         # libcrypto's Ed25519, and one it signed in pure Python
         "sign.native",
         "sign.python",
+        # verifier/cert.py CertBackend — pairs a certificate check
+        # multiplied (padding left out), those of them the host oracle
+        # took instead of the device program, and the bytes of the
+        # committee's line tables on the device, once where they are built
+        "cert.pairs",
+        "cert.host_fallback",
+        "cert.table_bytes",
     }
 )
 

@@ -193,3 +193,88 @@ def g1_ladder_batch(
         else:
             results.append((f.from_limbs(xa[i]), f.from_limbs(ya[i])))
     return results, [bool(x) for x in fb]
+
+
+def _ints(limbs: np.ndarray) -> List[int]:
+    """Canonical limb rows [n, LIMBS] -> python ints, vectorized."""
+    bits = (limbs[:, :, None] >> np.arange(f.LIMB_BITS, dtype=np.int32)) & 1
+    packed = np.packbits(
+        bits.reshape(limbs.shape[0], -1).astype(np.uint8), axis=1, bitorder="little"
+    )
+    return [int.from_bytes(row.tobytes(), "little") for row in packed]
+
+
+class HashToG1Batch:
+    """``bls.hash_to_g1`` for up to ``rows`` messages a call, at one shape:
+    SHA stays on the host; each message's first ``TRIES`` candidates take
+    their square roots in one dispatch of :func:`_pow_fn`, and the chosen
+    points clear the cofactor in one dispatch of :func:`_ladder_fn`.
+
+    The points equal the oracle's: the first candidate whose root is one,
+    the smaller root, ``[h1]`` of it. A message none of whose ``TRIES``
+    candidates is a square (one in 2^TRIES), or whose cleared point is
+    the identity or meets the ladder's mid-way addition of equal points,
+    is hashed by the oracle itself; ``fallbacks`` counts them."""
+
+    TRIES = 8
+
+    def __init__(self, rows: int, domain: bytes = b"dagrider-coin-v1"):
+        from dag_rider_tpu.crypto import bls12381 as bls
+
+        self._bls = bls
+        self.rows = int(rows)
+        self.domain = domain
+        self.fallbacks = 0
+        sqrt_exp = (P + 1) // 4
+        self._pow = _pow_fn(sqrt_exp.bit_length())
+        self._sqrt_bits = jnp.asarray(
+            np.array(
+                [sqrt_exp >> k & 1 for k in range(sqrt_exp.bit_length() - 2, -1, -1)],
+                dtype=np.int32,
+            )
+        )
+        cofactor = bls._H1_COFACTOR
+        self._ladder = _ladder_fn(cofactor.bit_length())
+        self._cofactor_bits = jnp.asarray(_bit_columns([cofactor] * self.rows)[0])
+        gx, gy = bls.G1_GEN
+        self._pad = f.to_limbs_bulk([gx, gy])
+
+    def __call__(self, msgs: Sequence[bytes]) -> Tuple[np.ndarray, np.ndarray]:
+        """The points of ``msgs`` as canonical limb rows (x, y), one a
+        message."""
+        bls = self._bls
+        m, tries = len(msgs), self.TRIES
+        if m > self.rows:
+            raise ValueError(f"{m} messages for a batch of {self.rows}")
+        xs = [bls._hash_candidate_x(msg, self.domain, c) for msg in msgs for c in range(tries)]
+        y2s = [(x * x * x + 4) % P for x in xs]
+        base = np.zeros((self.rows * tries, f.LIMBS), dtype=np.int32)
+        if xs:
+            base[: m * tries] = f.to_limbs_bulk(y2s)
+        roots = _ints(np.asarray(self._pow(jnp.asarray(base), self._sqrt_bits))[: m * tries])
+        px = np.broadcast_to(self._pad[0], (self.rows, f.LIMBS)).copy()
+        py = np.broadcast_to(self._pad[1], (self.rows, f.LIMBS)).copy()
+        host = []
+        chosen_x, chosen_y, at = [], [], []
+        for i in range(m):
+            for c in range(i * tries, (i + 1) * tries):
+                y = roots[c]
+                if y * y % P == y2s[c]:
+                    chosen_x.append(xs[c])
+                    chosen_y.append(min(y, P - y))
+                    at.append(i)
+                    break
+            else:
+                host.append(i)
+        if at:
+            px[at] = f.to_limbs_bulk(chosen_x)
+            py[at] = f.to_limbs_bulk(chosen_y)
+        xa, ya, inf, fb = self._ladder(jnp.asarray(px), jnp.asarray(py), self._cofactor_bits)
+        xa, ya = np.array(xa)[:m], np.array(ya)[:m]
+        inf, fb = np.asarray(inf), np.asarray(fb)
+        host.extend(i for i in at if inf[i] or fb[i])
+        for i in host:
+            pt = bls.hash_to_g1(msgs[i], self.domain)
+            xa[i], ya[i] = f.to_limbs_bulk([pt[0], pt[1]])
+        self.fallbacks += len(host)
+        return xa, ya

@@ -29,9 +29,11 @@ simulator's dedup'd shared per-vertex dispatch.
 
 from __future__ import annotations
 
+import gc
+import time
 from typing import Callable, List, Optional, Sequence, Tuple
 
-from dag_rider_tpu import config
+from dag_rider_tpu import config, obs
 from dag_rider_tpu.core.types import RoundCertificate, SpanCertificate
 from dag_rider_tpu.crypto import bls12381 as bls
 from dag_rider_tpu.verifier.base import KeyRegistry
@@ -39,6 +41,26 @@ from dag_rider_tpu.verifier.base import KeyRegistry
 #: memoized verdicts kept per verifier (bounded like the crypto-layer
 #: precompute caches)
 _VERDICT_CACHE_MAX = 4096
+
+
+def structurally_valid(cert: RoundCertificate, quorum: int, n: int) -> bool:
+    """A quorum of distinct committee signers, each with its digest:
+    ``signers`` strictly increasing (so sorted, unique, and one wire
+    form), at least ``quorum`` of them, all in [0, n)."""
+    s = cert.signers
+    if len(s) < quorum or len(s) != len(cert.digests):
+        return False
+    if any(b <= a for a, b in zip(s, s[1:])):
+        return False
+    return 0 <= s[0] and s[-1] < n
+
+
+def decompress_aggregate(data: bytes):
+    """The aggregate signature's G1 point, or None where it is not one:
+    malformed, off the curve, or outside the r-order subgroup (a
+    cofactor-order component passes the pairing check unseen)."""
+    pt = bls.g1_decompress(data)
+    return pt if bls.g1_in_subgroup(pt) else None
 
 
 def _resolve_msm(msm: Optional[str]) -> str:
@@ -162,13 +184,12 @@ class CertVerifier:
     # -- verification (the receiving side) ------------------------------
 
     def _structurally_valid(self, cert: RoundCertificate) -> bool:
-        s = cert.signers
-        if len(s) < self.quorum or len(s) != len(cert.digests):
-            return False
-        # strictly increasing => sorted, unique, and a stable wire form
-        if any(b <= a for a, b in zip(s, s[1:])):
-            return False
-        return 0 <= s[0] and s[-1] < self.registry.n
+        return structurally_valid(cert, self.quorum, self.registry.n)
+
+    def _remember(self, key: tuple, ok: bool) -> None:
+        if len(self._verdicts) >= _VERDICT_CACHE_MAX:
+            self._verdicts.clear()
+        self._verdicts[key] = ok
 
     def verify_certificate(self, cert: RoundCertificate) -> bool:
         """One aggregate check for the whole round. False for ANY defect
@@ -182,9 +203,7 @@ class CertVerifier:
             self.stats["verdict_hits"] += 1
             return hit
         ok = self._check(cert)
-        if len(self._verdicts) >= _VERDICT_CACHE_MAX:
-            self._verdicts.clear()
-        self._verdicts[key] = ok
+        self._remember(key, ok)
         self.stats["certs_valid" if ok else "certs_invalid"] += 1
         if ok and self.on_certified is not None:
             self.on_certified(cert)
@@ -206,7 +225,7 @@ class CertVerifier:
         structural defect (bad bitmap, unknown signer, bad point)."""
         if not self._structurally_valid(cert):
             return None
-        agg = bls.g1_decompress(cert.agg_sig)
+        agg = decompress_aggregate(cert.agg_sig)
         if agg is None:
             return None
         pairs: List[tuple] = [(agg, bls.g2_neg(bls.G2_GEN))]
@@ -266,9 +285,7 @@ class CertVerifier:
             for pl in pair_lists:
                 all_pairs.extend(pl)  # type: ignore[arg-type]
             if self._pairing_check(all_pairs):
-                if len(self._verdicts) >= _VERDICT_CACHE_MAX:
-                    self._verdicts.clear()
-                self._verdicts[combined_key] = True
+                self._remember(combined_key, True)
                 for i in fresh:
                     self.stats["certs_checked"] += 1
                     self.stats["certs_valid"] += 1
@@ -338,16 +355,14 @@ class CertVerifier:
             self.stats["verdict_hits"] += 1
             return hit
         ok = self._span_check(span)
-        if len(self._verdicts) >= _VERDICT_CACHE_MAX:
-            self._verdicts.clear()
-        self._verdicts[key] = ok
+        self._remember(key, ok)
         self.stats["certs_valid" if ok else "certs_invalid"] += 1
         return ok
 
     def _span_check(self, span: SpanCertificate) -> bool:
         if not self._span_structurally_valid(span):
             return False
-        agg = bls.g1_decompress(span.agg_sig)
+        agg = decompress_aggregate(span.agg_sig)
         if agg is None:
             return False
         pairs: List[tuple] = [(agg, bls.g2_neg(bls.G2_GEN))]
@@ -358,3 +373,110 @@ class CertVerifier:
                     return False
                 pairs.append((bls.hash_to_g1(digest), pk))
         return self._pairing_check(pairs)
+
+
+class CertBackend:
+    """The round-certificate check the verify service hosts
+    (``VerifierSidecarServer``'s ``VerifyCerts``): every certificate of a
+    request is checked afresh — no verdict is kept between requests — by
+    the rules :class:`CertVerifier` applies.
+
+    With ``pair="device"`` (the default; None reads DAGRIDER_CERT_PAIR
+    only where it is given as such) a check is: the structural rules, the
+    aggregate's decompression with its subgroup check, the digests' H(d)
+    through the batched split map (:class:`ops.bls_g1.HashToG1Batch`),
+    ONE dispatch of the certificate Miller program over the committee's
+    line tables on the device (:class:`ops.bls_pairing.CertPairing`), and
+    the final exponentiation on the host — cubed, by x-chains
+    (``bls.final_exponentiation_cubed``), which leaves the verdict as it
+    is and costs a fifth of the direct power. :meth:`warmup` builds the
+    tables and compiles every program before a server opens its port, so
+    nothing compiles while it serves. A key whose schedule would meet a
+    vertical line has no table: its certificates go to the host oracle.
+    With ``pair="host"`` every check is the host oracle's
+    (``bls.multi_pairing_check``), and nothing touches jax.
+
+    Spans ``cert.hash``, ``cert.miller`` (dispatch to the product on the
+    host), ``cert.final_exp``; counters ``cert.pairs`` (pairs a check
+    multiplied, padding left out), ``cert.host_fallback`` (those of them
+    the host oracle took) and ``cert.table_bytes`` (the tables, once)."""
+
+    def __init__(self, registry: KeyRegistry, quorum: int, pair: Optional[str] = "device"):
+        if not registry.bls_public_keys:
+            raise ValueError("CertBackend needs a registry with BLS certificate keys")
+        self.registry = registry
+        self.quorum = int(quorum)
+        self.pair = _resolve_pair(pair)
+        self._pairing = None
+        self._hash = None
+        self.table_build_s = 0.0
+        self.compile_s = 0.0
+        self.stats_ = {"certs_checked": 0, "certs_valid": 0, "certs_invalid": 0}
+
+    def warmup(self) -> float:
+        """Build the device tables and compile the hash and Miller
+        programs at their one shape; the seconds spent (0.0 when there is
+        nothing to do). A warm-up that compiled collects the heap once and
+        freezes it, as ``TPUVerifier.warmup`` does."""
+        if self.pair != "device" or self._pairing is not None:
+            return 0.0
+        from dag_rider_tpu.ops import bls_g1, bls_pairing
+
+        t0 = time.perf_counter()
+        self._pairing = bls_pairing.CertPairing(self.registry.bls_public_keys)
+        obs.count("cert.table_bytes", self._pairing.table_bytes)
+        self._hash = bls_g1.HashToG1Batch(self.registry.n)
+        t1 = time.perf_counter()
+        self.table_build_s = t1 - t0
+        # one check of the widest certificate runs every program once
+        signers = list(range(self.registry.n))
+        hx, hy = self._hash([bytes(32)] * len(signers))
+        self._pairing.product(bls.G1_GEN, signers, hx, hy)
+        self.compile_s = time.perf_counter() - t1
+        gc.collect()
+        held = gc.get_freeze_count()
+        gc.freeze()
+        obs.count("heap.frozen_objects", gc.get_freeze_count() - held)
+        return self.table_build_s + self.compile_s
+
+    def stats(self) -> dict:
+        return {
+            **self.stats_,
+            "pair": self.pair,
+            "table_build_s": self.table_build_s,
+            "compile_s": self.compile_s,
+            "hash_fallbacks": self._hash.fallbacks if self._hash else 0,
+        }
+
+    def verify_certs(self, certs: Sequence[RoundCertificate]) -> List[bool]:
+        """One verdict a certificate; False for any defect, never an
+        exception."""
+        out = [self._verify(c) for c in certs]
+        self.stats_["certs_checked"] += len(out)
+        self.stats_["certs_valid"] += sum(out)
+        self.stats_["certs_invalid"] += len(out) - sum(out)
+        return out
+
+    def _verify(self, cert: RoundCertificate) -> bool:
+        if not structurally_valid(cert, self.quorum, self.registry.n):
+            return False
+        agg = decompress_aggregate(cert.agg_sig)
+        keys = [self.registry.bls_key_of(s) for s in cert.signers]
+        if agg is None or any(pk is None for pk in keys):
+            return False
+        pairs = len(keys) + 1
+        obs.count("cert.pairs", pairs)
+        if self.pair == "device":
+            self.warmup()
+            with obs.span("cert.hash"):
+                hx, hy = self._hash(cert.digests)
+            with obs.span("cert.miller"):
+                fm = self._pairing.product(agg, cert.signers, hx, hy)
+            if fm is not None:
+                with obs.span("cert.final_exp"):
+                    return bls.final_exponentiation_cubed(fm) == bls.FP12_ONE
+        obs.count("cert.host_fallback", pairs)
+        return bls.multi_pairing_check(
+            [(agg, bls.g2_neg(bls.G2_GEN))]
+            + [(bls.hash_to_g1(d), pk) for d, pk in zip(cert.digests, keys)]
+        )

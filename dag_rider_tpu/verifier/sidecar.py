@@ -6,15 +6,21 @@ Two halves:
 
 - :class:`VerifierSidecarServer` — hosts any Verifier backend (normally
   :class:`~dag_rider_tpu.verifier.tpu.TPUVerifier` pinned to the chip)
-  behind one unary method ``/dagrider.Verifier/VerifyBatch``;
+  behind the unary method ``/dagrider.Verifier/VerifyBatch``, and a
+  round-certificate backend (:class:`~dag_rider_tpu.verifier.cert.CertBackend`)
+  behind ``/dagrider.Verifier/VerifyCerts``, on the same worker;
 - :class:`RemoteVerifier` — a drop-in Verifier whose ``verify_batch``
-  round-trips the batch to the sidecar.
+  round-trips the batch to the sidecar;
+- :class:`RemoteCertVerifier` — a drop-in ``CertVerifier`` whose round
+  certificates are checked by the sidecar.
 
 Wire format (no protobuf codegen in the image — generic byte handlers,
 like transport/net.py): request = concatenated length-prefixed frames of
-codec-encoded vertices; response = one byte per vertex (0x00/0x01 mask).
-The mask therefore stays byte-identical across in-process CPU, in-process
-TPU, and remote-TPU verifier placements.
+codec-encoded vertices (``VerifyCerts``: of codec-encoded certificates,
+``codec.encode_certificates``); response = one byte per vertex or
+certificate (0x00/0x01 mask). The mask therefore stays byte-identical
+across in-process CPU, in-process TPU, and remote-TPU verifier
+placements.
 """
 
 from __future__ import annotations
@@ -28,10 +34,12 @@ import grpc
 
 from dag_rider_tpu import obs
 from dag_rider_tpu.core import codec
-from dag_rider_tpu.core.types import Vertex
-from dag_rider_tpu.verifier.base import Verifier, VerifierUnavailableError
+from dag_rider_tpu.core.types import RoundCertificate, Vertex
+from dag_rider_tpu.verifier.base import KeyRegistry, Verifier, VerifierUnavailableError
+from dag_rider_tpu.verifier.cert import CertVerifier
 
 _METHOD = "/dagrider.Verifier/VerifyBatch"
+_CERT_METHOD = "/dagrider.Verifier/VerifyCerts"
 _identity = lambda b: b  # noqa: E731
 
 #: Largest request or reply either end accepts, in bytes. gRPC's own
@@ -67,27 +75,22 @@ def _decode_batch(payload: bytes) -> List[Vertex]:
 
 
 class _VerifyHandler(grpc.GenericRpcHandler):
-    def __init__(self, backend: Verifier):
+    def __init__(self, backend: Optional[Verifier], certs=None):
         self._backend = backend
+        self._certs = certs
         #: when the last ``unary`` returned, on the spans' clock; the
         #: server has one worker thread, so from there to the next entry
         #: is gRPC's share and nothing else (``sidecar.between_rpcs``)
         self._left_ns: Optional[int] = None
 
     def service(self, handler_call_details):
-        if handler_call_details.method != _METHOD:
+        method = handler_call_details.method
+        if method == _METHOD and self._backend is not None:
+            serve, rpc_span = self._serve_batch, "sidecar.rpc"
+        elif method == _CERT_METHOD and self._certs is not None:
+            serve, rpc_span = self._serve_certs, "sidecar.cert_rpc"
+        else:
             return None
-
-        def serve(request: bytes, context) -> bytes:
-            try:
-                with obs.span("sidecar.decode"):
-                    batch = _decode_batch(request)
-            except ValueError:
-                context.abort(
-                    grpc.StatusCode.INVALID_ARGUMENT, "malformed batch"
-                )
-            mask = self._backend.verify_batch(batch)
-            return bytes(1 if ok else 0 for ok in mask)
 
         def unary(request: bytes, context) -> bytes:
             if self._left_ns is not None:
@@ -97,7 +100,7 @@ class _VerifyHandler(grpc.GenericRpcHandler):
                 )
             obs.count("sidecar.request_bytes", len(request))
             try:
-                with obs.span("sidecar.rpc"):
+                with obs.span(rpc_span):
                     return serve(request, context)
             finally:
                 self._left_ns = obs.spans.clock_ns()
@@ -105,6 +108,26 @@ class _VerifyHandler(grpc.GenericRpcHandler):
         return grpc.unary_unary_rpc_method_handler(
             unary, request_deserializer=_identity, response_serializer=_identity
         )
+
+    def _serve_batch(self, request: bytes, context) -> bytes:
+        try:
+            with obs.span("sidecar.decode"):
+                batch = _decode_batch(request)
+        except ValueError:
+            context.abort(grpc.StatusCode.INVALID_ARGUMENT, "malformed batch")
+        mask = self._backend.verify_batch(batch)
+        return bytes(1 if ok else 0 for ok in mask)
+
+    def _serve_certs(self, request: bytes, context) -> bytes:
+        try:
+            with obs.span("sidecar.cert_decode"):
+                certs = codec.decode_certificates(request)
+        except ValueError:
+            context.abort(
+                grpc.StatusCode.INVALID_ARGUMENT, "malformed certificate batch"
+            )
+        verdicts = self._certs.verify_certs(certs)
+        return bytes(1 if ok else 0 for ok in verdicts)
 
 
 class VerifierSidecarServer:
@@ -114,11 +137,16 @@ class VerifierSidecarServer:
 
     def __init__(
         self,
-        backend: Verifier,
+        backend: Optional[Verifier],
         listen_addr: str = "127.0.0.1:0",
         *,
         prep_workers: Optional[int] = None,
+        cert_backend=None,
     ):
+        """``backend`` serves ``VerifyBatch`` and ``cert_backend`` (a
+        :class:`~dag_rider_tpu.verifier.cert.CertBackend`) ``VerifyCerts``;
+        either may be None, and a method without a backend answers
+        UNIMPLEMENTED."""
         from concurrent import futures
 
         # Parallel host-prep engine (verifier/prep.py): an explicit
@@ -134,8 +162,9 @@ class VerifierSidecarServer:
         # ever compiles. Host-only backends (CPUVerifier oracle) have no
         # warmup — no jax import.
         self.warmup_compile_s = 0.0
-        if hasattr(backend, "warmup"):
-            self.warmup_compile_s = backend.warmup()
+        for hosted in (backend, cert_backend):
+            if hasattr(hosted, "warmup"):
+                self.warmup_compile_s += hosted.warmup()
         # full collections from here on walk what the handler makes
         # (the heap the program's tracing left was frozen by warmup);
         # they keep their name so that a heap that grows back shows
@@ -145,7 +174,9 @@ class VerifierSidecarServer:
         self._server = grpc.server(
             futures.ThreadPoolExecutor(max_workers=1), options=_MESSAGE_OPTIONS
         )
-        self._server.add_generic_rpc_handlers((_VerifyHandler(backend),))
+        self._server.add_generic_rpc_handlers(
+            (_VerifyHandler(backend, cert_backend),)
+        )
         self.bound_port = self._server.add_insecure_port(listen_addr)
         self._server.start()
 
@@ -281,6 +312,85 @@ class RemoteVerifier(Verifier):
                 f"{self._retries + 1} attempt(s)"
             )
         return [False] * len(vertices)
+
+    def close(self) -> None:
+        self._channel.close()
+
+
+class RemoteCertVerifier(CertVerifier):
+    """A :class:`CertVerifier` whose round certificates the sidecar checks
+    (``VerifyCerts``): a validator configured ``verifier: "remote"`` with
+    ``cert: "agg"`` holds one in place of a local CertVerifier.
+
+    Aggregation (``make_certificate``, ``make_span``) and span checks stay
+    local; every round certificate not already answered goes to the
+    sidecar, one RPC for the certificates of a call, each with a verdict
+    of its own. Fail-closed: a failed or mis-sized reply admits nothing,
+    and its certificates are not remembered, so a later call asks again.
+    Verdicts the sidecar gave are memoized like CertVerifier's."""
+
+    def __init__(
+        self,
+        address: str,
+        registry: KeyRegistry,
+        quorum: int,
+        *,
+        timeout: float = 30.0,
+        msm: Optional[str] = None,
+    ):
+        super().__init__(registry, quorum, msm=msm, pair="host")
+        self._address = address
+        self._timeout = timeout
+        self._channel = grpc.insecure_channel(address, options=_MESSAGE_OPTIONS)
+        self._call = self._channel.unary_unary(
+            _CERT_METHOD,
+            request_serializer=_identity,
+            response_deserializer=_identity,
+        )
+        self._lock = threading.Lock()
+        #: RPCs that failed or came back mis-sized
+        self.rpc_failures = 0
+
+    def verify_remote(self, certs: Sequence[RoundCertificate]) -> List[bool]:
+        """One RPC: the sidecar's verdict for each certificate, with no
+        memo on either side; all False where the RPC fails."""
+        return self._ask(certs) or [False] * len(certs)
+
+    def _ask(self, certs: Sequence[RoundCertificate]) -> Optional[List[bool]]:
+        if not certs:
+            return []
+        try:
+            with self._lock:
+                reply = self._call(
+                    codec.encode_certificates(certs), timeout=self._timeout
+                )
+        except grpc.RpcError:
+            reply = None
+        if reply is None or len(reply) != len(certs):
+            self.rpc_failures += 1
+            return None
+        return [b == 1 for b in reply]
+
+    def verify_certificate(self, cert: RoundCertificate) -> bool:
+        return self.verify_many([cert])[0]
+
+    def verify_many(self, certs: Sequence[RoundCertificate]) -> List[bool]:
+        verdicts: List[Optional[bool]] = [
+            self._verdicts.get(c.signing_key()) for c in certs
+        ]
+        self.stats["certs_checked"] += len(certs)
+        self.stats["verdict_hits"] += sum(v is not None for v in verdicts)
+        fresh = [i for i, v in enumerate(verdicts) if v is None]
+        got = self._ask([certs[i] for i in fresh])
+        if got is None:
+            return [bool(v) for v in verdicts]
+        for i, ok in zip(fresh, got):
+            verdicts[i] = ok
+            self._remember(certs[i].signing_key(), ok)
+            self.stats["certs_valid" if ok else "certs_invalid"] += 1
+            if ok and self.on_certified is not None:
+                self.on_certified(certs[i])
+        return [bool(v) for v in verdicts]
 
     def close(self) -> None:
         self._channel.close()
